@@ -70,16 +70,6 @@ def cmd_run(args) -> int:
 
         json_fmt = use_json_logging(logging.getLogger())
 
-    if args.engine == "tpu":
-        # Persistent XLA compile cache: a restarting node (and every
-        # node of a localhost testnet) reuses compiled consensus
-        # kernels instead of paying tens of seconds of recompiles.
-        # (Core wires this too; doing it before any JAX import settles
-        # the config as early as possible.)
-        from .devices import ensure_compile_cache
-
-        ensure_compile_cache(args.compile_cache_dir or None)
-
     datadir = args.datadir
     key = PemKey(datadir).read_key()
     peers = sort_peers_by_pub_key(JSONPeers(datadir).peers())
@@ -94,54 +84,7 @@ def cmd_run(args) -> int:
     if json_fmt is not None:
         json_fmt.node_id = node_id
 
-    conf = Config(
-        heartbeat_timeout=args.heartbeat / 1000.0,
-        tcp_timeout=args.tcp_timeout / 1000.0,
-        cache_size=args.cache_size,
-        sync_limit=args.sync_limit,
-        store_type=args.store,
-        store_path=args.store_path or os.path.join(datadir, "store.db"),
-        store_sync=args.store_sync,
-        engine=args.engine,
-        engine_mesh=args.engine_mesh,
-        consensus_interval=(
-            args.consensus_interval / 1000.0
-            if args.consensus_interval is not None
-            else (0.25 if args.engine == "tpu" else 0.0)),
-        pipeline_depth=args.pipeline_depth,
-        verify_workers=args.verify_workers,
-        runtime=args.runtime,
-        device_verify=args.device_verify,
-        engine_prewarm=not args.no_prewarm,
-        breaker_threshold=0 if args.no_breaker else args.breaker_threshold,
-        breaker_base_backoff=args.breaker_backoff / 1000.0,
-        sync_retries=args.sync_retries,
-        engine_failover_threshold=(
-            0 if args.no_failover else args.engine_failover_threshold),
-        trace_ring=args.trace_ring,
-        trace_sample=args.trace_sample,
-        profile_hz=args.profile_hz,
-        divergence_sentinel=not args.no_sentinel,
-        gossip_observatory=not args.no_gossip_observatory,
-        capacity=not args.no_capacity,
-        stall_timeout=args.stall_timeout / 1000.0,
-        wire_format=args.wire_format,
-        max_msg_bytes=args.max_msg_bytes << 20,
-        compile_cache_dir=args.compile_cache_dir,
-        plumtree=not args.no_plumtree,
-        eager_fanout=args.eager_fanout,
-        ihave_interval=args.ihave_interval / 1000.0,
-        graft_timeout=args.graft_timeout / 1000.0,
-        anti_entropy_interval=args.anti_entropy_interval / 1000.0,
-        admission=not args.no_admission,
-        intake_queue=args.intake_queue,
-        ingress_target_delay=args.ingress_target_ms / 1000.0,
-        quota_rate=args.quota_rate,
-        quota_burst=args.quota_burst,
-        submit_token=args.submit_token,
-        journal_sync=args.journal_sync,
-        logger=logger,
-    )
+    conf = config_from_args(args)
 
     needs_bootstrap = False
     if conf.store_type == "file":
@@ -215,6 +158,57 @@ def cmd_run(args) -> int:
         if close is not None:
             close()
     return 0
+
+
+def config_from_args(args) -> Config:
+    """The node Config that `run` builds from its parsed flags."""
+    return Config(
+        heartbeat_timeout=args.heartbeat / 1000.0,
+        tcp_timeout=args.tcp_timeout / 1000.0,
+        cache_size=args.cache_size,
+        sync_limit=args.sync_limit,
+        store_type=args.store,
+        store_path=args.store_path or os.path.join(args.datadir, "store.db"),
+        store_sync=args.store_sync,
+        engine=args.engine,
+        engine_mesh=args.engine_mesh,
+        consensus_interval=(
+            args.consensus_interval / 1000.0
+            if args.consensus_interval is not None
+            else (0.25 if args.engine == "tpu" else 0.0)),
+        pipeline_depth=args.pipeline_depth,
+        verify_workers=args.verify_workers,
+        runtime=args.runtime,
+        device_verify=args.device_verify,
+        engine_prewarm=not args.no_prewarm,
+        breaker_threshold=0 if args.no_breaker else args.breaker_threshold,
+        breaker_base_backoff=args.breaker_backoff / 1000.0,
+        sync_retries=args.sync_retries,
+        engine_failover_threshold=(
+            0 if args.no_failover else args.engine_failover_threshold),
+        trace_ring=args.trace_ring,
+        trace_sample=args.trace_sample,
+        profile_hz=args.profile_hz,
+        divergence_sentinel=not args.no_sentinel,
+        gossip_observatory=not args.no_gossip_observatory,
+        capacity=not args.no_capacity,
+        stall_timeout=args.stall_timeout / 1000.0,
+        wire_format=args.wire_format,
+        max_msg_bytes=args.max_msg_bytes << 20,
+        plumtree=not args.no_plumtree,
+        eager_fanout=args.eager_fanout,
+        ihave_interval=args.ihave_interval / 1000.0,
+        graft_timeout=args.graft_timeout / 1000.0,
+        anti_entropy_interval=args.anti_entropy_interval / 1000.0,
+        admission=not args.no_admission,
+        intake_queue=args.intake_queue,
+        ingress_target_delay=args.ingress_target_ms / 1000.0,
+        quota_rate=args.quota_rate,
+        quota_burst=args.quota_burst,
+        submit_token=args.submit_token,
+        journal_sync=args.journal_sync,
+        logger=logging.getLogger("babble_tpu"),
+    )
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -397,11 +391,6 @@ def build_parser() -> argparse.ArgumentParser:
                          "(JSON line or binary frame, either "
                          "direction); oversized messages fail with a "
                          "clear TransportError")
-    rn.add_argument("--compile_cache_dir", default="",
-                    help="persistent XLA compilation cache directory "
-                         "for the tpu engine (restart-surviving kernel "
-                         "compiles; default ~/.cache/babble_tpu/jax or "
-                         "$JAX_COMPILATION_CACHE_DIR)")
     # -- ingress (docs/ingress.md) --------------------------------------
     rn.add_argument("--no_admission", action="store_true",
                     help="disable the ingress admission plane "

@@ -146,12 +146,6 @@ class Config:
     # window inputs read pass k's committed result carries, so only one
     # pass can be in flight per engine.
     pipeline_depth: int = 1
-    # Persistent XLA compilation cache directory for the device engine
-    # (devices.ensure_compile_cache): restarts and sibling testnet
-    # processes reuse compiled consensus kernels instead of re-paying
-    # 5-15s of cold-start compiles per engine. "" = the default
-    # (~/.cache/babble_tpu/jax, or $JAX_COMPILATION_CACHE_DIR).
-    compile_cache_dir: str = ""
     # Compile the device engine's cold-start kernel ladder at node
     # construction (IncrementalEngine.prewarm) instead of stalling the
     # first live syncs on it. Skipped automatically when the scratch

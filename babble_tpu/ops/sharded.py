@@ -58,10 +58,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-try:  # jax >= 0.5 top-level spelling
-    from jax import shard_map
-except ImportError:  # older jax ships it under experimental
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .kernels import (FAME_TRUE, FAME_FALSE, FAME_UNDEFINED, INT32_MAX,
@@ -97,11 +94,8 @@ def _make_axis_index(mesh: Mesh, axis: MeshAxis):
 
 
 def _sharded(mesh, fn, in_specs, out_specs):
-    kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    try:  # replication-check kwarg renamed across jax versions
-        return jax.jit(shard_map(fn, check_vma=False, **kw))
-    except TypeError:
-        return jax.jit(shard_map(fn, check_rep=False, **kw))
+    return jax.jit(shard_map(fn, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False))
 
 
 # -- remote row fetches ---------------------------------------------------

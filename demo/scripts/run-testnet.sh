@@ -10,6 +10,15 @@ HEARTBEAT="${HEARTBEAT:-50}" ENGINE="${ENGINE:-host}" CONF="demo/conf"
 # ignored by the host engine)
 ENGINE_MESH="${ENGINE_MESH:-0}"
 [ -d "$CONF/node0" ] || { echo "run conf.sh first" >&2; exit 1; }
+# A chip belongs to the one process that initializes JAX on it: a second
+# --engine tpu process on this host would fail or hang waiting for it.
+# Run one tpu node per host (chip_smoke.py runs several validators on
+# one chip inside a single process).
+if [ "$ENGINE" = "tpu" ] && [ "$NODES" -gt 1 ]; then
+  echo "ENGINE=tpu needs one chip per node process; refusing $NODES" \
+       "nodes on this host (use NODES=1, or ENGINE=host)" >&2
+  exit 1
+fi
 : > "$CONF/pids"
 for i in $(seq 0 $((NODES - 1))); do
   p=$((BASE_PORT + i * 10))

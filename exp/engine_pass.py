@@ -7,9 +7,8 @@ sys.path.insert(0, "/root/repo")
 import numpy as np
 
 def main(n=4, e_tot=20000, bs=256, cap=65536, kcap=65536, timers=True):
-    import jax
-    CACHE_DIR = os.path.join(os.path.expanduser("~"), ".cache", "babble_tpu", "jax")
-    jax.config.update("jax_compilation_cache_dir", CACHE_DIR)
+    from babble_tpu.devices import ensure_compile_cache
+    ensure_compile_cache()
     from babble_tpu.ops.dag import synthetic_dag
     from babble_tpu.ops.incremental import IncrementalEngine
     dag, _ = synthetic_dag(n, e_tot, seed=5)

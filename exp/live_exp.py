@@ -7,10 +7,8 @@ if os.environ.get("SWITCH_IV"):
 
 def main(engine="tpu", n_nodes=4, warm_s=150.0, window_s=45.0, interval=1.0,
          gate=1500):
-    import jax as _jax
-    CACHE_DIR = os.path.join(os.path.expanduser("~"), ".cache", "babble_tpu", "jax")
-    os.makedirs(CACHE_DIR, exist_ok=True)
-    _jax.config.update("jax_compilation_cache_dir", CACHE_DIR)
+    from babble_tpu.devices import ensure_compile_cache
+    ensure_compile_cache()
 
     from babble_tpu import crypto
     from babble_tpu.hashgraph import InmemStore

@@ -214,3 +214,38 @@ def test_node_fast_sync_catches_up():
         stop.set()
         for nd in nodes:
             nd.shutdown()
+
+
+def test_fast_forward_drops_an_owed_wrap():
+    """A wrap that pacing deferred names a pre-reset event; after a
+    fast-forward the store no longer holds it, so the next self-event
+    must not take it as other-parent (every heartbeat insert would
+    fail and the tx pool would never be wrapped)."""
+    cores, _ = make_cores(4)
+    for c in cores[:3]:
+        c.init()
+    rng = random.Random(5)
+    for step in range(200):
+        a, b = rng.sample(range(3), 2)
+        gossip_round(cores, a, b)
+        if step % 5 == 0:
+            cores[a].run_consensus()
+    donor = cores[0]
+    donor.run_consensus()
+    assert donor.get_last_consensus_round_index() >= 1
+    frame = donor.get_frame()
+
+    joiner = cores[3]
+    joiner.init()
+    joiner.wrap_min_interval = 0.2
+    joiner._last_wrap_ts = time.monotonic()  # it has just wrapped
+    joiner.sync(donor.to_wire(donor.diff(joiner.known())),
+                wrap_fresh_only=True)
+    assert joiner._owed_wrap is not None, "pacing should defer this wrap"
+    joiner.fast_forward(frame.roots, frame.events)
+    assert joiner._owed_wrap is None
+    head = joiner.head
+    joiner.add_transactions([b"tx"])
+    joiner.add_self_event()  # raised before the reset dropped the debt
+    assert joiner.head != head
+    assert joiner.hg.store.get_event(joiner.head).other_parent() == ""

@@ -172,8 +172,19 @@ def test_equivocation_injected_via_chaos_transport_detected_live():
         else:
             raise AssertionError("warmup timeout")
 
-        with victim.core_lock:
-            head, forged = _forge_at_head(victim.core, victim_key)
+        # Forge at a head every honest node already holds: the evidence
+        # names the copy that arrives second as forged, so a node that
+        # got the forged copy first would name the real head instead.
+        vid = victim.core.participants[victim.core.hex_id()]
+        deadline = time.monotonic() + 30.0
+        while True:
+            with victim.core_lock:
+                head, forged = _forge_at_head(victim.core, victim_key)
+            if all(nd.core.known().get(vid, -1) >= head.index()
+                   for nd in nodes[1:]):
+                break
+            assert time.monotonic() < deadline, "victim head never spread"
+            time.sleep(0.02)
         wrapped[victim.local_addr].inject_equivocation(
             [forged.to_wire()])
 

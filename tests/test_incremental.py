@@ -56,22 +56,25 @@ def make_tpu_twin(build):
 
 
 @pytest.mark.parametrize(
-    "n,e,bs", [(8, 300, 37), (5, 97, 10)], ids=["n8", "n5"]
+    "n,e,bs,feed", [(8, 300, 37, False), (5, 97, 10, False),
+                    (8, 300, 37, True)], ids=["n8", "n5", "n8_feed"]
 )
-def test_engine_matches_full_pipeline(n, e, bs):
+def test_engine_matches_full_pipeline(n, e, bs, feed):
     """Batched ingest with run() between batches == one-shot recompute,
-    across capacity doubling and chain-bucket growth."""
+    across capacity doubling and chain-bucket growth; `feed` drives the
+    pipelined dispatch/collect loop (IncrementalEngine.feed) instead."""
     dag, _ = synthetic_dag(n, e, seed=3)
     eng = IncrementalEngine(n, capacity=64, block=64, k_capacity=8)
-    k = 0
-    while k < e:
-        hi = min(k + bs, e)
-        eng.append_batch(
-            dag.self_parent[k:hi], dag.other_parent[k:hi],
-            dag.creator[k:hi], dag.index[k:hi], dag.coin[k:hi],
-            np.arange(k, hi))
-        eng.run()
-        k = hi
+    if feed:
+        assert list(eng.feed(dag, bs))[-1] == e
+    else:
+        for k in range(0, e, bs):
+            hi = min(k + bs, e)
+            eng.append_batch(
+                dag.self_parent[k:hi], dag.other_parent[k:hi],
+                dag.creator[k:hi], dag.index[k:hi], dag.coin[k:hi],
+                np.arange(k, hi))
+            eng.run()
 
     rounds, wit, wt, famous, rr, cts = map(
         np.asarray, run_pipeline(dag, engine="wavefront"))
@@ -238,7 +241,7 @@ def test_run_unlocked_appends_interleave():
 
 
 def test_run_retries_after_transient_failure():
-    """A pass that dies mid-flight (tunnel drop, preemption) must not
+    """A pass that dies mid-flight (device lost, preemption) must not
     orphan its batch: the snapshot is restored, the node's consensus
     worker retries, and the retry produces the same results as a
     never-failed engine."""
@@ -265,12 +268,12 @@ def test_run_retries_after_transient_failure():
     feed(eng, 0, 120)
 
     @contextlib.contextmanager
-    def tunnel_drop():
-        raise RuntimeError("tunnel dropped")
+    def device_lost():
+        raise RuntimeError("device lost")
         yield  # pragma: no cover
 
     with pytest.raises(RuntimeError):
-        eng.run(unlocked=tunnel_drop)
+        eng.run(unlocked=device_lost)
     eng.run()  # retry re-mirrors the restored batch
     feed(eng, 120, e)
     eng.run()

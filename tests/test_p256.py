@@ -67,11 +67,13 @@ def test_device_padding_lanes_ignored():
 def test_ingest_routes_device_backend(monkeypatch):
     """verify_events(..., device_verify=True) routes through the
     p256 kernel and memoizes the same verdicts the host path would."""
+    from babble_tpu import crypto
     from babble_tpu.hashgraph.event import Event
     from babble_tpu.node import ingest
 
-    key = fb.key_from_seed(5)
-    pub = fb.pub_key_bytes(key)
+    # Event.sign goes through the active backend: give it its own key.
+    key = crypto.key_from_seed(5)
+    pub = crypto.pub_key_bytes(key)
     events = []
     for i in range(3):
         ev = Event.new([b"tx-%d" % i], ["p0", "p1"], pub, i)

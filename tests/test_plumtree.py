@@ -525,3 +525,30 @@ def test_plumtree_debug_views():
         assert set(roles) == {"addr1", "addr2"}
     finally:
         _shutdown(nodes)
+
+
+def test_paced_out_wrap_is_made_on_a_later_tick():
+    """Wrap pacing may defer the wrap of a fresh batch; the core owes
+    it and the heartbeat makes it once two intervals pass without one. Without
+    that, a cluster whose nodes all deferred their last wrap fell
+    silent with payload undecided (chip_smoke.py's testnet, about one
+    run in five)."""
+    from test_node import init_cores
+
+    cores = init_cores(2)
+    cores[0].add_transactions([b"tx"])
+    cores[0].add_self_event()  # a loaded event the sync delivers fresh
+    late = cores[1]
+    late.wrap_min_interval = 0.2
+    late._last_wrap_ts = time.monotonic()  # it has just wrapped
+    head = late.head
+    late.sync(cores[0].to_wire(cores[0].diff(late.known())),
+              wrap_fresh_only=True)
+    assert late.head == head, "pacing should defer this wrap"
+    assert not late.wrap_owed()
+    time.sleep(0.45)  # two intervals without a wrap
+    assert late.wrap_owed()
+    late.add_self_event()
+    assert late.hg.store.get_event(late.head).other_parent() == \
+        cores[0].head
+    assert not late.wrap_owed()

@@ -149,6 +149,22 @@ def test_procs_verify_parity_including_failure_position():
     assert all(ev._sig_ok is True for ev in batch2[1:])
 
 
+def test_procs_workers_never_load_jax():
+    """A chip belongs to one process: the spawned verify workers must
+    never load JAX (let alone initialize a backend), or a procs-mode
+    tpu node would fight its own workers for the chip."""
+    key, events = _signed_events(8, seed=79, tag=b"jx")
+    ingest.verify_events(events, workers=2, runtime="procs")
+    assert all(ev._sig_ok is True for ev in events)
+    for w in rt.active_pool().workers():
+        path = f"/proc/{w.proc.pid}/maps"
+        if not os.path.exists(path):
+            pytest.skip("no /proc maps to inspect")
+        with open(path) as f:
+            maps = f.read()
+        assert "jaxlib" not in maps and "libtpu" not in maps, w.name
+
+
 def test_procs_worker_killed_midbatch_drops_and_reverifies_inline():
     """Worker death with a chunk in flight mirrors the cancelled-chunk
     contract (PR 16): the chunk observes its queued wait, counts a
