@@ -51,6 +51,7 @@ class TpuHashgraph(Hashgraph):
         mesh=None,
         mesh_axis: str = "sp",
         prewarm: bool = False,
+        trace=None,
     ):
         super().__init__(participants, store, commit_callback)
         self._capacity = capacity
@@ -58,9 +59,12 @@ class TpuHashgraph(Hashgraph):
         self._k_capacity = k_capacity
         self._mesh = mesh
         self._mesh_axis = mesh_axis
+        # The owner's span ring (Core's), for the engine's pass spans.
+        self._trace = trace
         self.engine = IncrementalEngine(
             len(participants), capacity=capacity, block=block,
-            k_capacity=k_capacity, mesh=mesh, mesh_axis=mesh_axis)
+            k_capacity=k_capacity, mesh=mesh, mesh_axis=mesh_axis,
+            trace=trace)
         if prewarm:
             # Compile the cold-start kernel ladder now (scratch sibling
             # engine, process-global jit caches) so the first live
@@ -345,7 +349,8 @@ class TpuHashgraph(Hashgraph):
         self.engine = IncrementalEngine(
             n, root_round, capacity=self._capacity, block=self._block,
             k_capacity=self._k_capacity, index_base=index_base,
-            from_reset=True, mesh=self._mesh, mesh_axis=self._mesh_axis)
+            from_reset=True, mesh=self._mesh, mesh_axis=self._mesh_axis,
+            trace=self._trace)
         self._eid_of = {}
         self._hex_by_id = []
         self.undecided_rounds = list(self.engine.undecided_rounds)

@@ -78,6 +78,11 @@ class Core:
         # device_kind of the device the engine runs on.
         self.engine_platform = ""
         self.engine_device_kind = ""
+        # The span ring records sync / consensus-pass / failover spans,
+        # and the device engine's pass spans, for /debug/trace; a no-op
+        # ring when the owner (tests constructing Core bare) passes
+        # none.
+        self.trace = trace if trace is not None else SpanRing(0)
         if engine == "tpu":
             # Device-backed consensus behind the same seam — the
             # JaxStore-sibling integration of SURVEY §7 step 3.
@@ -115,7 +120,7 @@ class Core:
                     mesh = Mesh(_np.array(devs[:d]), ("sp",))
             self.hg: Hashgraph = TpuHashgraph(
                 participants, store, commit_callback, mesh=mesh,
-                prewarm=engine_prewarm,
+                prewarm=engine_prewarm, trace=self.trace,
                 **engine_sizes(len(participants), engine_opts))
             # Where the engine landed, logged and in /Stats: JAX falls
             # back to the CPU with only a warning when the chip cannot
@@ -161,11 +166,7 @@ class Core:
         # DISTRIBUTIONS (phase_ns keeps only last/total/calls) and the
         # full consensus-pass wall — for the pipelined device engine
         # that is dispatch -> collect across worker wakes, stamped on
-        # the PendingPass itself. The span ring records sync /
-        # consensus-pass / failover spans for /debug/trace; a no-op
-        # ring when the owner (tests constructing Core bare) passes
-        # none.
-        self.trace = trace if trace is not None else SpanRing(0)
+        # the PendingPass itself.
         # The owning Node shares its per-node registry; a Core built
         # bare (tests, tools) records into the process-global one.
         self._registry = registry if registry is not None else get_registry()

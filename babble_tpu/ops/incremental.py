@@ -50,6 +50,7 @@ import bisect
 import functools
 import queue
 import threading
+import time
 from dataclasses import dataclass, field
 from typing import List, Optional, Tuple
 
@@ -58,6 +59,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from ..telemetry.trace import SpanRing
 from . import closure, frontier, kernels
 from .kernels import FAME_TRUE, FAME_UNDEFINED, INT32_MAX
 
@@ -395,103 +397,108 @@ def _consensus_fused(chain_la, chain_rb_tab, chain_len, la, ranks, rb_vec,
     fd = _FdRows(ranks, chain_len, creator, index)
 
     # 1. Witness frontier.
-    wt_tab, fr_tab, t_end = frontier.frontier_sweep_impl(
-        chain_la, chain_rb_tab, chain_len, la, fd, rb_vec, chain,
-        wt_tab, fr_tab, wt_prev, fr_prev, t0, rho_min,
-        n=n, sm=sm, rcap=rcap)
+    with jax.named_scope("frontier"):
+        wt_tab, fr_tab, t_end = frontier.frontier_sweep_impl(
+            chain_la, chain_rb_tab, chain_len, la, fd, rb_vec, chain,
+            wt_tab, fr_tab, wt_prev, fr_prev, t0, rho_min,
+            n=n, sm=sm, rcap=rcap)
 
     # 2. Rounds + witness flags for the batch [e0, e1): round = rho_min
     # - 1 + #{frontier rows at or below the event's chain position}
     # (rows >= t_end keep the upload's kcap fill and never count).
-    ids_b = e0 + jnp.arange(bp)
-    valid_b = ids_b < e1
-    cr_b = lax.dynamic_slice(creator, (e0,), (bp,))
-    pos_b = lax.dynamic_slice(index, (e0,), (bp,))
-    sp_b = lax.dynamic_slice(self_parent, (e0,), (bp,))
-    cnt = (fr_tab[:, cr_b] <= pos_b[None, :]).sum(0, dtype=jnp.int32)
-    rnd_b = jnp.where(valid_b, rho_min - 1 + cnt, -1)
-    rounds_all = lax.dynamic_update_slice(rounds_prev, rnd_b, (e0,))
-    sp_safe = jnp.where(sp_b >= 0, sp_b, 0)
-    wit_b = valid_b & ((sp_b < 0) | (rnd_b > rounds_all[sp_safe]))
-    big = jnp.iinfo(jnp.int32).max // 2
-    min_new = jnp.min(jnp.where(valid_b, rnd_b, big))
-    i0 = jnp.minimum(first_undec_prev, min_new + 1)
+    with jax.named_scope("rounds"):
+        ids_b = e0 + jnp.arange(bp)
+        valid_b = ids_b < e1
+        cr_b = lax.dynamic_slice(creator, (e0,), (bp,))
+        pos_b = lax.dynamic_slice(index, (e0,), (bp,))
+        sp_b = lax.dynamic_slice(self_parent, (e0,), (bp,))
+        cnt = (fr_tab[:, cr_b] <= pos_b[None, :]).sum(0, dtype=jnp.int32)
+        rnd_b = jnp.where(valid_b, rho_min - 1 + cnt, -1)
+        rounds_all = lax.dynamic_update_slice(rounds_prev, rnd_b, (e0,))
+        sp_safe = jnp.where(sp_b >= 0, sp_b, 0)
+        wit_b = valid_b & ((sp_b < 0) | (rnd_b > rounds_all[sp_safe]))
+        big = jnp.iinfo(jnp.int32).max // 2
+        min_new = jnp.min(jnp.where(valid_b, rnd_b, big))
+        i0 = jnp.minimum(first_undec_prev, min_new + 1)
 
     # 3. Fame over the window [rx0, rho_min + rcap): rows gathered from
     # the swept table (mask instead of slice — rx0 is dynamic and
     # dynamic_slice would clamp), merged under the undecided-rounds
     # gating exactly as before.
-    t_w = rx0 - rho_min + jnp.arange(rw)
-    row_ok = (t_w >= 0) & (t_w < rcap)
-    t_wc = jnp.clip(t_w, 0, rcap - 1)
-    wt_win = jnp.where(row_ok[:, None], wt_tab[t_wc], -1)
-    famous_prev_win = jnp.where(row_ok[:, None], fam_rel[t_wc], 0)
-    in_list_win = row_ok & in_list_rel[t_wc]
+    with jax.named_scope("fame"):
+        t_w = rx0 - rho_min + jnp.arange(rw)
+        row_ok = (t_w >= 0) & (t_w < rcap)
+        t_wc = jnp.clip(t_w, 0, rcap - 1)
+        wt_win = jnp.where(row_ok[:, None], wt_tab[t_wc], -1)
+        famous_prev_win = jnp.where(row_ok[:, None], fam_rel[t_wc], 0)
+        in_list_win = row_ok & in_list_rel[t_wc]
 
-    famous_comp = kernels.decide_fame_impl(
-        wt_win, la, fd, index, coin, n=n, sm=sm, r=rw)
-    wt_valid_f = wt_win >= 0
-    mergeable = (
-        in_list_win[:, None] & wt_valid_f
-        & (famous_prev_win == FAME_UNDEFINED)
-    )
-    famous_merged = jnp.where(mergeable, famous_comp, famous_prev_win)
-    undec_row = (wt_valid_f & (famous_merged == FAME_UNDEFINED)).any(1)
-    still_listed = in_list_win & undec_row
-    t_first = jnp.min(jnp.where(still_listed, jnp.arange(rw), big))
-    first_undec = rx0 + t_first  # huge when the list empties
+        famous_comp = kernels.decide_fame_impl(
+            wt_win, la, fd, index, coin, n=n, sm=sm, r=rw)
+        wt_valid_f = wt_win >= 0
+        mergeable = (
+            in_list_win[:, None] & wt_valid_f
+            & (famous_prev_win == FAME_UNDEFINED)
+        )
+        famous_merged = jnp.where(mergeable, famous_comp, famous_prev_win)
+        undec_row = (wt_valid_f & (famous_merged == FAME_UNDEFINED)).any(1)
+        still_listed = in_list_win & undec_row
+        t_first = jnp.min(jnp.where(still_listed, jnp.arange(rw), big))
+        first_undec = rx0 + t_first  # huge when the list empties
 
     # 4. Round received over [i0, rho_min + rcap): fame/eligibility from
     # the host tables below rx0, from this run's merge at and above it.
-    i_vec = i0 + jnp.arange(iw)
-    rel = i_vec - rho_min
-    rel_ok = (rel >= 0) & (rel < rcap)
-    rel_c = jnp.clip(rel, 0, rcap - 1)
-    wt_rr = jnp.where(rel_ok[:, None], wt_tab[rel_c], -1)
-    t2 = jnp.clip(i_vec - rx0, 0, rw - 1)
-    in_fame_win = i_vec >= rx0
-    fam_low = jnp.where(rel_ok[:, None], fam_rel[rel_c], 0)
-    fam_rr = jnp.where(in_fame_win[:, None], famous_merged[t2], fam_low)
-    # Decidedness below the fame window is derived from the POST-sweep
-    # witness table, not host state: a straggler witness landing THIS
-    # run in an already-removed round has UNDEFINED fame forever and
-    # must poison the round's witnesses_decided (reference
-    # hashgraph.go:629-637, 762-764).
-    elig_low = rel_ok & ~(
-        (wt_rr >= 0) & (fam_low == FAME_UNDEFINED)).any(1)
-    decided_vec = jnp.where(in_fame_win, ~undec_row[t2], elig_low)
-    elig = decided_vec & (first_undec > i_vec)
+    with jax.named_scope("round_received"):
+        i_vec = i0 + jnp.arange(iw)
+        rel = i_vec - rho_min
+        rel_ok = (rel >= 0) & (rel < rcap)
+        rel_c = jnp.clip(rel, 0, rcap - 1)
+        wt_rr = jnp.where(rel_ok[:, None], wt_tab[rel_c], -1)
+        t2 = jnp.clip(i_vec - rx0, 0, rw - 1)
+        in_fame_win = i_vec >= rx0
+        fam_low = jnp.where(rel_ok[:, None], fam_rel[rel_c], 0)
+        fam_rr = jnp.where(in_fame_win[:, None], famous_merged[t2], fam_low)
+        # Decidedness below the fame window is derived from the POST-sweep
+        # witness table, not host state: a straggler witness landing THIS
+        # run in an already-removed round has UNDEFINED fame forever and
+        # must poison the round's witnesses_decided (reference
+        # hashgraph.go:629-637, 762-764).
+        elig_low = rel_ok & ~(
+            (wt_rr >= 0) & (fam_low == FAME_UNDEFINED)).any(1)
+        decided_vec = jnp.where(in_fame_win, ~undec_row[t2], elig_low)
+        elig = decided_vec & (first_undec > i_vec)
 
-    wt_valid = wt_rr >= 0
-    wt_safe = jnp.where(wt_valid, wt_rr, 0)
-    fmask = (fam_rr == FAME_TRUE) & wt_valid
-    fcnt = fmask.sum(1)
-    idx_w = jnp.where(wt_valid, index[wt_safe], -1)
+        wt_valid = wt_rr >= 0
+        wt_safe = jnp.where(wt_valid, wt_rr, 0)
+        fmask = (fam_rr == FAME_TRUE) & wt_valid
+        fcnt = fmask.sum(1)
+        idx_w = jnp.where(wt_valid, index[wt_safe], -1)
 
-    # The sweep runs over the UNDECIDED window only (host-gathered ids
-    # with rr < 0): decided events never change, so each of the iw
-    # sequential steps compares [n, |undecided|] instead of [n, E] —
-    # the dominant per-sync cost once the DAG is deep.
-    au = und_ids.shape[0]
-    lane_ok = jnp.arange(au) < n_und
-    uid = jnp.where(lane_ok, und_ids, 0)
-    cr_u = creator[uid]
-    ix_u = index[uid]
-    rnd_u = rounds_all[uid]
-    rr_u0 = jnp.where(lane_ok, rr_prev[uid], 0)  # pad lanes: never assigned
+        # The sweep runs over the UNDECIDED window only (host-gathered ids
+        # with rr < 0): decided events never change, so each of the iw
+        # sequential steps compares [n, |undecided|] instead of [n, E] —
+        # the dominant per-sync cost once the DAG is deep.
+        au = und_ids.shape[0]
+        lane_ok = jnp.arange(au) < n_und
+        uid = jnp.where(lane_ok, und_ids, 0)
+        cr_u = creator[uid]
+        ix_u = index[uid]
+        rnd_u = rounds_all[uid]
+        # Pad lanes are never assigned.
+        rr_u0 = jnp.where(lane_ok, rr_prev[uid], 0)
 
-    def step(t, rr_u):
-        i = i0 + t
-        la_w = la[wt_safe[t]]  # [n(w), n]
-        see_wx = la_w[:, cr_u] >= ix_u[None, :]  # [n(w), au]
-        s_cnt = (see_wx & fmask[t][:, None]).sum(0)
-        ok = (elig[t] & (s_cnt > fcnt[t] // 2) & (i > rnd_u)
-              & (rr_u < 0) & lane_ok)
-        return jnp.where(ok, i, rr_u)
+        def step(t, rr_u):
+            i = i0 + t
+            la_w = la[wt_safe[t]]  # [n(w), n]
+            see_wx = la_w[:, cr_u] >= ix_u[None, :]  # [n(w), au]
+            s_cnt = (see_wx & fmask[t][:, None]).sum(0)
+            ok = (elig[t] & (s_cnt > fcnt[t] // 2) & (i > rnd_u)
+                  & (rr_u < 0) & lane_ok)
+            return jnp.where(ok, i, rr_u)
 
-    rr_u = lax.fori_loop(0, iw, step, rr_u0)
-    newly_l = (rr_u >= 0) & (rr_u0 < 0) & lane_ok
-    newly_count = newly_l.sum(dtype=jnp.int32)
+        rr_u = lax.fori_loop(0, iw, step, rr_u0)
+        newly_l = (rr_u >= 0) & (rr_u0 < 0) & lane_ok
+        newly_count = newly_l.sum(dtype=jnp.int32)
 
     # Consensus timestamps only for the lanes that were JUST assigned —
     # compacted to a static [cb] bucket so the median machinery (the
@@ -500,61 +507,63 @@ def _consensus_fused(chain_la, chain_rb_tab, chain_len, la, ranks, rb_vec,
     # newly_count lanes are exactly the newly-received lanes; if the
     # bucket overflows (a late fame decision releasing a huge backlog),
     # newly_count > cb tells the host to redo with a bigger bucket.
-    order = jnp.argsort(~newly_l)
-    sel_l = order[:cb]  # [cb] lanes, newly lanes first
-    live = newly_l[sel_l]
-    sel_ids = uid[sel_l]
-    t_sel = jnp.clip(rr_u[sel_l] - i0, 0, iw - 1)
-    w_sel = wt_safe[t_sel]  # [cb, n]
-    fm_sel = fmask[t_sel]
-    idxw_sel = idx_w[t_sel]
-    cr_sel = creator[sel_ids]
-    ix_sel = index[sel_ids]
-    fd_sel = fd[sel_ids]  # [cb, n]
-    see_sel = la[w_sel, cr_sel[:, None]] >= ix_sel[:, None]
-    s_mask = see_sel & fm_sel
-    s_cnt = s_mask.sum(1)
-    valid_t = fd_sel <= idxw_sel  # first descendant reaches the witness
-    fd_pos = jnp.clip(fd_sel, 0, k - 1)
-    rows_n = jnp.arange(n)[None, :]
-    ts_hi = chain_th[rows_n, fd_pos]
-    ts_lo = chain_tl[rows_n, fd_pos]
-    # ZERO_TIME for unreached witnesses (sorts first); INT32_MAX pads
-    # the non-famous lanes to the end. Median by LEXICOGRAPHIC two-key
-    # sort — signed (hi, lo) order equals int64 ns order (_ts_split).
-    hi_v = jnp.where(valid_t, ts_hi, ZERO_TS_HI)
-    lo_v = jnp.where(valid_t, ts_lo, 0)
-    hi_m = jnp.where(s_mask, hi_v, INT32_MAX)
-    lo_m = jnp.where(s_mask, lo_v, INT32_MAX)
-    s_hi, s_lo = lax.sort((hi_m, lo_m), dimension=1, num_keys=2)
-    pick = (s_cnt // 2)[:, None]
-    med_hi = jnp.take_along_axis(s_hi, pick, axis=1)[:, 0]
-    med_lo = jnp.take_along_axis(s_lo, pick, axis=1)[:, 0]
-    # Results ride home cb-compacted: the first newly_count entries of
-    # sel_l are exactly the newly-received lanes (stable argsort), so
-    # the pull carries [cb] lanes+rr+cts instead of three au-wide
-    # planes — at n=1024 the undecided window is tens of thousands of
-    # lanes and this saves megabytes per pull.
-    rr_sel = rr_u[sel_l]
+    with jax.named_scope("timestamps"):
+        order = jnp.argsort(~newly_l)
+        sel_l = order[:cb]  # [cb] lanes, newly lanes first
+        live = newly_l[sel_l]
+        sel_ids = uid[sel_l]
+        t_sel = jnp.clip(rr_u[sel_l] - i0, 0, iw - 1)
+        w_sel = wt_safe[t_sel]  # [cb, n]
+        fm_sel = fmask[t_sel]
+        idxw_sel = idx_w[t_sel]
+        cr_sel = creator[sel_ids]
+        ix_sel = index[sel_ids]
+        fd_sel = fd[sel_ids]  # [cb, n]
+        see_sel = la[w_sel, cr_sel[:, None]] >= ix_sel[:, None]
+        s_mask = see_sel & fm_sel
+        s_cnt = s_mask.sum(1)
+        valid_t = fd_sel <= idxw_sel  # first descendant reaches the witness
+        fd_pos = jnp.clip(fd_sel, 0, k - 1)
+        rows_n = jnp.arange(n)[None, :]
+        ts_hi = chain_th[rows_n, fd_pos]
+        ts_lo = chain_tl[rows_n, fd_pos]
+        # ZERO_TIME for unreached witnesses (sorts first); INT32_MAX pads
+        # the non-famous lanes to the end. Median by LEXICOGRAPHIC two-key
+        # sort — signed (hi, lo) order equals int64 ns order (_ts_split).
+        hi_v = jnp.where(valid_t, ts_hi, ZERO_TS_HI)
+        lo_v = jnp.where(valid_t, ts_lo, 0)
+        hi_m = jnp.where(s_mask, hi_v, INT32_MAX)
+        lo_m = jnp.where(s_mask, lo_v, INT32_MAX)
+        s_hi, s_lo = lax.sort((hi_m, lo_m), dimension=1, num_keys=2)
+        pick = (s_cnt // 2)[:, None]
+        med_hi = jnp.take_along_axis(s_hi, pick, axis=1)[:, 0]
+        med_lo = jnp.take_along_axis(s_lo, pick, axis=1)[:, 0]
+        # Results ride home cb-compacted: the first newly_count entries of
+        # sel_l are exactly the newly-received lanes (stable argsort), so
+        # the pull carries [cb] lanes+rr+cts instead of three au-wide
+        # planes — at n=1024 the undecided window is tens of thousands of
+        # lanes and this saves megabytes per pull.
+        rr_sel = rr_u[sel_l]
 
     # Post-pass device carries: the batch's rounds and this sync's rr
     # assignments stay resident, so the next pass uploads neither. Pad
     # lanes scatter past the carry (NOT to row e, which may be a live
     # pad row a later append will occupy) and are dropped.
-    uid_scatter = jnp.where(lane_ok, uid, rr_prev.shape[0])
-    rr_all = rr_prev.at[uid_scatter].set(rr_u, mode="drop")
+    with jax.named_scope("pack"):
+        uid_scatter = jnp.where(lane_ok, uid, rr_prev.shape[0])
+        rr_all = rr_prev.at[uid_scatter].set(rr_u, mode="drop")
 
-    # Only rows [t_start, t_start + tw) of the frontier tables can have
-    # changed this sync; the host reconstructs the rest from its copy.
-    wt_ret = lax.dynamic_slice(wt_tab, (t_start, 0), (tw, n))
-    fr_ret = lax.dynamic_slice(fr_tab, (t_start, 0), (tw, n))
+        # Only rows [t_start, t_start + tw) of the frontier tables can have
+        # changed this sync; the host reconstructs the rest from its copy.
+        wt_ret = lax.dynamic_slice(wt_tab, (t_start, 0), (tw, n))
+        fr_ret = lax.dynamic_slice(fr_tab, (t_start, 0), (tw, n))
 
-    packed = jnp.concatenate([
-        t_end[None].astype(jnp.int32), newly_count[None],
-        wt_ret.ravel(), fr_ret.ravel(),
-        rnd_b, wit_b.astype(jnp.int32), famous_merged.ravel(),
-        sel_l.astype(jnp.int32), rr_sel, med_hi, med_lo,
-    ])
+        packed = jnp.concatenate([
+            t_end[None].astype(jnp.int32), newly_count[None],
+            wt_ret.ravel(), fr_ret.ravel(),
+            rnd_b, wit_b.astype(jnp.int32), famous_merged.ravel(),
+            sel_l.astype(jnp.int32), rr_sel, med_hi, med_lo,
+        ])
     return packed, rounds_all, rr_all
 
 
@@ -590,9 +599,14 @@ class PendingPass:
     is immutable from the engine's point of view until collect — the
     double-buffer contract: appends landing while the pass is in
     flight go to the engine's fresh staging list, never this one.
+
+    `pass_id` numbers the engine's passes; every span of the pass, on
+    either thread, carries it. `phase_ns` collects the pass's phase
+    times, published as the engine's `phase_ns` when collect returns.
     """
 
     __slots__ = (
+        "pass_id", "phase_ns",
         "new_ids", "e", "cap0", "k0", "chain_len0",
         "chain_len_d", "la", "rb", "cr_d", "idx_d", "coin_d",
         "t0", "wt_prev", "fr_prev", "rel_rows",
@@ -602,7 +616,7 @@ class PendingPass:
         "w_floor", "tw_floor", "rw", "iw", "cb", "tw", "rcap",
         "tw_i", "t_start",
         "packed_dev", "rounds_out", "rr_out",
-        "dispatched_ns", "stage_tail_ns",
+        "dispatched_ns",
         "ready", "error",
     )
 
@@ -627,10 +641,14 @@ class IncrementalEngine:
     def __init__(self, n: int, root_round=None, *, capacity: int = 256,
                  block: int = 256, k_capacity: int = 64,
                  index_base=None, from_reset: bool = False,
-                 mesh=None, mesh_axis="sp"):
+                 mesh=None, mesh_axis="sp", trace: Optional[SpanRing] = None):
         if n < 1:
             raise ValueError("need at least one participant")
         self.n = n
+        # Spans of each pass (docs/observability.md): on this ring, and
+        # in any profiler session on the device trace's clock.
+        self.trace = trace if trace is not None else SpanRing(0)
+        self._passes = 0
         self.sm = 2 * n // 3 + 1
         self.block = block
         self.root_round = (
@@ -806,10 +824,15 @@ class IncrementalEngine:
         # the host was free), and the blocking share of the pull.
         self.last_overlap_ns = 0
 
-        # Per-phase wall time (ns) of the last run(), mirroring the
-        # reference's phase logging around the consensus pipeline
-        # (node/core.go:278-296). Keys: coords, fd, frontier, rounds,
-        # fame_rr.
+        # Host wall (ns) of the phases of the last collected pass, from
+        # the stamps of its spans (node/core.go:278-296 logs the same
+        # phases). Worker thread, around asynchronous enqueues: coords
+        # (engine.ingest + engine.closure), fd_fold (engine.tables),
+        # stage (engine.windows); c_dispatch sums engine.fused_enqueue
+        # (worker and redos). Caller: c_stage_wait, c_pull_wait,
+        # c_pull_xfer, c_pull (wait + xfer), apply (engine.apply), and
+        # consensus: engine.collect outside those and the redos (the
+        # redo decisions).
         self.phase_ns: dict = {}
         # Compiled-cost attribution (docs/observability.md "Device
         # profiling"): request_cost_report() arms a one-shot capture;
@@ -1200,6 +1223,9 @@ class IncrementalEngine:
         self._new_since_run = []
         try:
             pp = PendingPass()
+            self._passes += 1
+            pp.pass_id = self._passes
+            pp.phase_ns = {}
             pp.new_ids = new_ids
             # Snapshot (see run() docstring): the staging worker and
             # collect must use these, not the live fields, since
@@ -1299,78 +1325,74 @@ class IncrementalEngine:
         derivation, and the fused-epilogue dispatch. Reads only the
         pass snapshot plus host state that collect alone mutates —
         concurrent append() is safe by the snapshot discipline (see
-        run() docstring)."""
-        n = self.n
-        new_ids = pp.new_ids
-        e = pp.e
-        cap0, k0 = pp.cap0, pp.k0
-        chain_len0 = pp.chain_len0
-        import os as _os
-        import time as _time
+        run() docstring). Its phase times are host wall around
+        asynchronous enqueues; the device's share of each stage is read
+        from a profiler trace."""
+        n, e, cap0 = self.n, pp.e, pp.cap0
+        with self._span(pp, "engine.stage", events=len(pp.new_ids)):
+            # 0. Device sync-up: lazy capacity growth, then ingest the
+            # new batch into the resident event arrays and chain table.
+            # All dispatches are async — nothing here round-trips. Under
+            # a mesh, re-pin the carries first (growth concats and
+            # kernel outputs may drift from the intended shardings).
+            with self._span(pp, "engine.ingest") as ing:
+                self._sync_device(cap0, pp.k0)
+                self._constrain_carries()
+                self._ingest_batch(e, pp.chain_len0)
+                pp.chain_len_d = jnp.asarray(pp.chain_len0)
+                pp.cr_d = self._cr_d
+                pp.idx_d = self._idx_d
+                pp.coin_d = self._coin_d
 
-        _t = _time.perf_counter_ns
-        _phase_start = _t()
-        self.phase_ns = {}
-        # Without the env flag, phases are NOT synced: every host sync
-        # costs a device round trip, so production runs keep the
-        # dispatch queue async and the timers only bracket
-        # host-visible boundaries.
-        _sync_timers = _os.environ.get("BABBLE_ENGINE_TIMERS") == "1"
+            # 1. Coordinates: only blocks the frozen prefix doesn't cover.
+            with self._span(pp, "engine.closure") as clo:
+                nb = (e + self.block - 1) // self.block
+                self._la, self._rb = self._k_closure(
+                    self._la, self._rb, self._sp_d, self._op_d, pp.cr_d,
+                    pp.idx_d, self._rb0_d, jnp.int32(self._frozen_blocks),
+                    jnp.int32(nb), n=n, block=self.block)
+                self._frozen_blocks = e // self.block
+                pp.la = self._la[:cap0]
+                pp.rb = self._rb[:cap0]
 
-        def _mark(name, *sync):
-            nonlocal _phase_start
-            if _sync_timers:
-                for x in sync:
-                    jax.block_until_ready(x)
-            now = _t()
-            self.phase_ns[name] = now - _phase_start
-            _phase_start = now
+            # 2. First descendants from the resident rank cube, folding
+            # the batch first (incremental compare-and-count — per-sync
+            # cost scales with the batch, not E; see _tables_update /
+            # _tables_update_hist, picked per backend at construction).
+            # fd is consumed as lazy row gathers from the rank cube
+            # inside the fused kernel (_FdRows) — no [cap, n]
+            # materialization.
+            with self._span(pp, "engine.tables") as tab:
+                if self._e_counted < e:
+                    self._ranks, self._chain_la, self._chain_rb = (
+                        self._tables_fn(
+                            self._ranks, self._chain_la, self._chain_rb,
+                            self._la, self._rb, self._newtab_d,
+                            self._newpos_d, n=n, m=self._new_m))
+                    self._e_counted = e
+                    self._len_counted = pp.chain_len0.copy()
 
-        # 0. Device sync-up: lazy capacity growth, then ingest the new
-        # batch into the resident event arrays and chain table. All
-        # dispatches are async — nothing here round-trips. Under a mesh,
-        # re-pin the carries first (growth concats and kernel outputs
-        # may drift from the intended shardings).
-        self._sync_device(cap0, k0)
-        self._constrain_carries()
-        self._ingest_batch(e, chain_len0)
-        pp.chain_len_d = jnp.asarray(chain_len0)
-        pp.cr_d = self._cr_d
-        pp.idx_d = self._idx_d
-        pp.coin_d = self._coin_d
+            with self._span(pp, "engine.windows") as win:
+                self._derive_windows(pp)
+            self._dispatch_fused(pp)
+        pp.phase_ns.update(coords=clo.t1 - ing.t0, fd_fold=tab.t1 - tab.t0,
+                           stage=win.t1 - win.t0)
+        pp.dispatched_ns = time.perf_counter_ns()
 
-        # 1. Coordinates: only blocks the frozen prefix doesn't cover.
-        nb = (e + self.block - 1) // self.block
-        self._la, self._rb = self._k_closure(
-            self._la, self._rb, self._sp_d, self._op_d, pp.cr_d,
-            pp.idx_d, self._rb0_d, jnp.int32(self._frozen_blocks),
-            jnp.int32(nb), n=n, block=self.block)
-        self._frozen_blocks = e // self.block
-        pp.la = self._la[:cap0]
-        pp.rb = self._rb[:cap0]
-        _mark("coords", pp.la)
+    def _span(self, pp: PendingPass, name: str, **args):
+        """A span of pass `pp` (docs/observability.md "The trace ring")."""
+        return self.trace.span(name, cat="engine", pass_id=pp.pass_id,
+                               **args)
 
-        # 2. First descendants from the resident rank cube, folding the
-        # batch first (incremental compare-and-count — per-sync cost
-        # scales with the batch, not E; see _tables_update /
-        # _tables_update_hist, picked per backend at construction).
-        if self._e_counted < e:
-            self._ranks, self._chain_la, self._chain_rb = self._tables_fn(
-                self._ranks, self._chain_la, self._chain_rb,
-                self._la, self._rb, self._newtab_d, self._newpos_d,
-                n=n, m=self._new_m)
-            self._e_counted = e
-            self._len_counted = chain_len0.copy()
-        _mark("fd_fold", self._ranks)
-        # fd is consumed as lazy row gathers from the rank cube
-        # inside the fused kernel (_FdRows) — no [cap, n]
-        # materialization.
-
-        # 3-6. Frontier, new-event rounds, fame, and round-received in
-        # ONE device dispatch with ONE packed pull (_consensus_fused):
-        # every device->host sync costs a full round trip, so the
-        # windows the host used to build between pulls are now derived
-        # on device from host bookkeeping tables.
+    def _derive_windows(self, pp: PendingPass) -> None:
+        """3-6. Frontier, new-event rounds, fame, and round-received run
+        in ONE device dispatch with ONE packed pull (_consensus_fused):
+        every device->host sync costs a full round trip, so the windows
+        the host used to build between pulls are derived on device from
+        host bookkeeping tables. This sets the pass's window geometry
+        and inputs."""
+        n, e, cap0 = self.n, pp.e, pp.cap0
+        new_ids, chain_len0 = pp.new_ids, pp.chain_len0
         rel_rows = len(self._fr_table)
         if rel_rows:
             # A row can only change when a chain it is still waiting on
@@ -1506,15 +1528,6 @@ class IncrementalEngine:
         # (2*rcap*n int32) are sub-millisecond even at n=1024.
         pp.rcap = _pow2(rel_rows + 8,
                         max(64, min(2048, (1 << 16) // n)))
-        cd0 = self.phase_ns.get("c_dispatch", 0)
-        self._dispatch_fused(pp)
-        # Worker-side share of the staging tail (window derivation +
-        # table build), excluding the dispatch-enqueue time recorded
-        # by _dispatch_fused.
-        self.phase_ns["stage"] = (
-            self.phase_ns.get("stage", 0) + _t() - _phase_start
-            - (self.phase_ns.get("c_dispatch", 0) - cd0))
-        pp.dispatched_ns = _t()
 
     def _dispatch_fused(self, pp: PendingPass) -> None:
         """Build the window tables from host bookkeeping and enqueue
@@ -1523,8 +1536,21 @@ class IncrementalEngine:
         window-overflow redo; reads only host state that collect alone
         mutates, so a redo between dispatch and collect sees exactly
         the staging-time values."""
-        import time as _time
+        with self._span(pp, "engine.fused_enqueue") as enq:
+            fused_args, fused_kw = self._fused_inputs(pp)
+            pp.packed_dev, pp.rounds_out, pp.rr_out = _consensus_fused(
+                *fused_args, **fused_kw)
+        pp.phase_ns["c_dispatch"] = (
+            pp.phase_ns.get("c_dispatch", 0) + enq.t1 - enq.t0)
+        if self._cost_requested:
+            # One-shot: an overflow redo of the same pass re-arms only
+            # if the operator asks again.
+            self._cost_requested = False
+            self.cost_report = self._analyze_cost(fused_args, fused_kw)
 
+    def _fused_inputs(self, pp: PendingPass):
+        """The fused epilogue's arguments and static sizes for the
+        pass's current windows."""
         n, sm = self.n, self.sm
         rcap = pp.rcap
         wt_tab = np.full((rcap, n), -1, np.int32)
@@ -1547,7 +1573,6 @@ class IncrementalEngine:
         # from the intact prediction instead of a stale bound.
         pp.tw_i = min(pp.tw, rcap)
         pp.t_start = min(pp.t0, rcap - pp.tw_i)
-        _t_stage = _time.perf_counter_ns()
         fused_args = (
             self._chain_la, self._chain_rb, pp.chain_len_d, pp.la,
             self._ranks, pp.rb,
@@ -1562,16 +1587,7 @@ class IncrementalEngine:
             jnp.int32(pp.t_start))
         fused_kw = dict(n=n, sm=sm, rcap=rcap, bp=pp.bp, rw=pp.rw,
                         iw=pp.iw, cb=pp.cb, tw=pp.tw_i)
-        pp.packed_dev, pp.rounds_out, pp.rr_out = _consensus_fused(
-            *fused_args, **fused_kw)
-        self.phase_ns["c_dispatch"] = (
-            self.phase_ns.get("c_dispatch", 0)
-            + _time.perf_counter_ns() - _t_stage)
-        if self._cost_requested:
-            # One-shot: an overflow redo of the same pass re-arms only
-            # if the operator asks again.
-            self._cost_requested = False
-            self.cost_report = self._analyze_cost(fused_args, fused_kw)
+        return fused_args, fused_kw
 
     def request_cost_report(self) -> None:
         """Arm a one-shot compiled-cost capture: the next fused-
@@ -1680,15 +1696,35 @@ class IncrementalEngine:
         return out
 
     def _collect_pass(self, pp: PendingPass, unlocked) -> RunDelta:
-        n = self.n
-        import time as _time
+        ph = pp.phase_ns
+        with self._span(pp, "engine.collect") as col:
+            packed, newly_count, redo_ns = self._await_delta(pp, unlocked)
+            with self._span(pp, "engine.apply", newly=newly_count) as app:
+                delta = self._apply_delta(pp, packed, newly_count)
+        ph["apply"] = app.t1 - app.t0
+        # Collect's own host share: the redo decisions and the lock
+        # hand-over, outside the waits, pulls, redos and the apply.
+        ph["consensus"] = (col.t1 - col.t0 - ph["c_stage_wait"]
+                           - ph["c_pull"] - redo_ns - ph["apply"])
+        # One assignment of the finished dict: a reader after the next
+        # dispatch sees this pass whole, never a part of the next one.
+        self.phase_ns = ph
+        # An append that slipped in during the unlocked wait means the
+        # state is NOT at a fixpoint yet.
+        self._empty_delta_ok = not self._new_since_run
+        return delta
 
-        _t = _time.perf_counter_ns
-        # The stage-wait + pull + redo loop runs with the caller's
-        # lock RELEASED (the one blocking device->host wait of the
-        # pass): every input was uploaded at dispatch, and everything
-        # below uses the pass snapshot, so interleaved appends are
-        # safe (see run() docstring).
+    def _await_delta(self, pp: PendingPass, unlocked):
+        """The stage wait, the pull and the redo loop. Returns the
+        final packed delta, its newly-received count, and the wall of
+        the redo dispatches.
+
+        This runs with the caller's lock RELEASED (the one blocking
+        device->host wait of the pass): every input was uploaded at
+        dispatch, and everything below uses the pass snapshot, so
+        interleaved appends are safe (see run() docstring)."""
+        ph = pp.phase_ns
+        redo_ns = 0
         _uctx = unlocked() if unlocked is not None else None
         if _uctx is not None:
             _uctx.__enter__()
@@ -1696,21 +1732,15 @@ class IncrementalEngine:
             # Wait for the staging worker (usually already done — the
             # wait is only non-zero when collect fires before staging
             # could enqueue everything, e.g. under compile stalls).
-            # phase_ns keys must not be written before this point:
-            # _stage_pass resets the dict on the worker.
-            _t_wait = _t()
-            pp.ready.wait()
+            with self._span(pp, "engine.stage_wait") as wait:
+                pp.ready.wait()
             if pp.error is not None:
                 raise pp.error
-            t_enter = _t()
-            self.phase_ns["c_stage_wait"] = (
-                self.phase_ns.get("c_stage_wait", 0) + t_enter - _t_wait)
+            ph["c_stage_wait"] = wait.t1 - wait.t0
             # Overlap diagnostic: wall between the staging worker's
             # last enqueue and collect entry — device compute the host
             # did NOT wait for (it was ingesting gossip instead).
-            self.last_overlap_ns = max(t_enter - pp.dispatched_ns, 0)
-            cd0 = self.phase_ns.get("c_dispatch", 0)
-            cp0 = self.phase_ns.get("c_pull", 0)
+            self.last_overlap_ns = max(wait.t1 - pp.dispatched_ns, 0)
             while True:
                 # c_pull sub-phases (the bounding sustained phase —
                 # BENCH_r05 put it at 0.44 share): `wait` is device
@@ -1719,24 +1749,19 @@ class IncrementalEngine:
                 # the kernel (wait-bound) or the pull payload/transport
                 # (xfer-bound); c_pull stays their sum for every
                 # existing consumer.
-                _t_pull = _t()
-                try:
-                    pp.packed_dev.block_until_ready()
-                except AttributeError:
-                    pass  # non-array stand-ins in tests
-                _t_ready = _t()
-                packed = np.asarray(pp.packed_dev)
-                _t_done = _t()
-                self.phase_ns["c_pull_wait"] = (
-                    self.phase_ns.get("c_pull_wait", 0)
-                    + _t_ready - _t_pull)
-                self.phase_ns["c_pull_xfer"] = (
-                    self.phase_ns.get("c_pull_xfer", 0)
-                    + _t_done - _t_ready)
-                self.phase_ns["c_pull"] = (
-                    self.phase_ns.get("c_pull", 0) + _t_done - _t_pull)
-                self.c_pull_bytes = int(
-                    getattr(pp.packed_dev, "nbytes", 0))
+                with self._span(pp, "engine.pull_wait") as pull:
+                    try:
+                        pp.packed_dev.block_until_ready()
+                    except AttributeError:
+                        pass  # non-array stand-ins in tests
+                self.c_pull_bytes = int(getattr(pp.packed_dev, "nbytes", 0))
+                with self._span(pp, "engine.pull_xfer",
+                                bytes=self.c_pull_bytes) as xfer:
+                    packed = np.asarray(pp.packed_dev)
+                wait_ns, xfer_ns = pull.t1 - pull.t0, xfer.t1 - xfer.t0
+                ph["c_pull_wait"] = ph.get("c_pull_wait", 0) + wait_ns
+                ph["c_pull_xfer"] = ph.get("c_pull_xfer", 0) + xfer_ns
+                ph["c_pull"] = ph.get("c_pull", 0) + wait_ns + xfer_ns
                 t_end = int(packed[0])
                 newly_count = int(packed[1])
                 if t_end == pp.rcap:
@@ -1746,64 +1771,71 @@ class IncrementalEngine:
                     # no wrong or out-of-order assignment is possible) but
                     # incomplete — discard and redo at double capacity.
                     pp.rcap *= 2
+                    reason = "rcap"
+                else:
+                    reason = self._resize_windows(pp, packed, t_end,
+                                                  newly_count)
+                if reason is not None:
                     self.redo_count += 1
-                    self._dispatch_fused(pp)
-                    continue
-                # Window overflow: in-window results are a valid subset
-                # (decisions are monotone in voting rounds; rr assignments
-                # outside the window simply stay unassigned) but rounds
-                # beyond the windows were never processed — redo with the
-                # exact spans now known from the pull. Likewise a
-                # timestamp-bucket overflow (a fame decision released more
-                # events than cb) redoes with the exact count.
-                # All overflow checks read the pulled buffer (offsets use
-                # the tw_i actually dispatched), so a sync overflowing
-                # several windows enlarges them all before ONE redo.
-                redo = False
-                if t_end > pp.t_start + pp.tw_i:
-                    # Returned-window overflow: the sweep advanced past the
-                    # predicted row window — redo with the exact span.
-                    pp.tw = _pow2(max(t_end - pp.t_start, pp.tw_i + 1),
-                                  pp.tw_floor)
-                    pp.rw = pp.iw = max(pp.rw, _pow2(pp.tw, pp.w_floor))
-                    redo = True
-                rnd_b = packed[2 + 2 * pp.tw_i * n:
-                               2 + 2 * pp.tw_i * n + pp.bp]
-                valid_b = rnd_b >= 0
-                min_new = int(rnd_b[valid_b].min()) if valid_b.any() else None
-                r_hi = self.rho_min + t_end
-                i0_true = self._prev_first_undec
-                if min_new is not None:
-                    i0_true = min(i0_true, min_new + 1)
-                if (r_hi - pp.rx0 > pp.rw or r_hi - i0_true > pp.iw
-                        or newly_count > pp.cb):
-                    pp.rw = pp.iw = _pow2(
-                        max(r_hi - pp.rx0, r_hi - i0_true, pp.rw),
-                        pp.w_floor)
-                    pp.cb = min(_pow2(max(newly_count, 1024)), pp.cap0,
-                                pp.au)
-                    redo = True
-                if redo:
-                    self.redo_count += 1
-                    self._dispatch_fused(pp)
+                    with self._span(pp, "engine.redo", reason=reason) as redo:
+                        self._dispatch_fused(pp)
+                    redo_ns += redo.t1 - redo.t0
                     continue
                 # Window-geometry diagnostics of the final dispatch.
                 self._dbg_windows = dict(
                     rcap=pp.rcap, rw=pp.rw, iw=pp.iw, cb=pp.cb, au=pp.au,
                     bp=pp.bp, tw=pp.tw_i, t0=pp.t0, t_end=t_end,
                     rel_rows=pp.rel_rows)
-                break
+                return packed, newly_count, redo_ns
         finally:
             if _uctx is not None:
                 _uctx.__exit__(None, None, None)
 
-        e = pp.e
-        cap0 = pp.cap0
-        chain_len0 = pp.chain_len0
-        new_ids = pp.new_ids
+    def _resize_windows(self, pp: PendingPass, packed, t_end: int,
+                        newly_count: int) -> Optional[str]:
+        """Window overflow: in-window results are a valid subset
+        (decisions are monotone in voting rounds; rr assignments
+        outside the window simply stay unassigned) but rounds beyond
+        the windows were never processed — redo with the exact spans
+        now known from the pull. Likewise a timestamp-bucket overflow
+        (a fame decision released more events than cb) redoes with the
+        exact count. All overflow checks read the pulled buffer
+        (offsets use the tw_i actually dispatched), so a sync
+        overflowing several windows enlarges them all before ONE redo.
+        Returns the redo's reason, `window` or `cb`, or None."""
+        n = self.n
+        reason = None
+        if t_end > pp.t_start + pp.tw_i:
+            # Returned-window overflow: the sweep advanced past the
+            # predicted row window — redo with the exact span.
+            pp.tw = _pow2(max(t_end - pp.t_start, pp.tw_i + 1),
+                          pp.tw_floor)
+            pp.rw = pp.iw = max(pp.rw, _pow2(pp.tw, pp.w_floor))
+            reason = "window"
+        rnd_b = packed[2 + 2 * pp.tw_i * n:2 + 2 * pp.tw_i * n + pp.bp]
+        valid_b = rnd_b >= 0
+        min_new = int(rnd_b[valid_b].min()) if valid_b.any() else None
+        r_hi = self.rho_min + t_end
+        i0_true = self._prev_first_undec
+        if min_new is not None:
+            i0_true = min(i0_true, min_new + 1)
+        wide = r_hi - pp.rx0 > pp.rw or r_hi - i0_true > pp.iw
+        if wide or newly_count > pp.cb:
+            pp.rw = pp.iw = _pow2(
+                max(r_hi - pp.rx0, r_hi - i0_true, pp.rw), pp.w_floor)
+            pp.cb = min(_pow2(max(newly_count, 1024)), pp.cap0, pp.au)
+            reason = reason or ("window" if wide else "cb")
+        return reason
+
+    def _apply_delta(self, pp: PendingPass, packed,
+                     newly_count: int) -> RunDelta:
+        """Unpack the final delta, apply the host mirrors, commit the
+        device result carries, and build the RunDelta."""
+        n = self.n
+        t_end = int(packed[0])
+        e, chain_len0, new_ids = pp.e, pp.chain_len0, pp.new_ids
         tw_i, t_start, bp, rw, cb = pp.tw_i, pp.t_start, pp.bp, pp.rw, pp.cb
         rel_rows, rx0, und = pp.rel_rows, pp.rx0, pp.und
-        rounds_out, rr_out = pp.rounds_out, pp.rr_out
         off = 2
         tabs = packed[off:off + 2 * tw_i * n].reshape(2, tw_i, n)
         off += 2 * tw_i * n
@@ -1825,15 +1857,6 @@ class IncrementalEngine:
         cts_hi_np = packed[off:off + cb]
         off += cb
         cts_lo_np = packed[off:]
-        # "consensus" is the host-side share of the fused stage:
-        # window staging + unpack, EXCLUDING the dispatch-block and the
-        # pull recorded separately above (they would otherwise be
-        # double-counted and skew the bench's bounded-by verdict).
-        _now = _t()
-        self.phase_ns["consensus"] = (
-            self.phase_ns.get("consensus", 0) + _now - t_enter
-            - (self.phase_ns.get("c_dispatch", 0) - cd0)
-            - (self.phase_ns.get("c_pull", 0) - cp0))
 
         active = (fr_all < chain_len0[None, :]).any(axis=1)
         n_rows = int(np.nonzero(active)[0][-1]) + 1 if active.any() else 0
@@ -1855,59 +1878,62 @@ class IncrementalEngine:
 
         # Host mirrors of the device-computed rounds (reference
         # DivideRounds bookkeeping, hashgraph.go:616-646).
-        for j, i in enumerate(new_ids):
-            rnd = int(rnd_b[j])
-            wit = bool(wit_b[j])
-            self.rounds[i] = rnd
-            self.witness[i] = wit
-            delta.new_rounds.append((i, rnd, wit))
-            if rnd not in self._queued_rounds:
-                self._queued_rounds.add(rnd)
-                bisect.insort(self.undecided_rounds, rnd)
+        with self._span(pp, "engine.apply.rounds"):
+            for j, i in enumerate(new_ids):
+                rnd = int(rnd_b[j])
+                wit = bool(wit_b[j])
+                self.rounds[i] = rnd
+                self.witness[i] = wit
+                delta.new_rounds.append((i, rnd, wit))
+                if rnd not in self._queued_rounds:
+                    self._queued_rounds.add(rnd)
+                    bisect.insort(self.undecided_rounds, rnd)
 
         # Host mirror of DecideFame's bookkeeping from the pulled
         # fame window (hashgraph.go:649-730).
-        for rho in list(self.undecided_rounds):
-            if rho >= r_total:
-                continue
-            t = rho - rx0
-            row_decided = True
-            for c in range(n):
-                if wt_abs[rho, c] < 0:
+        with self._span(pp, "engine.apply.fame"):
+            for rho in list(self.undecided_rounds):
+                if rho >= r_total:
                     continue
-                if self.famous[rho, c] == FAME_UNDEFINED:
-                    f = int(famous_merged[t, c])
-                    if f != FAME_UNDEFINED:
-                        self.famous[rho, c] = f
-                        delta.fame_updates.append(
-                            (rho, int(wt_abs[rho, c]), f == FAME_TRUE))
-                if self.famous[rho, c] == FAME_UNDEFINED:
-                    row_decided = False
-            if row_decided:
-                self.undecided_rounds.remove(rho)
-                delta.newly_decided_rounds.append(rho)
-                if (self.last_consensus_round is None
-                        or rho > self.last_consensus_round):
-                    self.last_consensus_round = rho
-                    delta.last_commited_round_events = int(
-                        (self.rounds[:e] == rho - 1).sum())
+                t = rho - rx0
+                row_decided = True
+                for c in range(n):
+                    if wt_abs[rho, c] < 0:
+                        continue
+                    if self.famous[rho, c] == FAME_UNDEFINED:
+                        f = int(famous_merged[t, c])
+                        if f != FAME_UNDEFINED:
+                            self.famous[rho, c] = f
+                            delta.fame_updates.append(
+                                (rho, int(wt_abs[rho, c]), f == FAME_TRUE))
+                    if self.famous[rho, c] == FAME_UNDEFINED:
+                        row_decided = False
+                if row_decided:
+                    self.undecided_rounds.remove(rho)
+                    delta.newly_decided_rounds.append(rho)
+                    if (self.last_consensus_round is None
+                            or rho > self.last_consensus_round):
+                        self.last_consensus_round = rho
+                        delta.last_commited_round_events = int(
+                            (self.rounds[:e] == rho - 1).sum())
 
         # The cb-compacted tail: entries [0, newly_count) are the newly
         # received lanes in ascending lane (= event id) order — the
         # same order the au-wide scan used to produce.
-        for j in range(newly_count):
-            li = int(sel_np[j])
-            i = int(und[li])
-            rr_i = int(rr_sel_np[j])
-            hi = int(cts_hi_np[j])
-            self.rr[i] = rr_i
-            if hi == ZERO_TS_HI:
-                self.cts_ns[i] = CTS_SENTINEL
-                ns = ZERO_TIME_NS
-            else:
-                ns = _ts_join(hi, int(cts_lo_np[j]))
-                self.cts_ns[i] = ns
-            delta.new_received.append((int(i), rr_i, ns))
+        with self._span(pp, "engine.apply.received"):
+            for j in range(newly_count):
+                li = int(sel_np[j])
+                i = int(und[li])
+                rr_i = int(rr_sel_np[j])
+                hi = int(cts_hi_np[j])
+                self.rr[i] = rr_i
+                if hi == ZERO_TS_HI:
+                    self.cts_ns[i] = CTS_SENTINEL
+                    ns = ZERO_TIME_NS
+                else:
+                    ns = _ts_join(hi, int(cts_lo_np[j]))
+                    self.cts_ns[i] = ns
+                delta.new_received.append((int(i), rr_i, ns))
         delta.last_consensus_round = self.last_consensus_round
         self._prev_first_undec = (
             self.undecided_rounds[0] if self.undecided_rounds else r_total)
@@ -1916,17 +1942,8 @@ class IncrementalEngine:
         # mirrors are applied: a redo, a transient device failure, or an
         # exception anywhere above leaves the previous pass's carries
         # intact, so the retry recomputes against consistent state.
-        self._rounds_d = rounds_out
-        self._rr_d = rr_out
-
-        # Host mirror application time — the remaining post-pull share
-        # of the pass (everything above `_now`).
-        self.phase_ns["apply"] = (
-            self.phase_ns.get("apply", 0) + _t() - _now)
-
-        # An append that slipped in during the unlocked wait means the
-        # state is NOT at a fixpoint yet.
-        self._empty_delta_ok = not self._new_since_run
+        self._rounds_d = pp.rounds_out
+        self._rr_d = pp.rr_out
         return delta
 
     def feed(self, dag, sync: int = 4096):

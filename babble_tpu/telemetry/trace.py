@@ -21,16 +21,37 @@ transaction across the node rows.
 Entries carry a monotonically increasing completion sequence (`seq`),
 the cursor behind `/debug/trace?since=` — scrapers re-fetch only what
 completed since their last poll instead of re-downloading the whole
-ring."""
+ring.
+
+Every span is also a `jax.profiler.TraceAnnotation` of the same name
+and args, so it lands in any profiler session (`/debug/profile`, the
+benchmark's traced run) on the device trace's clock — even from a
+disabled ring. Outside a session the annotation is a no-op."""
 
 from __future__ import annotations
 
 import contextlib
 import itertools
+import sys
 import threading
 import time
 from collections import deque
 from typing import Callable, Dict, List, Optional
+
+
+def _annotation(name: str, args: dict):
+    """The profiler annotation of a span. JAX is never imported here: a
+    process that has not loaded it has no profiler session to land in."""
+    prof = sys.modules.get("jax.profiler")
+    ann = getattr(prof, "TraceAnnotation", None)
+    return ann(name, **args) if ann is not None else contextlib.nullcontext()
+
+
+class Span(dict):
+    """The args a span records, as SpanRing.span yields them, with its
+    `perf_counter_ns` stamps: `t0` at entry, `t1` once the body ends."""
+
+    __slots__ = ("t0", "t1")
 
 
 class SpanRing:
@@ -65,20 +86,24 @@ class SpanRing:
         carry it (`extra={"span_id": rec["span_id"]}` — the JSON log
         formatter lifts it). Exceptions propagate; the span is
         recorded either way with outcome=error unless the body set
-        its own."""
-        rec = dict(args)
-        if self._spans is None:
-            yield rec
-            return
-        rec["span_id"] = next(self._ids)
-        t0 = time.perf_counter_ns()
-        try:
-            yield rec
-        except BaseException:
-            rec.setdefault("outcome", "error")
-            raise
-        finally:
-            self.record(name, t0, time.perf_counter_ns(), cat=cat, **rec)
+        its own. The yielded Span carries the stamps the ring records
+        (`t0`, `t1`), so a caller's phase timers read the same clock
+        reads."""
+        rec = Span(args)
+        with _annotation(name, args):
+            if self._spans is not None:
+                rec["span_id"] = next(self._ids)
+            rec.t0 = time.perf_counter_ns()
+            try:
+                yield rec
+            except BaseException:
+                if self._spans is not None:
+                    rec.setdefault("outcome", "error")
+                raise
+            finally:
+                rec.t1 = time.perf_counter_ns()
+                if self._spans is not None:
+                    self.record(name, rec.t0, rec.t1, cat=cat, **rec)
 
     def record(self, name: str, start_ns: int, end_ns: int,
                cat: str = "node", **args) -> int:

@@ -2436,36 +2436,6 @@ def measure():
                     "xfer": round(phase_tot.get("c_pull_xfer", 0)
                                   / phase_tot["c_pull"], 3)}
 
-        # Device-time attribution in a SEPARATE short pass (synced
-        # per-phase timers serialize every stage, so they must not run
-        # inside the timed loop): a fresh engine replays the first 6
-        # batches synchronously with compile caches warm — answering
-        # which DEVICE stage is the biggest compute, independent of
-        # what the pipeline hides from the host.
-        prof = IncrementalEngine(n, capacity=65536, block=512,
-                                 k_capacity=1024)
-        os.environ["BABBLE_ENGINE_TIMERS"] = "1"
-        phase_sync: dict = {}
-        k = 0
-        for p_i in range(min(6, len(per_batch))):
-            hi = min(k + bs, e_sus)
-            prof.append_batch(
-                dag_s.self_parent[k:hi], dag_s.other_parent[k:hi],
-                dag_s.creator[k:hi], dag_s.index[k:hi], dag_s.coin[k:hi],
-                _np.arange(k, hi))
-            prof.run()
-            if p_i >= 3:  # skip warmup batches
-                for ph, ns in prof.phase_ns.items():
-                    phase_sync[ph] = phase_sync.get(ph, 0) + ns
-            k = hi
-        os.environ.pop("BABBLE_ENGINE_TIMERS", None)
-        if phase_sync:
-            top_s = {ph: ns for ph, ns in phase_sync.items()
-                     if ph not in ("c_pull_wait", "c_pull_xfer")}
-            tot_ns = sum(top_s.values())
-            payload["sustained_phase_share_synced"] = {
-                ph: round(ns / tot_ns, 3)
-                for ph, ns in sorted(top_s.items())}
         _emit(payload)
 
     # -- stage 2c: the real gossiping node --------------------------------

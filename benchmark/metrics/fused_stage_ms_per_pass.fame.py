@@ -1,0 +1,9 @@
+"""Device time of the fused kernel's `fame` stage per pass: leaf-op
+time under its jax.named_scope (benchmark/xplane_ops.py)."""
+
+
+def read(run):
+    ops, passes = getattr(run, "trace_ops", None), run.counters.get("passes")
+    if ops is None or not passes or "fame" not in ops.fused_stages_s:
+        return None
+    return ops.fused_stages_s["fame"] / passes * 1e3

@@ -1,19 +1,18 @@
 """Standalone engine pass-cost probe at live-node shapes.
 Feeds a realistic n-node gossip DAG to IncrementalEngine in sync-sized
-batches and reports synced per-phase costs per pass, for different
-k_capacity presizes and batch sizes."""
-import os, sys, time
+batches and reports per-phase host walls per pass, for different
+k_capacity presizes and batch sizes. The device's share of each stage
+is read from a profiler trace (benchmark/record_trace.py)."""
+import sys, time
 sys.path.insert(0, "/root/repo")
 import numpy as np
 
-def main(n=4, e_tot=20000, bs=256, cap=65536, kcap=65536, timers=True):
+def main(n=4, e_tot=20000, bs=256, cap=65536, kcap=65536):
     from babble_tpu.devices import ensure_compile_cache
     ensure_compile_cache()
     from babble_tpu.ops.dag import synthetic_dag
     from babble_tpu.ops.incremental import IncrementalEngine
     dag, _ = synthetic_dag(n, e_tot, seed=5)
-    if timers:
-        os.environ["BABBLE_ENGINE_TIMERS"] = "1"
     eng = IncrementalEngine(n, capacity=cap, block=512, k_capacity=kcap)
     k = 0
     per = []
@@ -51,6 +50,5 @@ if __name__ == "__main__":
     ap.add_argument("--bs", type=int, default=256)
     ap.add_argument("--cap", type=int, default=65536)
     ap.add_argument("--kcap", type=int, default=65536)
-    ap.add_argument("--no-timers", action="store_true")
     a = ap.parse_args()
-    main(a.n, a.e, a.bs, a.cap, a.kcap, not a.no_timers)
+    main(a.n, a.e, a.bs, a.cap, a.kcap)
