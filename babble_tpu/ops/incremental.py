@@ -79,6 +79,12 @@ CTS_SENTINEL = np.iinfo(np.int64).min
 # need ns < -2^62, i.e. ~146 billion years before 1970).
 ZERO_TS_HI = -(2**31)
 
+# Lanes per step of _consensus_fused's timestamp loop: a dispatch runs
+# ceil(newly_count / chunk) steps of [chunk, n] gathers, where chunk is
+# this or the bucket cb if smaller. It equals cb's floor, so a pass that
+# receives no more than the floor bucket holds runs one step.
+TS_CHUNK = 1024
+
 
 def _ts_split(ts: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
     """Split int64 ns into order-preserving (hi, lo) int32 planes."""
@@ -352,14 +358,14 @@ def _fd_from_ranks(ranks, chain_len, creator, index, *, n):
 
 @functools.partial(
     jax.jit,
-    static_argnames=("n", "sm", "rcap", "bp", "rw", "iw", "cb", "tw"))
+    static_argnames=("n", "sm", "rcap", "bp", "rw", "iw", "cb", "tc", "tw"))
 def _consensus_fused(chain_la, chain_rb_tab, chain_len, la, ranks, rb_vec,
                      chain, wt_tab, fr_tab, wt_prev, fr_prev, t0, rho_min,
                      self_parent, creator, index, coin, e0, e1,
                      rounds_prev, rr_prev, fam_rel, in_list_rel,
                      chain_th, chain_tl, rx0, first_undec_prev, und_ids,
                      n_und, t_start,
-                     *, n, sm, rcap, bp, rw, iw, cb, tw):
+                     *, n, sm, rcap, bp, rw, iw, cb, tc, tw):
     """The whole per-sync consensus tail in one dispatch — frontier
     sweep, new-event rounds, fame merge, round-received — returning a
     single packed int32 buffer so the host pays exactly ONE
@@ -386,7 +392,10 @@ def _consensus_fused(chain_la, chain_rb_tab, chain_len, la, ranks, rb_vec,
     cb-compacted tail carries the newly-received lanes: sel_l[j] for
     j < newly_count is an undecided-window lane index, with its
     round-received and split-int64 consensus timestamp (see _ts_split)
-    in the matching positions of the other three planes.
+    in the matching positions of the other three planes. The timestamps
+    are computed `tc` lanes at a time for the first newly_count lanes
+    only; past the last chunk the cts planes read 0, and they read 0
+    throughout when newly_count > cb (the host redoes that dispatch).
 
     Besides the packed pull, the kernel returns updated `rounds` and
     `rr` DEVICE CARRIES (rounds_prev with the batch's rounds written,
@@ -500,49 +509,69 @@ def _consensus_fused(chain_la, chain_rb_tab, chain_len, la, ranks, rb_vec,
         newly_l = (rr_u >= 0) & (rr_u0 < 0) & lane_ok
         newly_count = newly_l.sum(dtype=jnp.int32)
 
-    # Consensus timestamps only for the lanes that were JUST assigned —
-    # compacted to a static [cb] bucket so the median machinery (the
-    # [rows, n] gathers and the per-row sort) scales with the sync's
-    # decisions, not with E. argsort(~newly_l) is stable, so the first
-    # newly_count lanes are exactly the newly-received lanes; if the
-    # bucket overflows (a late fame decision releasing a huge backlog),
-    # newly_count > cb tells the host to redo with a bigger bucket.
+    # Consensus timestamps only for the lanes that were JUST assigned.
+    # argsort(~newly_l) is stable, so the first newly_count entries of
+    # sel_l are exactly the newly-received lanes. The median machinery
+    # (the [rows, n] gathers and the per-row sort) runs over those
+    # lanes only, tc at a time, so it scales with this dispatch's
+    # decisions, not with the static bucket cb. If the bucket overflows
+    # (a late fame decision releasing a huge backlog), newly_count > cb
+    # tells the host to redo with a bigger bucket, and no chunk runs.
     with jax.named_scope("timestamps"):
         order = jnp.argsort(~newly_l)
         sel_l = order[:cb]  # [cb] lanes, newly lanes first
-        live = newly_l[sel_l]
-        sel_ids = uid[sel_l]
-        t_sel = jnp.clip(rr_u[sel_l] - i0, 0, iw - 1)
-        w_sel = wt_safe[t_sel]  # [cb, n]
-        fm_sel = fmask[t_sel]
-        idxw_sel = idx_w[t_sel]
-        cr_sel = creator[sel_ids]
-        ix_sel = index[sel_ids]
-        fd_sel = fd[sel_ids]  # [cb, n]
-        see_sel = la[w_sel, cr_sel[:, None]] >= ix_sel[:, None]
-        s_mask = see_sel & fm_sel
-        s_cnt = s_mask.sum(1)
-        valid_t = fd_sel <= idxw_sel  # first descendant reaches the witness
-        fd_pos = jnp.clip(fd_sel, 0, k - 1)
-        rows_n = jnp.arange(n)[None, :]
-        ts_hi = chain_th[rows_n, fd_pos]
-        ts_lo = chain_tl[rows_n, fd_pos]
-        # ZERO_TIME for unreached witnesses (sorts first); INT32_MAX pads
-        # the non-famous lanes to the end. Median by LEXICOGRAPHIC two-key
-        # sort — signed (hi, lo) order equals int64 ns order (_ts_split).
-        hi_v = jnp.where(valid_t, ts_hi, ZERO_TS_HI)
-        lo_v = jnp.where(valid_t, ts_lo, 0)
-        hi_m = jnp.where(s_mask, hi_v, INT32_MAX)
-        lo_m = jnp.where(s_mask, lo_v, INT32_MAX)
-        s_hi, s_lo = lax.sort((hi_m, lo_m), dimension=1, num_keys=2)
-        pick = (s_cnt // 2)[:, None]
-        med_hi = jnp.take_along_axis(s_hi, pick, axis=1)[:, 0]
-        med_lo = jnp.take_along_axis(s_lo, pick, axis=1)[:, 0]
-        # Results ride home cb-compacted: the first newly_count entries of
-        # sel_l are exactly the newly-received lanes (stable argsort), so
-        # the pull carries [cb] lanes+rr+cts instead of three au-wide
-        # planes — at n=1024 the undecided window is tens of thousands of
-        # lanes and this saves megabytes per pull.
+        # The loop's fd gathers read a re-laid-out copy of the rank cube
+        # (512 MiB at n=1024). Tied to newly_count, that copy is made
+        # after the round-received sweep; otherwise the compiler shares
+        # the frontier's copy and keeps it live through the sweep's peak.
+        ranks_ts = lax.optimization_barrier((ranks, newly_count))[0]
+        fd_ts = _FdRows(ranks_ts, chain_len, creator, index)
+
+        def chunk(j, med):
+            # When tc does not divide cb, the last chunk's slice and
+            # update clamp to the same earlier start: lanes computed twice
+            # get the same values.
+            at = j * tc
+            lanes = lax.dynamic_slice(sel_l, (at,), (tc,))
+            sel_ids = uid[lanes]
+            t_sel = jnp.clip(rr_u[lanes] - i0, 0, iw - 1)
+            w_sel = wt_safe[t_sel]  # [tc, n]
+            fm_sel = fmask[t_sel]
+            idxw_sel = idx_w[t_sel]
+            cr_sel = creator[sel_ids]
+            ix_sel = index[sel_ids]
+            fd_sel = fd_ts[sel_ids]  # [tc, n]
+            see_sel = la[w_sel, cr_sel[:, None]] >= ix_sel[:, None]
+            s_mask = see_sel & fm_sel
+            s_cnt = s_mask.sum(1)
+            valid_t = fd_sel <= idxw_sel  # first descendant reaches witness
+            fd_pos = jnp.clip(fd_sel, 0, k - 1)
+            rows_n = jnp.arange(n)[None, :]
+            ts_hi = chain_th[rows_n, fd_pos]
+            ts_lo = chain_tl[rows_n, fd_pos]
+            # ZERO_TIME for unreached witnesses (sorts first); INT32_MAX
+            # pads the non-famous lanes to the end. Median by
+            # LEXICOGRAPHIC two-key sort — signed (hi, lo) order equals
+            # int64 ns order (_ts_split).
+            hi_v = jnp.where(valid_t, ts_hi, ZERO_TS_HI)
+            lo_v = jnp.where(valid_t, ts_lo, 0)
+            hi_m = jnp.where(s_mask, hi_v, INT32_MAX)
+            lo_m = jnp.where(s_mask, lo_v, INT32_MAX)
+            s_hi, s_lo = lax.sort((hi_m, lo_m), dimension=1, num_keys=2)
+            pick = (s_cnt // 2)[:, None]
+            return tuple(
+                lax.dynamic_update_slice(
+                    m, jnp.take_along_axis(s, pick, axis=1)[:, 0], (at,))
+                for m, s in zip(med, (s_hi, s_lo)))
+
+        n_chunks = jnp.where(newly_count <= cb, (newly_count + tc - 1) // tc,
+                             0)
+        zero = jnp.zeros(cb, jnp.int32)
+        med_hi, med_lo = lax.fori_loop(0, n_chunks, chunk, (zero, zero))
+        # Results ride home cb-compacted: the pull carries [cb]
+        # lanes+rr+cts instead of three au-wide planes — at n=1024 the
+        # undecided window is tens of thousands of lanes and this saves
+        # megabytes per pull.
         rr_sel = rr_u[sel_l]
 
     # Post-pass device carries: the batch's rounds and this sync's rr
@@ -613,7 +642,7 @@ class PendingPass:
         "e0_b", "bp", "rounds_up", "rr_up",
         "und", "und_up", "n_und", "au",
         "undecided_set", "rx0",
-        "w_floor", "tw_floor", "rw", "iw", "cb", "tw", "rcap",
+        "w_floor", "tw_floor", "rw", "iw", "cb", "tc", "tw", "rcap",
         "tw_i", "t_start",
         "packed_dev", "rounds_out", "rr_out",
         "dispatched_ns",
@@ -1573,6 +1602,7 @@ class IncrementalEngine:
         # from the intact prediction instead of a stale bound.
         pp.tw_i = min(pp.tw, rcap)
         pp.t_start = min(pp.t0, rcap - pp.tw_i)
+        pp.tc = min(TS_CHUNK, pp.cb)
         fused_args = (
             self._chain_la, self._chain_rb, pp.chain_len_d, pp.la,
             self._ranks, pp.rb,
@@ -1586,7 +1616,7 @@ class IncrementalEngine:
             jnp.int32(self._prev_first_undec), pp.und_up, pp.n_und,
             jnp.int32(pp.t_start))
         fused_kw = dict(n=n, sm=sm, rcap=rcap, bp=pp.bp, rw=pp.rw,
-                        iw=pp.iw, cb=pp.cb, tw=pp.tw_i)
+                        iw=pp.iw, cb=pp.cb, tc=pp.tc, tw=pp.tw_i)
         return fused_args, fused_kw
 
     def request_cost_report(self) -> None:
@@ -1699,7 +1729,8 @@ class IncrementalEngine:
         ph = pp.phase_ns
         with self._span(pp, "engine.collect") as col:
             packed, newly_count, redo_ns = self._await_delta(pp, unlocked)
-            with self._span(pp, "engine.apply", newly=newly_count) as app:
+            with self._span(pp, "engine.apply", newly=newly_count,
+                            ts_chunks=self._dbg_windows["ts_chunks"]) as app:
                 delta = self._apply_delta(pp, packed, newly_count)
         ph["apply"] = app.t1 - app.t0
         # Collect's own host share: the redo decisions and the lock
@@ -1781,11 +1812,13 @@ class IncrementalEngine:
                         self._dispatch_fused(pp)
                     redo_ns += redo.t1 - redo.t0
                     continue
-                # Window-geometry diagnostics of the final dispatch.
+                # Window-geometry diagnostics of the final dispatch, and
+                # the timestamp chunks it ran (_consensus_fused).
                 self._dbg_windows = dict(
                     rcap=pp.rcap, rw=pp.rw, iw=pp.iw, cb=pp.cb, au=pp.au,
                     bp=pp.bp, tw=pp.tw_i, t0=pp.t0, t_end=t_end,
-                    rel_rows=pp.rel_rows)
+                    rel_rows=pp.rel_rows,
+                    ts_chunks=-(-newly_count // pp.tc))
                 return packed, newly_count, redo_ns
         finally:
             if _uctx is not None:

@@ -129,7 +129,7 @@ def pass_kernels(n, *, cols, chains, vec, cap=CAP, k=K, lanes=4 * BP):
          {"n": n, "m": M}),
         ("fused", inc._consensus_fused, fused_args,
          {"n": n, "sm": sm, "rcap": rcap, "bp": BP, "rw": w, "iw": w,
-          "cb": 1024, "tw": 16}),
+          "cb": 1024, "tc": 1024, "tw": 16}),
     ]
 
 

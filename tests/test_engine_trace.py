@@ -42,7 +42,7 @@ def test_fused_kernel_ops_carry_their_stage_scope():
             s((rcap, n)), s((rcap,), jnp.bool_), s((n, k)), s((n, k)), sc,
             sc, s((lanes,)), sc, sc)
     text = inc._consensus_fused.lower(
-        *args, n=n, sm=3, rcap=rcap, bp=16, rw=w, iw=w, cb=16,
+        *args, n=n, sm=3, rcap=rcap, bp=16, rw=w, iw=w, cb=16, tc=4,
         tw=16).compile().as_text()
     scopes = set(re.findall(r'op_name="jit\(_consensus_fused\)/(\w+)', text))
     assert set(STAGES) <= scopes
@@ -70,7 +70,9 @@ def feed(eng, dag, sync, stamps):
         stamps[pending.pass_id] = [t, None, None]
 
 
-def test_engine_spans_link_each_pass_across_threads():
+def test_engine_spans_link_each_pass_across_threads(monkeypatch):
+    # Timestamp chunks of 16 lanes: a pass's ts_chunks counts them.
+    monkeypatch.setattr(inc, "TS_CHUNK", 16)
     ring = SpanRing(4096)
     eng = IncrementalEngine(4, capacity=2048, block=64, k_capacity=1024,
                             trace=ring)
@@ -110,11 +112,14 @@ def test_engine_spans_link_each_pass_across_threads():
                    if sp["name"] == "engine.pull_xfer")
         apply, = [sp for sp in group if sp["name"] == "engine.apply"]
         assert apply["args"]["newly"] >= 0
+        assert apply["args"]["ts_chunks"] == -(-apply["args"]["newly"] // 16)
         for sp in group:
             if sp["name"] == "engine.redo":
                 redos += 1
                 assert sp["args"]["reason"] in ("rcap", "window", "cb")
     assert redos == eng.redo_count >= 1
+    assert any(sp["args"]["ts_chunks"] > 1 for sp in spans
+               if sp["name"] == "engine.apply")
     # The phase times a collect publishes are its pass's span stamps.
     last = {sp["name"]: sp for sp in by_pass[max(by_pass)]}
     ph = stamps[max(by_pass)][2]

@@ -25,9 +25,12 @@ from babble_tpu.hashgraph.event import Event
 from babble_tpu.hashgraph.root import Root
 from babble_tpu.hashgraph.round_info import Trilean
 from babble_tpu.hashgraph.tpu_graph import TpuHashgraph
+from babble_tpu.ops import incremental as inc
 from babble_tpu.ops.dag import synthetic_dag
 from babble_tpu.ops.incremental import CTS_SENTINEL, IncrementalEngine
 from babble_tpu.ops.pipeline import run_pipeline
+from babble_tpu.telemetry import SpanRing
+from benchmark import reference
 
 from fixtures import (
     build_consensus_graph,
@@ -90,6 +93,79 @@ def test_engine_matches_full_pipeline(n, e, bs, feed):
     # pipeline cts are ranks into dag.ts_values == arange(e); -1 = zero time
     cts_ns = np.where(cts < 0, CTS_SENTINEL, cts.astype(np.int64))
     assert (eng.cts_ns[:e][dec] == cts_ns[dec]).all()
+
+
+TS_BASE = 1_700_000_000_000_000_000  # no real timestamp's hi half is 0
+
+
+@pytest.mark.parametrize(
+    "n,e,sync,chunk,case",
+    [(4, 300, 10, 4, "full_chunks"), (16, 600, 50, 4, "partial_last_chunk"),
+     (16, 600, 50, 4, "nothing_received"), (4, 1500, 1500, 4, "cb_overflow"),
+     (4, 600, 600, 520, "chunk_not_dividing_cb")],
+    ids=lambda v: v if isinstance(v, str) else None)
+def test_timestamp_chunks_follow_the_pass(monkeypatch, n, e, sync, chunk,
+                                          case):
+    """The fused kernel's timestamp stage runs ceil(newly / chunk)
+    chunks of the lanes a dispatch received, none when it received
+    nothing or overflowed the bucket cb; round received and consensus
+    timestamps still equal the plain reference for every event."""
+    monkeypatch.setattr(inc, "TS_CHUNK", chunk)
+    dag, _ = synthetic_dag(n, e, seed=3)
+    ts = TS_BASE + np.arange(e, dtype=np.int64)
+    ring = SpanRing(1 << 14)
+    eng = IncrementalEngine(n, capacity=2048 if sync > 1024 else 64,
+                            block=64, k_capacity=8, trace=ring)
+    pulls = []  # (newly, cb, tc, chunks run, cts planes, redo) per pull
+    resize = eng._resize_windows
+
+    def record(pp, packed, t_end, newly):
+        ran = -(-newly // pp.tc) if newly <= pp.cb else 0
+        cb, tc = pp.cb, pp.tc
+        cts = packed[-2 * cb:].reshape(2, cb).copy()
+        redo = resize(pp, packed, t_end, newly)
+        pulls.append((newly, cb, tc, ran, cts, redo))
+        return redo
+
+    monkeypatch.setattr(eng, "_resize_windows", record)
+    for k in range(0, e, sync):
+        hi = min(k + sync, e)
+        eng.append_batch(dag.self_parent[k:hi], dag.other_parent[k:hi],
+                         dag.creator[k:hi], dag.index[k:hi], dag.coin[k:hi],
+                         ts[k:hi])
+        eng.run()
+    eng.close()
+
+    ref = reference.consensus(n, dag.self_parent[:e], dag.other_parent[:e],
+                              dag.creator[:e], dag.index[:e], dag.coin[:e],
+                              ts)
+    assert (eng.rounds[:e] == ref.rounds).all()
+    assert (eng.rr[:e] == ref.rr).all()
+    got = ref.rr >= 0
+    assert got.sum() > e // 4
+    assert (eng.cts_ns[:e][got] == ref.cts[got]).all()
+
+    for newly, cb, tc, ran, cts, _ in pulls:
+        assert tc == min(chunk, cb)
+        assert (cts[:, ran * tc:] == 0).all()  # no chunk past the count
+        assert (cts[0, :newly] != 0).all() or not ran
+    # Each collected pass's final pull, and its engine.apply span.
+    final = [p for p in pulls if p[5] is None]
+    applied = [sp["args"] for sp in ring.snapshot()
+               if sp["name"] == "engine.apply"]
+    assert [(a["newly"], a["ts_chunks"]) for a in applied] == [
+        (p[0], p[3]) for p in final]
+    hit = {
+        "full_chunks": any(p[0] >= 2 * p[2] and p[0] % p[2] == 0
+                           for p in final),
+        "partial_last_chunk": any(p[0] > p[2] and p[0] % p[2]
+                                  for p in final),
+        "nothing_received": any(p[0] == 0 for p in final),
+        "cb_overflow": any(p[0] > p[1] for p in pulls),
+        "chunk_not_dividing_cb": any(p[1] % p[2] and p[3] * p[2] > p[1]
+                                     for p in final),
+    }
+    assert hit[case], [(p[0], p[1], p[2], p[5]) for p in pulls]
 
 
 @pytest.mark.parametrize(
