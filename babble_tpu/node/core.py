@@ -45,6 +45,35 @@ def engine_sizes(n: int, opts: Optional[Dict] = None) -> Dict[str, int]:
     }
 
 
+def engine_mesh(devices, n: int, d: int, axis: str = "sp"):
+    """The mesh a node's device engine shards its participant axis over:
+    the first `d` of `devices` on one axis, or None for one device.
+    The participant columns split over the mesh, so the device count
+    must divide the `n` validators; `d` shrinks to the largest divisor
+    rather than refusing to boot (with a warning)."""
+    if not d or d <= 1:
+        return None
+    if len(devices) < d:
+        raise ValueError(
+            f"engine_mesh={d} but only {len(devices)} devices visible")
+    want = d
+    while d > 1 and n % d:
+        d -= 1
+    if d != want:
+        logging.getLogger("babble_tpu").warning(
+            "engine_mesh=%d does not divide %d validators; using %d "
+            "device(s)", want, n, d)
+    if d == 1:
+        return None
+    import numpy as np
+    from jax.sharding import Mesh
+
+    return Mesh(np.array(devices[:d]), (axis,))
+
+
+_engine_mesh = engine_mesh  # Core's `engine_mesh` argument shadows the name
+
+
 class Core:
     def __init__(
         self,
@@ -96,28 +125,7 @@ class Core:
             ensure_compile_cache()
 
             devs = jax.devices()
-            mesh = None
-            if engine_mesh and engine_mesh > 1:
-                import numpy as _np
-                from jax.sharding import Mesh
-
-                if len(devs) < engine_mesh:
-                    raise ValueError(
-                        f"engine_mesh={engine_mesh} but only "
-                        f"{len(devs)} devices visible")
-                # The participant columns shard over the mesh, so the
-                # validator count must divide the device count; shrink
-                # to the largest divisor rather than refusing to boot.
-                d = engine_mesh
-                while d > 1 and len(participants) % d:
-                    d -= 1
-                if d != engine_mesh:
-                    logging.getLogger("babble_tpu").warning(
-                        "engine_mesh=%d does not divide %d validators; "
-                        "using %d device(s)", engine_mesh,
-                        len(participants), d)
-                if d > 1:
-                    mesh = Mesh(_np.array(devs[:d]), ("sp",))
+            mesh = _engine_mesh(devs, len(participants), engine_mesh)
             self.hg: Hashgraph = TpuHashgraph(
                 participants, store, commit_callback, mesh=mesh,
                 prewarm=engine_prewarm, trace=self.trace,

@@ -85,6 +85,22 @@ ZERO_TS_HI = -(2**31)
 # receives no more than the floor bucket holds runs one step.
 TS_CHUNK = 1024
 
+# Bytes per device of the round-received sweep's [witnesses, lanes]
+# compare plane. The sweep walks the undecided window in chunks of
+# _rr_lanes(n, devices) lanes, split over a mesh's devices. At n=1024
+# on one chip, and at n=4096 on four, a chunk is 262,144 lanes, so
+# every window no wider than that is one chunk; a 1M-lane window at
+# n=4096 on four chips would be four.
+RR_PLANE_BYTES = 1 << 30
+
+
+def _rr_lanes(n: int, devices: int) -> int:
+    """Lanes per chunk of the round-received sweep: the largest power of
+    two whose [n, lanes] int32 plane, split over `devices`, stays within
+    RR_PLANE_BYTES on each device."""
+    lanes = max(RR_PLANE_BYTES * devices // (4 * n), devices)
+    return 1 << (lanes.bit_length() - 1)
+
 
 def _ts_split(ts: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
     """Split int64 ns into order-preserving (hi, lo) int32 planes."""
@@ -356,16 +372,60 @@ def _fd_from_ranks(ranks, chain_len, creator, index, *, n):
     return jnp.where((index[:e1] >= 0)[:, None], fd, INT32_MAX)
 
 
+def _rr_chunked(rr_u0, i0, la, wt_safe, cr_u, ix_u, rnd_u, lane_ok, n_und,
+                elig, fmask, fcnt, *, iw, rl, lanes):
+    """The round-received sweep: in step t, lanes whose famous witnesses
+    of round i0 + t see them by more than half receive that round. It
+    walks the undecided window in chunks of `rl` lanes, only as many as
+    hold the n_und live lanes. The lanes lie as [chunks, rl]
+    planes; on a mesh (`lanes`, the sharding of a lane vector) each
+    chunk's lanes are split over the devices, which then each compare
+    [n, rl / devices] against the witness rows, gathered whole once per
+    round. No device holds an [n, au] plane."""
+    au = rr_u0.shape[0]
+    nch = au // rl
+
+    def place(a, spec):
+        if lanes is None:
+            return a
+        return lax.with_sharding_constraint(
+            a, jax.sharding.NamedSharding(lanes.mesh, spec))
+
+    lane_spec = jax.sharding.PartitionSpec(None, *lanes.spec) if lanes else None
+    cr2, ix2, rnd2, ok2, rr2 = (place(a.reshape(nch, rl), lane_spec)
+                                for a in (cr_u, ix_u, rnd_u, lane_ok, rr_u0))
+    n_chunks = (n_und + rl - 1) // rl
+
+    def step(t, rr2):
+        i = i0 + t
+        la_w = place(la[wt_safe[t]], jax.sharding.PartitionSpec())
+
+        def chunk(j, rr2):
+            rr = rr2[j]
+            see_wx = la_w[:, cr2[j]] >= ix2[j][None, :]  # [n(w), rl]
+            s_cnt = (see_wx & fmask[t][:, None]).sum(0)
+            ok = (elig[t] & (s_cnt > fcnt[t] // 2) & (i > rnd2[j])
+                  & (rr < 0) & ok2[j])
+            return lax.dynamic_update_index_in_dim(
+                rr2, jnp.where(ok, i, rr), j, 0)
+
+        return lax.fori_loop(0, n_chunks, chunk, rr2)
+
+    return lax.fori_loop(0, iw, step, rr2).reshape(au)
+
+
 @functools.partial(
     jax.jit,
-    static_argnames=("n", "sm", "rcap", "bp", "rw", "iw", "cb", "tc", "tw"))
+    static_argnames=("n", "sm", "rcap", "bp", "rw", "iw", "cb", "tc", "tw",
+                     "rl", "lanes"))
 def _consensus_fused(chain_la, chain_rb_tab, chain_len, la, ranks, rb_vec,
                      chain, wt_tab, fr_tab, wt_prev, fr_prev, t0, rho_min,
                      self_parent, creator, index, coin, e0, e1,
                      rounds_prev, rr_prev, fam_rel, in_list_rel,
                      chain_th, chain_tl, rx0, first_undec_prev, und_ids,
                      n_und, t_start,
-                     *, n, sm, rcap, bp, rw, iw, cb, tc, tw):
+                     *, n, sm, rcap, bp, rw, iw, cb, tc, tw, rl,
+                     lanes=None):
     """The whole per-sync consensus tail in one dispatch — frontier
     sweep, new-event rounds, fame merge, round-received — returning a
     single packed int32 buffer so the host pays exactly ONE
@@ -396,6 +456,9 @@ def _consensus_fused(chain_la, chain_rb_tab, chain_len, la, ranks, rb_vec,
     are computed `tc` lanes at a time for the first newly_count lanes
     only; past the last chunk the cts planes read 0, and they read 0
     throughout when newly_count > cb (the host redoes that dispatch).
+    The round-received sweep walks the undecided window in chunks of
+    `rl` lanes, only as many as hold its live lanes (_rr_chunked); on a
+    mesh `lanes` splits each chunk's lanes over the devices.
 
     Besides the packed pull, the kernel returns updated `rounds` and
     `rr` DEVICE CARRIES (rounds_prev with the batch's rounds written,
@@ -496,16 +559,9 @@ def _consensus_fused(chain_la, chain_rb_tab, chain_len, la, ranks, rb_vec,
         # Pad lanes are never assigned.
         rr_u0 = jnp.where(lane_ok, rr_prev[uid], 0)
 
-        def step(t, rr_u):
-            i = i0 + t
-            la_w = la[wt_safe[t]]  # [n(w), n]
-            see_wx = la_w[:, cr_u] >= ix_u[None, :]  # [n(w), au]
-            s_cnt = (see_wx & fmask[t][:, None]).sum(0)
-            ok = (elig[t] & (s_cnt > fcnt[t] // 2) & (i > rnd_u)
-                  & (rr_u < 0) & lane_ok)
-            return jnp.where(ok, i, rr_u)
-
-        rr_u = lax.fori_loop(0, iw, step, rr_u0)
+        rr_u = _rr_chunked(rr_u0, i0, la, wt_safe, cr_u, ix_u, rnd_u,
+                           lane_ok, n_und, elig, fmask, fcnt,
+                           iw=iw, rl=min(rl, au), lanes=lanes)
         newly_l = (rr_u >= 0) & (rr_u0 < 0) & lane_ok
         newly_count = newly_l.sum(dtype=jnp.int32)
 
@@ -643,7 +699,7 @@ class PendingPass:
         "und", "und_up", "n_und", "au",
         "undecided_set", "rx0",
         "w_floor", "tw_floor", "rw", "iw", "cb", "tc", "tw", "rcap",
-        "tw_i", "t_start",
+        "tw_i", "t_start", "rl", "counts",
         "packed_dev", "rounds_out", "rr_out",
         "dispatched_ns",
         "ready", "error",
@@ -724,12 +780,15 @@ class IncrementalEngine:
         # one-shot sharded pipeline makes (ops/sharded.py).
         self._mesh = mesh
         self._mesh_axis = mesh_axis
+        # Devices the participant axis is split over; the round-received
+        # sweep's lanes split over them too (_rr_chunked).
+        self.devices = 1
         if mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec as P
 
             from .sharded import _axis_size
 
-            daxes = _axis_size(mesh, mesh_axis)
+            self.devices = daxes = _axis_size(mesh, mesh_axis)
             if n % daxes:
                 raise ValueError(
                     f"participants {n} must divide over {daxes} devices")
@@ -747,6 +806,7 @@ class IncrementalEngine:
         # device and then split, the n=4096 coordinate table (8.6 GB at
         # capacity 524288) did not fit one v5e chip.
         cols, ch = self._shard_cols, self._shard_ch
+        t_init = time.perf_counter_ns()
         self._la = jnp.full((c1, n), -1, jnp.int32, device=cols)
         self._rb = jnp.full((c1,), -1, jnp.int32)
         self._frozen_blocks = 0
@@ -778,6 +838,9 @@ class IncrementalEngine:
                                   device=ch)
         self._chain_rb = jnp.full((n, self.kcap), INT32_MAX, jnp.int32,
                                   device=ch)
+        self.trace.record("engine.init", t_init, time.perf_counter_ns(),
+                          cat="engine.init", n=n, devices=self.devices,
+                          capacity=self.cap, k_capacity=self.kcap)
         self._e_counted = 0
         self._len_counted = np.zeros(n, np.int32)
 
@@ -863,6 +926,12 @@ class IncrementalEngine:
         # consensus: engine.collect outside those and the redos (the
         # redo decisions).
         self.phase_ns: dict = {}
+        # Counts of the last collected pass, published with phase_ns:
+        # au (the undecided-window bucket swept), n_und (its live
+        # lanes), iw (rounds swept), rr_chunks (chunks of the
+        # round-received sweep) and ts_chunks (chunks of the timestamp
+        # loop), both of the final dispatch.
+        self.pass_counts: dict = {}
         # Compiled-cost attribution (docs/observability.md "Device
         # profiling"): request_cost_report() arms a one-shot capture;
         # the next fused-epilogue dispatch AOT-lowers the kernel with
@@ -1089,7 +1158,7 @@ class IncrementalEngine:
         return self._chain_d.shape[1]
 
     def _sync_device(self, cap_t: Optional[int] = None,
-                     kcap_t: Optional[int] = None) -> None:
+                     kcap_t: Optional[int] = None, pass_id: int = 0) -> None:
         """Bring the device carries up to the host mirrors' capacity and
         chain-bucket sizes (appends grow host state only). All growth is
         device-side concatenation — no device->host round trips.
@@ -1097,37 +1166,45 @@ class IncrementalEngine:
         `cap_t`/`kcap_t` (default: the live fields) let a pass grow to
         its SNAPSHOT sizes: the pass may run outside the caller's lock,
         and a concurrent append crossing a growth boundary must not
-        change this pass's kernel shapes mid-flight."""
+        change this pass's kernel shapes mid-flight. Each doubling runs
+        under an `engine.grow` span of the pass (`what`, `old`, `new`)."""
         if cap_t is None:
             cap_t = self.cap
         if kcap_t is None:
             kcap_t = self.kcap
-        n = self.n
+
+        def grow(what, old):
+            return self.trace.span("engine.grow", cat="engine",
+                                   pass_id=pass_id, what=what, old=old,
+                                   new=2 * old)
+
         while self._cap_dev < cap_t:
             rows = self._cap_dev  # double
-            self._la = _pad_rows(self._la, rows=rows, fill=-1)
-            self._rb = _pad_rows(self._rb, rows=rows, fill=-1)
-            self._sp_d = _pad_rows(self._sp_d, rows=rows, fill=-1)
-            self._op_d = _pad_rows(self._op_d, rows=rows, fill=-1)
-            self._cr_d = _pad_rows(self._cr_d, rows=rows, fill=0)
-            self._idx_d = _pad_rows(self._idx_d, rows=rows, fill=-1)
-            self._coin_d = _pad_rows(self._coin_d, rows=rows, fill=0)
-            self._rb0_d = _pad_rows(self._rb0_d, rows=rows, fill=-1)
+            with grow("capacity", rows):
+                self._la = _pad_rows(self._la, rows=rows, fill=-1)
+                self._rb = _pad_rows(self._rb, rows=rows, fill=-1)
+                self._sp_d = _pad_rows(self._sp_d, rows=rows, fill=-1)
+                self._op_d = _pad_rows(self._op_d, rows=rows, fill=-1)
+                self._cr_d = _pad_rows(self._cr_d, rows=rows, fill=0)
+                self._idx_d = _pad_rows(self._idx_d, rows=rows, fill=-1)
+                self._coin_d = _pad_rows(self._coin_d, rows=rows, fill=0)
+                self._rb0_d = _pad_rows(self._rb0_d, rows=rows, fill=-1)
         while self._rounds_d.shape[0] < cap_t:
             rows = self._rounds_d.shape[0]  # double
             self._rounds_d = _pad_rows(self._rounds_d, rows=rows, fill=-1)
             self._rr_d = _pad_rows(self._rr_d, rows=rows, fill=-1)
         while self._kcap_dev < kcap_t:
             cols = self._kcap_dev  # double
-            self._ranks = _pad_ranks(
-                self._ranks, jnp.asarray(self._len_counted), cols=cols)
-            self._chain_la = _pad_cols(self._chain_la, cols=cols,
-                                       fill=INT32_MAX, axis=1)
-            self._chain_d = _pad_cols(self._chain_d, cols=cols, fill=-1)
-            self._chain_th = _pad_cols(self._chain_th, cols=cols, fill=0)
-            self._chain_tl = _pad_cols(self._chain_tl, cols=cols, fill=0)
-            self._chain_rb = _pad_cols(self._chain_rb, cols=cols,
-                                       fill=INT32_MAX)
+            with grow("chains", cols):
+                self._ranks = _pad_ranks(
+                    self._ranks, jnp.asarray(self._len_counted), cols=cols)
+                self._chain_la = _pad_cols(self._chain_la, cols=cols,
+                                           fill=INT32_MAX, axis=1)
+                self._chain_d = _pad_cols(self._chain_d, cols=cols, fill=-1)
+                self._chain_th = _pad_cols(self._chain_th, cols=cols, fill=0)
+                self._chain_tl = _pad_cols(self._chain_tl, cols=cols, fill=0)
+                self._chain_rb = _pad_cols(self._chain_rb, cols=cols,
+                                           fill=INT32_MAX)
 
     def _ingest_batch(self, e: int, chain_len0: np.ndarray):
         """Stage the events appended since the last run into the device
@@ -1365,7 +1442,7 @@ class IncrementalEngine:
             # a mesh, re-pin the carries first (growth concats and
             # kernel outputs may drift from the intended shardings).
             with self._span(pp, "engine.ingest") as ing:
-                self._sync_device(cap0, pp.k0)
+                self._sync_device(cap0, pp.k0, pp.pass_id)
                 self._constrain_carries()
                 self._ingest_batch(e, pp.chain_len0)
                 pp.chain_len_d = jnp.asarray(pp.chain_len0)
@@ -1603,6 +1680,7 @@ class IncrementalEngine:
         pp.tw_i = min(pp.tw, rcap)
         pp.t_start = min(pp.t0, rcap - pp.tw_i)
         pp.tc = min(TS_CHUNK, pp.cb)
+        pp.rl = _rr_lanes(n, self.devices)
         fused_args = (
             self._chain_la, self._chain_rb, pp.chain_len_d, pp.la,
             self._ranks, pp.rb,
@@ -1616,7 +1694,8 @@ class IncrementalEngine:
             jnp.int32(self._prev_first_undec), pp.und_up, pp.n_und,
             jnp.int32(pp.t_start))
         fused_kw = dict(n=n, sm=sm, rcap=rcap, bp=pp.bp, rw=pp.rw,
-                        iw=pp.iw, cb=pp.cb, tc=pp.tc, tw=pp.tw_i)
+                        iw=pp.iw, cb=pp.cb, tc=pp.tc, tw=pp.tw_i, rl=pp.rl,
+                        lanes=self._shard_ch)
         return fused_args, fused_kw
 
     def request_cost_report(self) -> None:
@@ -1729,17 +1808,21 @@ class IncrementalEngine:
         ph = pp.phase_ns
         with self._span(pp, "engine.collect") as col:
             packed, newly_count, redo_ns = self._await_delta(pp, unlocked)
+            counts = pp.counts
             with self._span(pp, "engine.apply", newly=newly_count,
-                            ts_chunks=self._dbg_windows["ts_chunks"]) as app:
+                            ts_chunks=counts["ts_chunks"],
+                            rr_chunks=counts["rr_chunks"],
+                            au=counts["au"]) as app:
                 delta = self._apply_delta(pp, packed, newly_count)
         ph["apply"] = app.t1 - app.t0
         # Collect's own host share: the redo decisions and the lock
         # hand-over, outside the waits, pulls, redos and the apply.
         ph["consensus"] = (col.t1 - col.t0 - ph["c_stage_wait"]
                            - ph["c_pull"] - redo_ns - ph["apply"])
-        # One assignment of the finished dict: a reader after the next
+        # One assignment of each finished dict: a reader after the next
         # dispatch sees this pass whole, never a part of the next one.
         self.phase_ns = ph
+        self.pass_counts = counts
         # An append that slipped in during the unlocked wait means the
         # state is NOT at a fixpoint yet.
         self._empty_delta_ok = not self._new_since_run
@@ -1812,13 +1895,19 @@ class IncrementalEngine:
                         self._dispatch_fused(pp)
                     redo_ns += redo.t1 - redo.t0
                     continue
-                # Window-geometry diagnostics of the final dispatch, and
-                # the timestamp chunks it ran (_consensus_fused).
-                self._dbg_windows = dict(
-                    rcap=pp.rcap, rw=pp.rw, iw=pp.iw, cb=pp.cb, au=pp.au,
-                    bp=pp.bp, tw=pp.tw_i, t0=pp.t0, t_end=t_end,
-                    rel_rows=pp.rel_rows,
+                # The chunks the final dispatch ran (_consensus_fused):
+                # the sweep's, as many as hold the window's live lanes,
+                # and the timestamp loop's.
+                n_und = len(pp.und)
+                pp.counts = dict(
+                    au=pp.au, n_und=n_und, iw=pp.iw,
+                    rr_chunks=-(-n_und // min(pp.rl, pp.au)),
                     ts_chunks=-(-newly_count // pp.tc))
+                # Window-geometry diagnostics of the final dispatch.
+                self._dbg_windows = dict(
+                    rcap=pp.rcap, rw=pp.rw, cb=pp.cb, bp=pp.bp,
+                    tw=pp.tw_i, t0=pp.t0, t_end=t_end,
+                    rel_rows=pp.rel_rows, **pp.counts)
                 return packed, newly_count, redo_ns
         finally:
             if _uctx is not None:

@@ -31,8 +31,10 @@ passed.
 four chips is bit-identical to the same engine on device 0 alone; at
 n=4096 (BASELINE.json config 5, 80 events per validator, enough for
 rounds to be decided) the mesh engine runs alone, must commit events,
-and prints its bytes per device. The n=4096 phase does not pass yet
-(PERF.md, PR 21).
+and prints its bytes per device. Both meshes are built as a node builds
+its engine's (node.core.engine_mesh). The n=4096 phase is not yet
+verified on the chip: it failed before the round-received sweep was
+chunked, and has not been run since (PERF.md).
 """
 
 from __future__ import annotations
@@ -366,13 +368,12 @@ def mesh_engines(devices, n: int = 1024, e: int = 100_000,
     """--four-chips: mesh vs device 0 at n, then the mesh alone at
     n_big. Yields (phase, t0, fields)."""
     import numpy as np
-    from jax.sharding import Mesh
 
-    from babble_tpu.node.core import engine_sizes
+    from babble_tpu.node.core import engine_mesh, engine_sizes
     from babble_tpu.ops.dag import synthetic_dag
     from babble_tpu.ops.incremental import IncrementalEngine
 
-    mesh = Mesh(np.array(devices), ("sp",))
+    mesh = engine_mesh(devices, n, len(devices))
     t0 = time.monotonic()
     dag, _ = synthetic_dag(n, e, seed=seed)
     t1 = time.monotonic()
@@ -395,10 +396,11 @@ def mesh_engines(devices, n: int = 1024, e: int = 100_000,
     # n=1024 and n=2048 (CPU one-shot runs, PR 21); 80 leaves a margin.
     # The engine is presized for the run through engine_opts, as a node
     # is (Core(engine_opts=...)): growth steps would only add compiles.
-    # A sync is 4 events per validator, as at n=1024. On v5e:2x2 this
-    # phase fails today: the fused epilogue does not fit once the
-    # undecided window passes 262144 events (PERF.md; the compile test
-    # test_mesh_epilogue_refuses_the_first_decision_backlog).
+    # A sync is 4 events per validator, as at n=1024. An undecided
+    # window past 262144 events before the first decision lands in the
+    # 1M-lane bucket, which the fused epilogue's round-received sweep
+    # walks in chunks split over the chips (ops/incremental.py
+    # _rr_chunked).
     t0 = time.monotonic()
     e_big = n_big * per_big
     dag, _ = synthetic_dag(n_big, e_big, seed=seed)
@@ -407,6 +409,7 @@ def mesh_engines(devices, n: int = 1024, e: int = 100_000,
         "capacity": 1 << e_big.bit_length(),
         "k_capacity": 1 << k_max.bit_length()})
     t1 = time.monotonic()
+    mesh = engine_mesh(devices, n_big, len(devices))
     big = drive(IncrementalEngine(n_big, mesh=mesh, **sizes), dag,
                 sync=4 * n_big)
     t2 = time.monotonic()

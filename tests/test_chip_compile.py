@@ -21,6 +21,11 @@ CAP = 65536  # node presize (node/core.py engine_sizes)
 K = 64  # engine_sizes' chain bucket at n=1024 and n=4096
 BP = 4096  # one 4096-event sync
 M = 16  # new events per creator in a sync: the x4 bucket floor
+# The v4096-mesh-replay job's largest shapes: capacity and chain bucket
+# grown to hold 88 events per validator, 16,384-event syncs, and the
+# 1M-lane bucket a window past 262,144 undecided events lands in (the
+# job's own window peaks just under that on a v5e 2x2 host).
+BACKLOG = {"cap": 1 << 19, "k": 128, "lanes": 1 << 20, "bp": 16384}
 
 
 @pytest.fixture(scope="module")
@@ -81,13 +86,15 @@ def fits(compiled, devices: int = 1) -> int:
     return need
 
 
-def pass_kernels(n, *, cols, chains, vec, cap=CAP, k=K, lanes=4 * BP):
+def pass_kernels(n, *, cols, chains, vec, cap=CAP, k=K, lanes=4 * BP,
+                 bp=BP):
     """(name, jitted kernel, args, static kwargs) for each dispatch of
     one engine pass (ops/incremental.py _stage_pass): ingest, chain
     ingest, closure, fd-rank fold, fused consensus epilogue. `cols`,
     `chains` and `vec` shard the [cap, n] carries, the chain-axis
-    carries and everything else. `lanes` is the undecided window the
-    round-received sweep walks."""
+    carries and everything else; on a mesh the round-received sweep's
+    lanes split as the chain axis does. `lanes` is the undecided window
+    the sweep walks, `bp` the sync's batch bucket."""
     import jax
     import jax.numpy as jnp
 
@@ -116,10 +123,11 @@ def pass_kernels(n, *, cols, chains, vec, cap=CAP, k=K, lanes=4 * BP):
         s((cap,)), s((cap,)), s((rcap, n)), s((rcap,), jnp.bool_),
         chain[1], chain[2], scalar, scalar, s((lanes,)), scalar, scalar)
     sm = 2 * n // 3 + 1
+    devices = len(chains.device_set)
     return [
         ("ingest", inc._ingest_nd if mesh else inc._ingest,
-         (*ev, coin, rb0, *(s((BP,)) for _ in range(4)), s((BP,), i8),
-          s((BP,)), scalar), {"bp": BP}),
+         (*ev, coin, rb0, *(s((bp,)) for _ in range(4)), s((bp,), i8),
+          s((bp,)), scalar), {"bp": bp}),
         ("chain_ingest", inc._chain_ingest_nd if mesh else inc._chain_ingest,
          (*chain, *new), {"n": n, "m": M}),
         ("closure", inc._closure_update_nd if mesh else inc._closure_update,
@@ -128,8 +136,9 @@ def pass_kernels(n, *, cols, chains, vec, cap=CAP, k=K, lanes=4 * BP):
          (ranks, chain_la, chain_rb, la, rb, new[0], new[1]),
          {"n": n, "m": M}),
         ("fused", inc._consensus_fused, fused_args,
-         {"n": n, "sm": sm, "rcap": rcap, "bp": BP, "rw": w, "iw": w,
-          "cb": 1024, "tc": 1024, "tw": 16}),
+         {"n": n, "sm": sm, "rcap": rcap, "bp": bp, "rw": w, "iw": w,
+          "cb": 1024, "tc": 1024, "tw": 16,
+          "rl": inc._rr_lanes(n, devices), "lanes": chains if mesh else None}),
     ]
 
 
@@ -142,22 +151,32 @@ def test_engine_pass_compiles_for_one_chip(one_chip, tpu_branches, kernel):
     fits(fn.lower(*args, **kw).compile())
 
 
-@pytest.mark.parametrize(
-    "kernel", ["ingest", "chain_ingest", "closure", "tables", "fused"])
-def test_engine_pass_compiles_on_four_chip_mesh(mesh, tpu_branches, kernel):
-    """A steady pass of the mesh-resident engine at n=4096 on v5e:2x2:
-    participant columns and chain axes sharded as ops/incremental.py
-    places them; every device's share fits its HBM."""
+def mesh_kernel(mesh, kernel, **shapes):
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     (_, fn, args, kw), = [k for k in pass_kernels(
         N_MESH, cols=NamedSharding(mesh, P(None, "sp")),
         chains=NamedSharding(mesh, P("sp")),
-        vec=NamedSharding(mesh, P())) if k[0] == kernel]
-    compiled = fn.lower(*args, **kw).compile()
-    need = fits(compiled, devices=4)
-    # Unsharded, the rank cube and the chain tables alone are 8 GiB.
-    assert need < 2 * 4 * N_MESH * K * N_MESH
+        vec=NamedSharding(mesh, P()), **shapes) if k[0] == kernel]
+    return fn.lower(*args, **kw).compile()
+
+
+@pytest.mark.parametrize(
+    "kernel", ["ingest", "chain_ingest", "closure", "tables", "fused",
+               "ingest-backlog", "chain_ingest-backlog", "closure-backlog",
+               "tables-backlog"])
+def test_engine_pass_compiles_on_four_chip_mesh(mesh, tpu_branches, kernel):
+    """A pass of the mesh-resident engine at n=4096 on v5e:2x2, steady
+    (node presize) or at the v4096-mesh-replay job's largest shapes
+    (`-backlog`): participant columns and chain axes sharded as
+    ops/incremental.py places them; every device's share fits its HBM."""
+    kernel, _, backlog = kernel.partition("-")
+    k = BACKLOG["k"] if backlog else K
+    need = fits(mesh_kernel(mesh, kernel, **(BACKLOG if backlog else {})),
+                devices=4)
+    # Unsharded, the rank cube and the chain tables alone are 8 GiB
+    # (16 GiB at the backlog's chain bucket).
+    assert need < 2 * 4 * N_MESH * k * N_MESH
 
 
 def test_p256_ladder_compiles_at_512_lanes(one_chip):
@@ -190,20 +209,19 @@ def test_pallas_strongly_see_compiles(one_chip):
     fits(compiled)
 
 
-def test_mesh_epilogue_refuses_the_first_decision_backlog(mesh, tpu_branches):
-    """A known refusal, asserted so that it stays in view. Random gossip
-    at n=4096 decides nothing before about 65 events per validator, so
-    the undecided window passes 262144 lanes and lands in the 1M-lane
-    bucket. The fused epilogue's round-received sweep then gathers la
-    columns for every lane, [1048576, 4096] int32, replicated on each
-    device: 18.04 GB of 15.75. The v5e:2x2 chip refused it the same way
-    (PR 21). When that sweep is sharded or chunked, this compile fits:
-    turn the test into a `fits` check."""
-    from jax.sharding import NamedSharding, PartitionSpec as P
+def test_mesh_epilogue_fits_the_first_decision_backlog(mesh, tpu_branches):
+    """Random gossip at n=4096 decides nothing before about 65 events per
+    validator, so the undecided window can pass 262144 lanes and land in
+    the 1M-lane bucket. Swept whole, its [4096, 1048576] int32 compare
+    plane sat on every device (18.04 GB of 15.75; the chip refused it
+    too). The sweep walks it in four chunks of 262,144 lanes, each split
+    over the four devices: one [4096, 65536] plane per device, and no
+    device holds a 4 GiB plane."""
+    from babble_tpu.ops import incremental as inc
 
-    (_, fn, args, kw), = [k for k in pass_kernels(
-        N_MESH, cols=NamedSharding(mesh, P(None, "sp")),
-        chains=NamedSharding(mesh, P("sp")), vec=NamedSharding(mesh, P()),
-        cap=1 << 19, k=128, lanes=1 << 20) if k[0] == "fused"]
-    with pytest.raises(Exception, match="RESOURCE_EXHAUSTED.*hbm"):
-        fn.lower(*args, **kw).compile()
+    assert inc._rr_lanes(N_MESH, 4) == BACKLOG["lanes"] // 4
+    compiled = mesh_kernel(mesh, "fused", **BACKLOG)
+    fits(compiled, devices=4)
+    text = compiled.as_text()
+    assert "[4096,65536]" in text
+    assert "[4096,1048576]" not in text and "[4096,262144]" not in text

@@ -43,7 +43,7 @@ def test_fused_kernel_ops_carry_their_stage_scope():
             sc, s((lanes,)), sc, sc)
     text = inc._consensus_fused.lower(
         *args, n=n, sm=3, rcap=rcap, bp=16, rw=w, iw=w, cb=16, tc=4,
-        tw=16).compile().as_text()
+        tw=16, rl=lanes).compile().as_text()
     scopes = set(re.findall(r'op_name="jit\(_consensus_fused\)/(\w+)', text))
     assert set(STAGES) <= scopes
 

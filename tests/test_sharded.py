@@ -119,3 +119,92 @@ def test_node_engine_mesh_gossip():
         assert len(eng._la.sharding.device_set) == 4
     run_gossip(nodes, target_round=3, timeout=300.0)
     check_gossip(nodes)
+
+
+def test_engine_mesh_shrinks_to_a_divisor(caplog):
+    """The mesh a node builds for its engine (Core, chip_smoke and the
+    benchmark's mesh driver share it): the first d devices on `sp`, d
+    shrunk to the largest divisor of the validator count."""
+    from babble_tpu.node.core import engine_mesh
+
+    devs = jax.devices()
+    assert engine_mesh(devs, 16, 0) is None
+    assert engine_mesh(devs, 16, 1) is None
+    mesh = engine_mesh(devs, 4096, 4)
+    assert mesh.axis_names == ("sp",)
+    assert list(mesh.devices.flat) == devs[:4]
+    with caplog.at_level("WARNING", logger="babble_tpu"):
+        assert engine_mesh(devs, 12, 8).devices.size == 6
+        assert engine_mesh(devs, 7, 4) is None
+    assert sum("does not divide" in r.getMessage()
+               for r in caplog.records) == 2
+    with pytest.raises(ValueError, match="only 2 devices"):
+        engine_mesh(devs[:2], 16, 4)
+
+
+def _feed(eng, dag, syncs):
+    """Append and run the DAG in syncs of the given sizes; the pass
+    counts of each pass."""
+    counts, k = [], 0
+    for size in syncs:
+        hi = min(k + size, dag.e)
+        eng.append_batch(dag.self_parent[k:hi], dag.other_parent[k:hi],
+                         dag.creator[k:hi], dag.index[k:hi], dag.coin[k:hi],
+                         np.arange(k, hi))
+        eng.run()
+        counts.append(dict(eng.pass_counts))
+        k = hi
+    eng.close()
+    return counts
+
+
+@pytest.mark.parametrize(
+    "where,syncs",
+    [("mesh4", [150] * 10), ("mesh4", [1200, 150, 150]),
+     ("one_device", [1200, 150, 150])],
+    ids=["mesh4-steady", "mesh4-backlog", "one-device-backlog"])
+def test_round_received_sweep_in_chunks(monkeypatch, where, syncs):
+    """The round-received sweep walked in chunks of 64 lanes (forced
+    through RR_PLANE_BYTES), on a 4-device mesh with each chunk's lanes
+    split over the devices or on one device, equals the one-chunk
+    engine and the plain reference bit for bit: rounds, witness, fame,
+    round received and consensus timestamps. The backlog cases' first
+    sync is deep enough that its first decisions release hundreds of
+    events in one pass."""
+    from babble_tpu.node.core import engine_mesh
+    from babble_tpu.ops import incremental as inc
+    from babble_tpu.ops.incremental import IncrementalEngine
+    from benchmark import reference
+
+    n = 16
+    dag, _ = synthetic_dag(n, sum(syncs), seed=7)
+    e = dag.e
+    sizes = dict(capacity=256, block=64, k_capacity=8)
+    one = IncrementalEngine(n, **sizes)
+    whole = _feed(one, dag, syncs)
+    assert all(c["rr_chunks"] == (c["n_und"] > 0) for c in whole)
+
+    mesh = engine_mesh(jax.devices(), n, 4) if where == "mesh4" else None
+    monkeypatch.setattr(inc, "RR_PLANE_BYTES", 64 * 4 * n // (4 if mesh else 1))
+    eng = IncrementalEngine(n, mesh=mesh, **sizes)
+    assert eng.devices == (4 if mesh else 1)
+    counts = _feed(eng, dag, syncs)
+    assert max(c["rr_chunks"] for c in counts) >= 3
+    for c in counts:
+        assert c["au"] > 64
+        assert c["rr_chunks"] == -(-c["n_und"] // 64)
+
+    for name in ("rounds", "witness", "rr", "cts_ns"):
+        assert (getattr(eng, name)[:e] == getattr(one, name)[:e]).all(), name
+    assert (eng.famous == one.famous).all()
+    ref = reference.consensus(n, dag.self_parent[:e], dag.other_parent[:e],
+                              dag.creator[:e], dag.index[:e], dag.coin[:e],
+                              np.arange(e))
+    got = ref.rr >= 0
+    assert got.sum() > e // 4
+    assert (eng.rounds[:e] == ref.rounds).all()
+    assert (eng.witness[:e] == ref.witness).all()
+    assert (eng.rr[:e] == ref.rr).all()
+    assert (eng.cts_ns[:e][got] == ref.cts[got]).all()
+    r = len(eng.famous)
+    assert (eng.famous == ref.famous[:r]).all()
