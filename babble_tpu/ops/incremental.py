@@ -18,7 +18,8 @@ per-sync work instead of full-DAG recompute:
                 descendants (a new first-descendant index always
                 exceeds the old event's last-ancestor index).
   fame          kernels.decide_fame over a round window starting at the
-                first undecided round. Window-relative round numbers
+                first undecided round, voting through its last row
+                that holds a witness. Window-relative round numbers
                 preserve the vote/coin semantics exactly: diff = j - rx
                 is shift-invariant, so first-round votes and coin
                 rounds (diff % n) land identically.
@@ -372,16 +373,24 @@ def _fd_from_ranks(ranks, chain_len, creator, index, *, n):
     return jnp.where((index[:e1] >= 0)[:, None], fd, INT32_MAX)
 
 
+def _rr_steps(elig):
+    """The steps the round-received sweep runs over the window's rounds
+    [i0, i0 + iw): through the last eligible one, none when no round is
+    eligible. A step whose round is not eligible assigns nothing."""
+    return jnp.max(jnp.where(elig, jnp.arange(1, elig.shape[0] + 1), 0))
+
+
 def _rr_chunked(rr_u0, i0, la, wt_safe, cr_u, ix_u, rnd_u, lane_ok, n_und,
-                elig, fmask, fcnt, *, iw, rl, lanes):
+                elig, fmask, fcnt, steps, *, rl, lanes):
     """The round-received sweep: in step t, lanes whose famous witnesses
     of round i0 + t see them by more than half receive that round. It
-    walks the undecided window in chunks of `rl` lanes, only as many as
-    hold the n_und live lanes. The lanes lie as [chunks, rl]
-    planes; on a mesh (`lanes`, the sharding of a lane vector) each
-    chunk's lanes are split over the devices, which then each compare
-    [n, rl / devices] against the witness rows, gathered whole once per
-    round. No device holds an [n, au] plane."""
+    runs `steps` steps (_rr_steps) and walks the undecided window in
+    chunks of `rl` lanes, only as many as hold the n_und live lanes.
+    The lanes lie as [chunks, rl] planes; on a mesh (`lanes`, the
+    sharding of a lane vector) each chunk's lanes are split over the
+    devices, which then each compare [n, rl / devices] against the
+    witness rows, gathered whole once per round. No device holds an
+    [n, au] plane."""
     au = rr_u0.shape[0]
     nch = au // rl
 
@@ -411,7 +420,7 @@ def _rr_chunked(rr_u0, i0, la, wt_safe, cr_u, ix_u, rnd_u, lane_ok, n_und,
 
         return lax.fori_loop(0, n_chunks, chunk, rr2)
 
-    return lax.fori_loop(0, iw, step, rr2).reshape(au)
+    return lax.fori_loop(0, steps, step, rr2).reshape(au)
 
 
 @functools.partial(
@@ -445,9 +454,13 @@ def _consensus_fused(chain_la, chain_rb_tab, chain_len, la, ranks, rb_vec,
 
     Packed layout (every plane is window-sized, never E- or cap-sized,
     so the pull stays small; its cost on the v5e chip is not measured):
-    [t_end, newly_count, wt_win(tw*n), fr_win(tw*n), new_rounds(bp),
-    new_wit(bp), famous_merged(rw*n), sel_l(cb), rr_sel(cb),
-    cts_hi(cb), cts_lo(cb)] where wt/fr_win are the swept table rows
+    [t_end, newly_count, fame_steps, rr_steps, wt_win(tw*n),
+    fr_win(tw*n), new_rounds(bp), new_wit(bp), famous_merged(rw*n),
+    sel_l(cb), rr_sel(cb), cts_hi(cb), cts_lo(cb)] where fame_steps
+    and rr_steps count the steps the fame vote loop and the
+    round-received sweep ran (each stops at the window's last row that
+    can hold work; W = rw = iw bounds the shapes, not the steps),
+    wt/fr_win are the swept table rows
     [t_start, t_start+tw) (the only rows that can have changed) and the
     cb-compacted tail carries the newly-received lanes: sel_l[j] for
     j < newly_count is an undecided-window lane index, with its
@@ -507,6 +520,7 @@ def _consensus_fused(chain_la, chain_rb_tab, chain_len, la, ranks, rb_vec,
 
         famous_comp = kernels.decide_fame_impl(
             wt_win, la, fd, index, coin, n=n, sm=sm, r=rw)
+        fame_steps = kernels.fame_steps(wt_win)
         wt_valid_f = wt_win >= 0
         mergeable = (
             in_list_win[:, None] & wt_valid_f
@@ -559,9 +573,10 @@ def _consensus_fused(chain_la, chain_rb_tab, chain_len, la, ranks, rb_vec,
         # Pad lanes are never assigned.
         rr_u0 = jnp.where(lane_ok, rr_prev[uid], 0)
 
+        rr_steps = _rr_steps(elig)
         rr_u = _rr_chunked(rr_u0, i0, la, wt_safe, cr_u, ix_u, rnd_u,
-                           lane_ok, n_und, elig, fmask, fcnt,
-                           iw=iw, rl=min(rl, au), lanes=lanes)
+                           lane_ok, n_und, elig, fmask, fcnt, rr_steps,
+                           rl=min(rl, au), lanes=lanes)
         newly_l = (rr_u >= 0) & (rr_u0 < 0) & lane_ok
         newly_count = newly_l.sum(dtype=jnp.int32)
 
@@ -645,11 +660,17 @@ def _consensus_fused(chain_la, chain_rb_tab, chain_len, la, ranks, rb_vec,
 
         packed = jnp.concatenate([
             t_end[None].astype(jnp.int32), newly_count[None],
+            fame_steps[None], rr_steps[None],
             wt_ret.ravel(), fr_ret.ravel(),
             rnd_b, wit_b.astype(jnp.int32), famous_merged.ravel(),
             sel_l.astype(jnp.int32), rr_sel, med_hi, med_lo,
         ])
     return packed, rounds_all, rr_all
+
+
+# Length of the packed delta's header: t_end, newly_count, fame_steps,
+# rr_steps (_consensus_fused).
+_PACK_HEAD = 4
 
 
 # Shape-keys already prewarmed this process (see
@@ -928,9 +949,11 @@ class IncrementalEngine:
         self.phase_ns: dict = {}
         # Counts of the last collected pass, published with phase_ns:
         # au (the undecided-window bucket swept), n_und (its live
-        # lanes), iw (rounds swept), rr_chunks (chunks of the
-        # round-received sweep) and ts_chunks (chunks of the timestamp
-        # loop), both of the final dispatch.
+        # lanes), iw (the round window's rows), rr_chunks (chunks of
+        # the round-received sweep), ts_chunks (chunks of the timestamp
+        # loop), fame_steps and rr_steps (steps the fame vote loop and
+        # the sweep ran, of iw - 1 and iw at most), all of the final
+        # dispatch.
         self.pass_counts: dict = {}
         # Compiled-cost attribution (docs/observability.md "Device
         # profiling"): request_cost_report() arms a one-shot capture;
@@ -1566,15 +1589,15 @@ class IncrementalEngine:
         pp.n_und = jnp.int32(len(und))
 
         # Fame/rr window widths: the spans actually needed, not the
-        # table capacity — decide_fame costs O(rw^2) sequential steps
-        # and the rr sweep O(iw) sequential [n, E] passes, and on this
-        # runtime the per-step overhead of those loops is the dominant
-        # device cost, so every halving of the window matters. The
-        # widths are PREDICTED from the previous run's observed round
-        # growth (doubled, so steady state never redoes); the post-pull
-        # checks below are the safety net — a misprediction or a
-        # straggler batch (i0 below the known rounds) costs one redo
-        # dispatch, never correctness.
+        # table capacity. W bounds the shapes, not the steps: the fame
+        # vote loop and the rr sweep stop at the window's last live or
+        # eligible row, but each fame step's vote planes are [n, W, n]
+        # and its matmul [n, n]@[n, W*n], so a wider window still costs
+        # in every step. The widths are PREDICTED from the previous
+        # run's observed round growth (doubled, so steady state never
+        # redoes); the post-pull checks below are the safety net — a
+        # misprediction or a straggler batch (i0 below the known
+        # rounds) costs one redo dispatch, never correctness.
         growth = 2 * self._last_growth + 2
         # Empty-queue fallback: _prev_first_undec, NOT beyond the table —
         # an empty list means either a fresh reset (first undecided round
@@ -1599,9 +1622,10 @@ class IncrementalEngine:
         # arrays scale with n) and stay tight at large n.
         # Large n => few, wide rounds: the fame step is a
         # [n, n]@[n, W*n] contraction per row, so an oversized W
-        # floor multiplies real FLOPs there; small n => fast, many
-        # rounds, where a big floor only pads cheap tiny rows but
-        # saves a compile per pow2 step.
+        # floor multiplies real FLOPs in each step run; small n =>
+        # fast, many rounds, where a big floor only pads cheap tiny
+        # rows (the loops never step over the empty ones) but saves a
+        # compile per pow2 step.
         w_floor = max(16, min(self._w_floor_max, (1 << 13) // n))
         pp.w_floor = w_floor
         pp.rw = pp.iw = _pow2(
@@ -1812,7 +1836,9 @@ class IncrementalEngine:
             with self._span(pp, "engine.apply", newly=newly_count,
                             ts_chunks=counts["ts_chunks"],
                             rr_chunks=counts["rr_chunks"],
-                            au=counts["au"]) as app:
+                            au=counts["au"],
+                            fame_steps=counts["fame_steps"],
+                            rr_steps=counts["rr_steps"]) as app:
                 delta = self._apply_delta(pp, packed, newly_count)
         ph["apply"] = app.t1 - app.t0
         # Collect's own host share: the redo decisions and the lock
@@ -1902,7 +1928,8 @@ class IncrementalEngine:
                 pp.counts = dict(
                     au=pp.au, n_und=n_und, iw=pp.iw,
                     rr_chunks=-(-n_und // min(pp.rl, pp.au)),
-                    ts_chunks=-(-newly_count // pp.tc))
+                    ts_chunks=-(-newly_count // pp.tc),
+                    fame_steps=int(packed[2]), rr_steps=int(packed[3]))
                 # Window-geometry diagnostics of the final dispatch.
                 self._dbg_windows = dict(
                     rcap=pp.rcap, rw=pp.rw, cb=pp.cb, bp=pp.bp,
@@ -1934,7 +1961,8 @@ class IncrementalEngine:
                           pp.tw_floor)
             pp.rw = pp.iw = max(pp.rw, _pow2(pp.tw, pp.w_floor))
             reason = "window"
-        rnd_b = packed[2 + 2 * pp.tw_i * n:2 + 2 * pp.tw_i * n + pp.bp]
+        off = _PACK_HEAD + 2 * pp.tw_i * n
+        rnd_b = packed[off:off + pp.bp]
         valid_b = rnd_b >= 0
         min_new = int(rnd_b[valid_b].min()) if valid_b.any() else None
         r_hi = self.rho_min + t_end
@@ -1958,7 +1986,7 @@ class IncrementalEngine:
         e, chain_len0, new_ids = pp.e, pp.chain_len0, pp.new_ids
         tw_i, t_start, bp, rw, cb = pp.tw_i, pp.t_start, pp.bp, pp.rw, pp.cb
         rel_rows, rx0, und = pp.rel_rows, pp.rx0, pp.und
-        off = 2
+        off = _PACK_HEAD
         tabs = packed[off:off + 2 * tw_i * n].reshape(2, tw_i, n)
         off += 2 * tw_i * n
         span_w = t_end - t_start

@@ -270,10 +270,22 @@ def compute_rounds(
     return rounds[:e], wit[:e], wt[:r]
 
 
+def fame_steps(wt):
+    """The vote steps decide_fame_impl runs over the window `wt` [r, n]:
+    one per voting row j in [1, last], `last` the window's last row that
+    holds a witness (0 when none past row 0 does). A step whose voter
+    row is empty leaves `famous` as it was, so the steps past `last`
+    decide nothing; an empty row below `last` (a round skip) still
+    zeroes the next row's tallies, so the sweep runs through it."""
+    live = (wt >= 0).any(1)
+    return jnp.max(jnp.where(live, jnp.arange(wt.shape[0]), 0))
+
+
 def decide_fame_impl(wt, la, fd, index, coin, *, n, sm, r):
     """Virtual voting — reference DecideFame (hashgraph.go:649-730).
 
-    One sweep over voting rounds j: round-j witnesses vote on every
+    One sweep over voting rounds j, up to the window's last row that
+    holds a witness (fame_steps): round-j witnesses vote on every
     earlier witness slot (rx, cx). First-round votes are plain `see`
     (ancestry); later rounds take the majority over the round-(j-1)
     witnesses they strongly see, deciding fame on a >= 2n/3+1 tally in
@@ -352,7 +364,8 @@ def decide_fame_impl(wt, la, fd, index, coin, *, n, sm, r):
         )
         return famous, vote
 
-    famous, _ = lax.fori_loop(1, r, step, (famous0, votes0))
+    famous, _ = lax.fori_loop(1, fame_steps(wt) + 1, step,
+                              (famous0, votes0))
     return famous
 
 

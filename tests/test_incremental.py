@@ -16,6 +16,10 @@ checkGossip):
 
 from __future__ import annotations
 
+import functools
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -26,6 +30,7 @@ from babble_tpu.hashgraph.root import Root
 from babble_tpu.hashgraph.round_info import Trilean
 from babble_tpu.hashgraph.tpu_graph import TpuHashgraph
 from babble_tpu.ops import incremental as inc
+from babble_tpu.ops import kernels, pipeline
 from babble_tpu.ops.dag import synthetic_dag
 from babble_tpu.ops.incremental import CTS_SENTINEL, IncrementalEngine
 from babble_tpu.ops.pipeline import run_pipeline
@@ -166,6 +171,143 @@ def test_timestamp_chunks_follow_the_pass(monkeypatch, n, e, sync, chunk,
                                      for p in final),
     }
     assert hit[case], [(p[0], p[1], p[2], p[5]) for p in pulls]
+
+
+def _fame_case(case, monkeypatch):
+    """decide_fame_impl over a window with empty rows equals the same
+    kernel run with the full static trip count, r - 1 steps."""
+    dag, _ = synthetic_dag(8, 600, seed=3)
+    n, sm, r = dag.n, dag.super_majority, 16
+    la, fd, _, _, wt = pipeline._coordinates_and_rounds(
+        dag.self_parent, dag.other_parent, dag.creator, dag.index,
+        dag.levels, dag.chain, dag.chain_len, dag.root_round, n=n, sm=sm,
+        r=dag.max_rounds)
+    win = np.asarray(wt)[2:2 + r].copy()  # rows from a later rx0
+    top = int(np.nonzero((win >= 0).any(1))[0][-1])
+    assert 8 <= top < r - 1  # trailing rows are empty
+    skip = 4
+    if case == "fame_round_skip":
+        win[skip] = -1
+    elif case == "fame_all_empty":
+        win[:] = -1
+        top = 0
+
+    def fame(w):
+        run = jax.jit(functools.partial(kernels.decide_fame_impl, n=n,
+                                        sm=sm, r=r))
+        return np.asarray(run(jnp.asarray(w), la, fd, dag.index, dag.coin))
+
+    got = fame(win)
+    assert int(kernels.fame_steps(jnp.asarray(win))) == top
+    with monkeypatch.context() as m:
+        m.setattr(kernels, "fame_steps", lambda w: w.shape[0] - 1)
+        full = fame(win)
+    assert (got == full).all()
+    if case == "fame_round_skip":
+        # Steps past the empty row decided fame, so a sweep that
+        # stopped at it would differ.
+        assert (got[skip + 1:] != kernels.FAME_UNDEFINED).any()
+    elif case == "fame_all_empty":
+        assert (got == kernels.FAME_UNDEFINED).all()
+
+
+def _rr_case(case):
+    """_rr_chunked run through the last eligible round equals the full
+    sweep over all iw rounds."""
+    dag, _ = synthetic_dag(8, 600, seed=3)
+    n, sm, iw, rl = dag.n, dag.super_majority, 16, 64
+    rounds, _, wt, famous, _, _ = map(
+        np.asarray, run_pipeline(dag, engine="wavefront"))
+    la, _, _, _, _ = pipeline._coordinates_and_rounds(
+        dag.self_parent, dag.other_parent, dag.creator, dag.index,
+        dag.levels, dag.chain, dag.chain_len, dag.root_round, n=n, sm=sm,
+        r=dag.max_rounds)
+    wt_w, fam_w = wt[:iw], famous[:iw]
+    wt_valid = wt_w >= 0
+    fmask = (fam_w == kernels.FAME_TRUE) & wt_valid
+    e = dag.e
+    au = -(-e // rl) * rl
+    uid = np.zeros(au, np.int32)
+    uid[:e] = np.arange(e)
+    lane_ok = np.arange(au) < e
+    elig = np.zeros(iw, bool)
+    if case == "rr_eligible_ends_mid_window":
+        elig[:5] = True
+    elif case == "rr_eligibility_gap":
+        elig[[0, 1, 3]] = True
+    steps = int(inc._rr_steps(jnp.asarray(elig)))
+    assert steps == (np.nonzero(elig)[0][-1] + 1 if elig.any() else 0)
+    sweep = jax.jit(functools.partial(inc._rr_chunked, rl=rl, lanes=None))
+    args = (jnp.where(jnp.asarray(lane_ok), -1, 0), jnp.int32(0), la,
+            jnp.asarray(np.where(wt_valid, wt_w, 0)),
+            dag.creator[uid], dag.index[uid], rounds[uid],
+            jnp.asarray(lane_ok), jnp.int32(e), jnp.asarray(elig),
+            jnp.asarray(fmask), jnp.asarray(fmask.sum(1)))
+    got = np.asarray(sweep(*args, jnp.int32(steps)))
+    full = np.asarray(sweep(*args, jnp.int32(iw)))
+    assert (got == full).all()
+    assert (got[:e] >= 0).any() == elig.any()
+
+
+def _replay_case(monkeypatch):
+    """On a small replay each pass's fame_steps is its window's last
+    live row (the top round less rx0), and rr_steps runs from i0 to the
+    first undecided round, none in a pass that decides no round and
+    brings no event of a decided one; both ride the engine.apply span."""
+    n, e, sync = 16, 600, 50
+    dag, _ = synthetic_dag(n, e, seed=3)
+    ring = SpanRing(1 << 14)
+    eng = IncrementalEngine(n, capacity=64, block=64, k_capacity=8,
+                            trace=ring)
+    passes = []  # (pass_counts, newly, fame_steps and rr_steps expected)
+    apply = eng._apply_delta
+
+    def record(pp, packed, newly):
+        first_undec = eng._prev_first_undec
+        delta = apply(pp, packed, newly)
+        top = int(eng.rounds[:pp.e].max())
+        i0 = min(first_undec, int(eng.rounds[pp.new_ids].min()) + 1)
+        assert eng.undecided_rounds  # else the device's bound is the window
+        rr_steps = min(max(eng._prev_first_undec - i0, 0), pp.iw)
+        passes.append((pp.counts, newly, (max(top - pp.rx0, 0), rr_steps)))
+        return delta
+
+    monkeypatch.setattr(eng, "_apply_delta", record)
+    for k in range(0, e, sync):
+        hi = min(k + sync, e)
+        eng.append_batch(dag.self_parent[k:hi], dag.other_parent[k:hi],
+                         dag.creator[k:hi], dag.index[k:hi], dag.coin[k:hi],
+                         np.arange(k, hi))
+        eng.run()
+    eng.close()
+    for pc, newly, want in passes:
+        assert (pc["fame_steps"], pc["rr_steps"]) == want
+        assert pc["rr_steps"] or not newly
+    # The bounds engaged: passes that stepped over no round, and passes
+    # whose fame loop stopped short of the window.
+    assert any(pc["rr_steps"] == 0 for pc, _, _ in passes)
+    assert any(0 < pc["rr_steps"] for pc, _, _ in passes)
+    assert all(pc["fame_steps"] < pc["iw"] - 1 for pc, _, _ in passes)
+    applied = [sp["args"] for sp in ring.snapshot()
+               if sp["name"] == "engine.apply"]
+    assert [(a["fame_steps"], a["rr_steps"]) for a in applied] == [
+        (pc["fame_steps"], pc["rr_steps"]) for pc, _, _ in passes]
+
+
+@pytest.mark.parametrize(
+    "case", ["fame_trailing_empty_rows", "fame_round_skip", "fame_all_empty",
+             "rr_none_eligible", "rr_eligible_ends_mid_window",
+             "rr_eligibility_gap", "replay_counts"])
+def test_window_loops_stop_at_the_last_row_with_work(monkeypatch, case):
+    """The fused kernel's fame vote loop and round-received sweep run
+    through the window's last row that can hold work, not over all W
+    rows, and give what the full trip count gives."""
+    if case.startswith("fame_"):
+        _fame_case(case, monkeypatch)
+    elif case.startswith("rr_"):
+        _rr_case(case)
+    else:
+        _replay_case(monkeypatch)
 
 
 @pytest.mark.parametrize(

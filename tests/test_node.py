@@ -4,6 +4,7 @@ node/node_test.go:396-599), over both the inmem and TCP transports."""
 
 from __future__ import annotations
 
+import threading
 import time
 
 import pytest
@@ -14,6 +15,7 @@ from babble_tpu.net import InmemTransport, Peer, TCPTransport
 from babble_tpu.net.inmem_transport import connect_all
 from babble_tpu.node import Core, Node
 from babble_tpu.node.config import test_config as fast_config
+from babble_tpu.node.state import StateMachine
 from babble_tpu.proxy import InmemAppProxy
 
 CACHE = 10000
@@ -456,3 +458,25 @@ def test_shutdown():
     finally:
         for node in nodes:
             node.shutdown()
+
+
+def test_wait_routines_while_routines_start():
+    """A node's shutdown joins its routines while others may be starting
+    (a gossip handler spawned mid-shutdown): wait_routines never sees a
+    thread it cannot join yet."""
+    state = StateMachine()
+    stop = threading.Event()
+
+    def spawn():
+        while not stop.is_set():
+            state.go_func(lambda: time.sleep(0.001))
+
+    spawner = threading.Thread(target=spawn)
+    spawner.start()
+    try:
+        t_end = time.monotonic() + 1.0
+        while time.monotonic() < t_end:
+            state.wait_routines(timeout=0.05)
+    finally:
+        stop.set()
+        spawner.join()
