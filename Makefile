@@ -18,7 +18,7 @@ test-crash:
 	$(PY) -m pytest tests/test_crash.py tests/test_durability.py -q
 
 bench:
-	$(PY) bench.py
+	python3 benchmark/run.py --workload v1024-replay
 
 demo:
 	demo/scripts/demo.sh

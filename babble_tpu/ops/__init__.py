@@ -1,49 +1,35 @@
-"""Batched TPU consensus kernels.
+"""The device consensus engine.
 
-This is the accelerator-native consensus engine: the reference's
-per-event, pointer-chasing pipeline (reference hashgraph/hashgraph.go)
-recast as dense tensor sweeps over a structure-of-arrays event DAG
-resident in HBM.
+IncrementalEngine (ops/incremental.py) keeps an append-only event DAG
+resident in HBM and, for each sync, runs the reference's
+DivideRounds / DecideFame / FindOrder (reference
+hashgraph/hashgraph.go:616-858) over the undecided tip only, as dense
+tensor sweeps in one fused kernel with one packed pull:
 
-Key recastings (reference anchors):
-- lastAncestors init = elementwise max of parent rows
-  (hashgraph.go:477-483) -> one wavefront gather/max/scatter per DAG
-  depth level instead of one Go call per event.
-- firstDescendants back-propagation along self-parent chains
-  (hashgraph.go:502-530) -> a *closed form*: last-ancestor columns are
-  monotone along each creator chain, so first_desc[a][c] is a batched
-  binary search (jnp.searchsorted) — no chain walking, no fixpoint.
-- stronglySee = lane-wise compare-and-count >= 2n/3+1
-  (hashgraph.go:179-198) -> broadcast compare against a [rounds, n]
-  witness table (at most one witness per creator per round).
-- DivideRounds (hashgraph.go:616-646) -> the same wavefront sweep that
-  fills coordinates, carrying rounds + the witness table.
-- DecideFame incl. coin rounds (hashgraph.go:649-730) -> one sweep over
-  voting rounds with an [n, rounds*n] vote-matrix contraction.
-- DecideRoundReceived + median consensus timestamps
-  (hashgraph.go:753-799,860-868) -> masked famous-witness see-counts and
-  an on-device sort over dense timestamp ranks (int32; host maps ranks
-  back to nanosecond values, -1 = Go zero time).
+- coordinates (lastAncestors, hashgraph.go:448-499): block closure
+  matmuls over the new events (ops/closure.py);
+- firstDescendants (hashgraph.go:490-530): a resident rank cube kept
+  by compare-and-count, since last-ancestor columns are monotone
+  along each creator chain;
+- rounds and witnesses (hashgraph.go:211-339): a witness-frontier
+  sweep, one step per round (ops/frontier.py);
+- fame with coin rounds (hashgraph.go:649-730): a vote-matrix
+  contraction over voting rounds (ops/kernels.py);
+- round received and median timestamps (hashgraph.go:753-799,
+  860-868): a windowed sweep and an on-device sort.
 
-Hashing, signatures, and the big-int S tiebreak stay on host; the device
-works purely in int32 event ids.
+Hashing, signatures and the big-int S tiebreak stay on the host; the
+device works in int32 event ids. ops/p256.py is the batched signature
+check; ops/dag.py and ops/sim.py build DAGs for tests and tools.
 """
 
 from .dag import DagTensors, build_dag, synthetic_dag
-from .engine import BatchConsensusResult, run_consensus_batch
 from .incremental import IncrementalEngine, RunDelta
-from .pipeline import consensus_pipeline, run_pipeline
-from .sharded import sharded_pipeline
 
 __all__ = [
     "DagTensors",
     "build_dag",
     "synthetic_dag",
-    "BatchConsensusResult",
-    "run_consensus_batch",
-    "consensus_pipeline",
-    "run_pipeline",
     "IncrementalEngine",
     "RunDelta",
-    "sharded_pipeline",
 ]

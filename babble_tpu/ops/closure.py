@@ -1,8 +1,7 @@
 """Block-parallel ancestor coordinates via boolean closure matmuls.
 
-Replaces the depth-sequential wavefront of kernels.compute_last_ancestors
-(one tiny dispatch per DAG level — 2,709 levels at n=64/e=50k) with a
-schedule whose trip count scales with E/block: events are processed in
+A schedule whose trip count scales with E/block, not with DAG depth
+(2,709 levels at n=64/e=50k): events are processed in
 topological blocks of B; intra-block reachability is closed by log2(B)
 boolean matrix squarings (MXU work), and each event's coordinates are
 the masked max of the closure-selected base rows (VPU reduction, fused
@@ -20,9 +19,6 @@ closure at the cost of one extra column.
 
 from __future__ import annotations
 
-import functools
-
-import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
@@ -45,9 +41,11 @@ def _apply_chunks(block: int, n: int) -> int:
 
 def make_block_body(self_parent, other_parent, creator, index, root_base,
                     *, n, block):
-    """The per-block closure step over [cap+1]-shaped inputs, shared by
-    the one-shot kernel below and the incremental carry kernel
-    (ops/incremental.py). Returns body(b, (la, rb)) -> (la, rb)."""
+    """The per-block closure step over [cap+1]-shaped inputs, run by the
+    engine's carry kernel (ops/incremental.py _closure_update). Inputs
+    carry a sentinel row at id cap; pad events (sp=op=-1, index=-1,
+    root_base=-1) produce inert rows. Returns body(b, (la, rb)) ->
+    (la, rb)."""
     e_pad = self_parent.shape[0] - 1
     log2b = max(int(np.ceil(np.log2(block))), 1)
     chunks = _apply_chunks(block, n)
@@ -105,58 +103,3 @@ def make_block_body(self_parent, other_parent, creator, index, root_base,
         return la, rb
 
     return body
-
-
-@functools.partial(jax.jit, static_argnames=("n", "block"))
-def compute_coordinates(self_parent, other_parent, creator, index, root_base,
-                        *, n, block):
-    """la[x, i] = index of x's latest ancestor created by i (-1 none);
-    rbase[x] = max over ancestors-incl-self of root_base (-1 none).
-
-    Inputs are [E_pad + 1] int32 with E_pad a multiple of `block` and a
-    sentinel row at id E_pad; pad events carry sp=op=-1, index=-1,
-    root_base=-1 and produce inert rows. Returns (la[E_pad, n],
-    rbase[E_pad]).
-    """
-    e_pad = self_parent.shape[0] - 1
-    nblocks = e_pad // block
-    la = jnp.full((e_pad + 1, n), -1, dtype=jnp.int32)
-    rb = jnp.full((e_pad + 1,), -1, dtype=jnp.int32)
-    body = make_block_body(self_parent, other_parent, creator, index,
-                           root_base, n=n, block=block)
-    la, rb = lax.fori_loop(0, nblocks, body, (la, rb))
-    return la[:e_pad], rb[:e_pad]
-
-
-def pad_for_blocks(dag, block: int):
-    """Pad a DagTensors' per-event arrays to a block multiple (+sentinel)
-    and build the root_base vector. Returns dict of kernel inputs."""
-    e = dag.e
-    e_pad = ((e + block - 1) // block) * block if e else block
-
-    def pad(a, fill):
-        out = np.full(e_pad + 1, fill, dtype=np.int32)
-        out[:e] = a[:e]
-        return out
-
-    sp = pad(dag.self_parent, -1)
-    op = pad(dag.other_parent, -1)
-    cr = pad(dag.creator, 0)
-    idx = pad(dag.index, -1)
-    root_base = np.full(e_pad + 1, -1, dtype=np.int32)
-    missing = (dag.self_parent[:e] < 0) | (dag.other_parent[:e] < 0)
-    root_base[:e] = np.where(
-        missing, dag.root_round[dag.creator[:e]] + 1, -1)
-    return {
-        "self_parent": sp, "other_parent": op, "creator": cr,
-        "index": idx, "root_base": root_base, "e_pad": e_pad,
-    }
-
-
-def coordinates(dag, block: int = 512):
-    """Host wrapper: (la[E, n], rbase[E]) for a DagTensors."""
-    p = pad_for_blocks(dag, block)
-    la, rb = compute_coordinates(
-        p["self_parent"], p["other_parent"], p["creator"], p["index"],
-        p["root_base"], n=dag.n, block=block)
-    return la[:dag.e], rb[:dag.e]

@@ -1,248 +1,83 @@
-"""Host-side assembly of an event DAG into SoA device tensors.
+"""Host-side assembly of an event DAG into integer arrays.
 
 Converts a topologically-ordered list of Events (hashes, pubkeys,
-timestamps) into the integer-id tensor layout the batched kernels
-consume. Strings never reach the device: events become ids in insertion
-order, creators become participant ids (the reference's sorted-pubkey
-fake ids, cmd/babble/main.go:215-221), and timestamps become dense
-int32 ranks (rank -1 is reserved for Go's zero time, the value the
-reference's MedianTimestamp substitutes for unknown events —
-hashgraph.go:860-868).
+timestamps) into the integer-id layout IncrementalEngine.append_batch
+takes. Strings never reach the device: events become ids in insertion
+order, and creators become participant ids (the reference's
+sorted-pubkey fake ids, cmd/babble/main.go:215-221).
 """
 
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, Sequence
 
 import numpy as np
 
 from ..hashgraph.event import Event
 from ..hashgraph.graph import middle_bit
-from ..hashgraph.root import Root
 
 
 @dataclass
 class DagTensors:
-    """Structure-of-arrays DAG. Per-event arrays are padded with one
-    trailing sentinel row (id E) so scatter/gather padding lanes have a
-    harmless target."""
+    """Structure-of-arrays DAG, one row per event in insertion order."""
 
     n: int  # participants
-    e: int  # true event count
-    # [E+1] int32; parents are event ids, -1 = root / none
+    e: int  # event count
+    # [E] int32; parents are event ids, -1 = root / none
     self_parent: np.ndarray
     other_parent: np.ndarray
-    creator: np.ndarray  # [E+1] int32 participant ids
-    index: np.ndarray  # [E+1] int32 creator-sequence index
-    coin: np.ndarray  # [E+1] int8 middleBit of the event hash
-    ts_rank: np.ndarray  # [E+1] int32 dense timestamp rank
-    ts_values: np.ndarray  # [U] int64 sorted unique timestamp ns
-    levels: np.ndarray  # [L, W] int32 event ids per DAG depth level, -1 pad
-    depth: int  # true DAG depth (pre-chunking level count)
-    chain: np.ndarray  # [n, K] int32 event id of creator c's k-th event, -1 pad
-    chain_len: np.ndarray  # [n] int32
-    chain_rank: np.ndarray  # [n, K] int32 timestamp rank along each chain
-    root_round: np.ndarray  # [n] int32 per-participant Root round (-1 base)
+    creator: np.ndarray  # [E] int32 participant ids
+    index: np.ndarray  # [E] int32 creator-sequence index
+    coin: np.ndarray  # [E] int8 middleBit of the event hash
+    ts_ns: np.ndarray  # [E] int64 event timestamps
     hexes: List[str]  # id -> event hex
-    hex_to_id: Dict[str, int]
     events: List[Event]  # id -> Event
 
-    @property
-    def super_majority(self) -> int:
-        return 2 * self.n // 3 + 1
 
-    @property
-    def max_rounds(self) -> int:
-        """Static bound on round numbers: rounds start from the largest
-        Root round (-1 for base roots) and grow by at most 1 per true
-        DAG depth level (round(x) <= max(parent rounds) + 1). Uses the
-        pre-chunking depth — chunked level rows subdivide levels
-        without adding round headroom."""
-        base = int(self.root_round.max()) + 1 if self.n else 0
-        return max(base, 0) + self.depth + 2
-
-
-def _assemble(
-    n: int,
-    e: int,
-    self_parent: np.ndarray,
-    other_parent: np.ndarray,
-    creator: np.ndarray,
-    index: np.ndarray,
-    coin: np.ndarray,
-    ts_rank: np.ndarray,
-    ts_values: np.ndarray,
-    root_round: np.ndarray,
-    hexes: List[str],
-    hex_to_id: Dict[str, int],
-    events: List[Event],
-    max_level_width: Optional[int] = None,
-) -> DagTensors:
-    """Shared tail of DAG assembly: wavefront levels + creator chains.
-
-    `max_level_width` splits wide levels into consecutive rows (events
-    within a level are mutually independent, so any split is valid) to
-    bound the [W, n, n] working set of the round kernel at large n."""
-    # DAG depth levels (wavefront schedule).
-    level = np.zeros(e, dtype=np.int32)
-    for i in range(e):
-        lv = -1
-        sp, op = self_parent[i], other_parent[i]
-        if sp >= 0:
-            lv = max(lv, level[sp])
-        if op >= 0:
-            lv = max(lv, level[op])
-        level[i] = lv + 1
-    n_levels = int(level.max()) + 1 if e else 1
-    depth = n_levels
-    buckets: List[List[int]] = [[] for _ in range(n_levels)]
-    for i in range(e):
-        buckets[level[i]].append(i)
-    if max_level_width is not None and max_level_width > 0:
-        chunked: List[List[int]] = []
-        for b in buckets:
-            for off in range(0, max(len(b), 1), max_level_width):
-                chunked.append(b[off : off + max_level_width])
-        buckets = chunked
-    width = max((len(b) for b in buckets), default=1)
-    levels = np.full((len(buckets), width), -1, dtype=np.int32)
-    for l, b in enumerate(buckets):
-        levels[l, : len(b)] = b
-
-    # Per-creator chains: chain[c, k] = id of c's event with index k.
-    k_max = int(index[:e].max()) + 1 if e else 1
-    chain = np.full((n, k_max), -1, dtype=np.int32)
-    chain_len = np.zeros(n, dtype=np.int32)
-    for i in range(e):
-        c, k = int(creator[i]), int(index[i])
-        if chain[c, k] != -1:
-            raise ValueError(f"fork: two events by creator {c} at index {k}")
-        chain[c, k] = i
-    for c in range(n):
-        length = 0
-        while length < k_max and chain[c, length] != -1:
-            length += 1
-        if np.any(chain[c, length:] != -1):
-            raise ValueError(f"non-contiguous chain for creator {c}")
-        chain_len[c] = length
-
-    chain_rank = np.full((n, k_max), -1, dtype=np.int32)
-    valid = chain >= 0
-    chain_rank[valid] = ts_rank[chain[valid]]
-
-    return DagTensors(
-        n=n,
-        e=e,
-        self_parent=self_parent,
-        other_parent=other_parent,
-        creator=creator,
-        index=index,
-        coin=coin,
-        ts_rank=ts_rank,
-        ts_values=ts_values,
-        levels=levels,
-        depth=depth,
-        chain=chain,
-        chain_len=chain_len,
-        chain_rank=chain_rank,
-        root_round=root_round,
-        hexes=hexes,
-        hex_to_id=hex_to_id,
-        events=events,
-    )
-
-
-def build_dag(
-    events: Sequence[Event],
-    participants: Dict[str, int],
-    roots: Optional[Dict[str, Root]] = None,
-    max_level_width: Optional[int] = None,
-) -> DagTensors:
+def build_dag(events: Sequence[Event],
+              participants: Dict[str, int]) -> DagTensors:
     """`events` must be in insertion (topological) order — the same
-    order the incremental engine would receive them."""
-    n = len(participants)
+    order the engine receives them."""
     e = len(events)
-
-    hex_to_id: Dict[str, int] = {}
-    hexes: List[str] = []
-    for i, ev in enumerate(events):
-        h = ev.hex()
-        hex_to_id[h] = i
-        hexes.append(h)
-
-    self_parent = np.full(e + 1, -1, dtype=np.int32)
-    other_parent = np.full(e + 1, -1, dtype=np.int32)
-    creator = np.zeros(e + 1, dtype=np.int32)
-    index = np.zeros(e + 1, dtype=np.int32)
-    coin = np.zeros(e + 1, dtype=np.int8)
+    hexes = [ev.hex() for ev in events]
+    hex_to_id = {h: i for i, h in enumerate(hexes)}
+    self_parent = np.full(e, -1, dtype=np.int32)
+    other_parent = np.full(e, -1, dtype=np.int32)
+    creator = np.zeros(e, dtype=np.int32)
+    index = np.zeros(e, dtype=np.int32)
+    coin = np.zeros(e, dtype=np.int8)
     ts_ns = np.zeros(e, dtype=np.int64)
-
     for i, ev in enumerate(events):
         sp, op = ev.self_parent(), ev.other_parent()
         if sp:
-            if sp not in hex_to_id:
-                raise ValueError(f"event {i} self-parent not in batch: {sp[:16]}")
             self_parent[i] = hex_to_id[sp]
         if op:
-            if op not in hex_to_id:
-                raise ValueError(f"event {i} other-parent not in batch: {op[:16]}")
             other_parent[i] = hex_to_id[op]
         creator[i] = participants[ev.creator()]
         index[i] = ev.index()
         coin[i] = 1 if middle_bit(ev.hex()) else 0
         ts_ns[i] = ev.body.timestamp.ns
-
-    # Dense timestamp ranks: median selection and the final sort only
-    # need ordering, so int32 ranks replace int64 nanoseconds on device.
-    ts_values, ts_rank_e = np.unique(ts_ns, return_inverse=True)
-    ts_rank = np.zeros(e + 1, dtype=np.int32)
-    ts_rank[:e] = ts_rank_e.astype(np.int32)
-
-    root_round = np.full(n, -1, dtype=np.int32)
-    if roots:
-        for pk, root in roots.items():
-            root_round[participants[pk]] = root.round
-
-    return _assemble(
-        n,
-        e,
-        self_parent,
-        other_parent,
-        creator,
-        index,
-        coin,
-        ts_rank,
-        ts_values,
-        root_round,
-        hexes,
-        hex_to_id,
-        list(events),
-        max_level_width=max_level_width,
-    )
+    return DagTensors(len(participants), e, self_parent, other_parent,
+                      creator, index, coin, ts_ns, hexes, list(events))
 
 
-def synthetic_dag(
-    n: int,
-    e: int,
-    seed: int = 0,
-    max_level_width: Optional[int] = None,
-):
-    """Generate a random-gossip DAG directly as tensors (no crypto, no
-    Event objects) for benchmarking the device pipeline: each step a
-    random creator records a sync from a random other peer, exactly the
-    event pattern the gossip runtime produces (reference
-    node/node.go:315-487).
+def synthetic_dag(n: int, e: int, seed: int = 0):
+    """Generate a random-gossip DAG directly as arrays (no crypto, no
+    Event objects): each step a random creator records a sync from a
+    random other peer, exactly the event pattern the gossip runtime
+    produces (reference node/node.go:315-487). Timestamps are the
+    event ids.
 
     Returns (DagTensors, s_rank[E] int64) where s_rank stands in for
     the raw big-int signature-S tiebreak of the final sort."""
     if e < n or n < 2:
         raise ValueError("need n >= 2 and at least one event per participant")
     rng = np.random.default_rng(seed)
-    self_parent = np.full(e + 1, -1, dtype=np.int32)
-    other_parent = np.full(e + 1, -1, dtype=np.int32)
-    creator = np.zeros(e + 1, dtype=np.int32)
-    index = np.zeros(e + 1, dtype=np.int32)
+    self_parent = np.full(e, -1, dtype=np.int32)
+    other_parent = np.full(e, -1, dtype=np.int32)
+    creator = np.zeros(e, dtype=np.int32)
+    index = np.zeros(e, dtype=np.int32)
 
     heads = np.full(n, -1, dtype=np.int64)
     seqs = np.full(n, -1, dtype=np.int64)
@@ -261,28 +96,8 @@ def synthetic_dag(
         index[i] = seqs[c]
         heads[c] = i
 
-    coin = np.zeros(e + 1, dtype=np.int8)
-    coin[:e] = rng.integers(0, 2, size=e, dtype=np.int8)
-    ts_rank = np.zeros(e + 1, dtype=np.int32)
-    ts_rank[:e] = np.arange(e, dtype=np.int32)  # monotone clock
-    ts_values = np.arange(e, dtype=np.int64)
-    root_round = np.full(n, -1, dtype=np.int32)
+    coin = rng.integers(0, 2, size=e, dtype=np.int8)
     s_rank = rng.integers(0, 2**62, size=e, dtype=np.int64)
-
-    dag = _assemble(
-        n,
-        e,
-        self_parent,
-        other_parent,
-        creator,
-        index,
-        coin,
-        ts_rank,
-        ts_values,
-        root_round,
-        hexes=[],
-        hex_to_id={},
-        events=[],
-        max_level_width=max_level_width,
-    )
+    dag = DagTensors(n, e, self_parent, other_parent, creator, index, coin,
+                     np.arange(e, dtype=np.int64), hexes=[], events=[])
     return dag, s_rank

@@ -1,9 +1,7 @@
 """Round assignment as a frontier sweep — sequential in the number of
-consensus rounds, not DAG depth.
-
-Replaces kernels.compute_rounds' per-level wavefront (2,709 sequential
-levels at n=64/e=50k) with one step per round (~72 at the same size,
-~E/(3n) in general): round numbers are determined by witness frontiers.
+consensus rounds, not DAG depth: one step per round (~72 at n=64/e=50k
+against 2,709 DAG levels, ~E/(3n) in general), round numbers being
+determined by witness frontiers.
 
 Theory (mirrors reference hashgraph.go:211-339, DivideRounds 616-646):
 round(x) = max over ancestors-incl-self y of local(y), where
@@ -36,10 +34,6 @@ higher-round candidate strongly sees >= sm true round-rho witnesses
 
 from __future__ import annotations
 
-import functools
-from typing import Optional, Tuple
-
-import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
@@ -61,24 +55,11 @@ def _chain_chunks(n: int) -> int:
     return n // cc
 
 
-@functools.partial(jax.jit, static_argnames=("n",))
-def build_chain_tables(la, rbase, chain, *, n):
-    """chain_la[c, k, i] = la[chain[c, k], i] (INT32_MAX beyond the
-    chain, so searchsorted targets land past real entries);
-    chain_rbase[c, k] likewise. chain: [n, K] event ids, -1 pad."""
-    valid = chain >= 0
-    safe = jnp.where(valid, chain, 0)
-    chain_la = jnp.where(valid[:, :, None], la[safe], INT32_MAX)
-    chain_rbase = jnp.where(valid, rbase[safe], INT32_MAX)
-    return chain_la, chain_rbase
-
-
 def make_round_step(chain_la, chain_rbase, chain_len, la, fd, rbase, chain,
                     *, n, sm):
     """One frontier round: step(rho, wt_prev, fr_prev) ->
-    (wt_row, fr_unclamped, fr_clamped, any_candidate). Shared by the
-    chunked host driver below and the single-dispatch while-loop sweep
-    (used by ops/incremental.py).
+    (wt_row, fr_unclamped, fr_clamped, any_candidate), run by the
+    single-dispatch while-loop sweep below.
 
     k2 is a vectorized binary search: because per-witness strongly-see
     indicators are monotone along a chain, "sm-th smallest over w of
@@ -153,35 +134,6 @@ def make_round_step(chain_la, chain_rbase, chain_len, la, fd, rbase, chain,
     return step
 
 
-@functools.partial(jax.jit, static_argnames=("n", "sm", "rc"))
-def frontier_chunk(chain_la, chain_rbase, chain_len, la, fd, rbase, chain,
-                   wt_prev, fr_prev, rho0, *, n, sm, rc):
-    """Advance the witness frontier by `rc` rounds starting at rho0.
-
-    wt_prev: [n] witness event ids of round rho0-1 (-1 none);
-    fr_prev: [n] first chain position with round >= rho0-1.
-    Returns (wt_out[rc, n], fr_out[rc, n], active[rc], wt_last, fr_last).
-    """
-    k_cap = chain_la.shape[1]
-    step = make_round_step(chain_la, chain_rbase, chain_len, la, fd, rbase,
-                           chain, n=n, sm=sm)
-
-    def round_step(t, carry):
-        wt_prev, fr_prev, wt_out, fr_out, act_out = carry
-        wt_row, fr, fr_c, any_cand = step(rho0 + t, wt_prev, fr_prev)
-        wt_out = wt_out.at[t].set(wt_row)
-        fr_out = fr_out.at[t].set(fr_c)
-        act_out = act_out.at[t].set(any_cand)
-        return wt_row, fr, wt_out, fr_out, act_out
-
-    wt_out = jnp.full((rc, n), -1, dtype=jnp.int32)
-    fr_out = jnp.full((rc, n), k_cap, dtype=jnp.int32)
-    act_out = jnp.zeros((rc,), dtype=jnp.bool_)
-    wt_last, fr_last, wt_out, fr_out, act_out = lax.fori_loop(
-        0, rc, round_step, (wt_prev, fr_prev, wt_out, fr_out, act_out))
-    return wt_out, fr_out, act_out, wt_last, fr_last
-
-
 def frontier_sweep_impl(chain_la, chain_rbase, chain_len, la, fd, rbase,
                         chain, wt_tab, fr_tab, wt_prev, fr_prev, t0,
                         rho_min, *, n, sm, rcap):
@@ -192,9 +144,9 @@ def frontier_sweep_impl(chain_la, chain_rbase, chain_len, la, fd, rbase,
     t_end == rcap with activity still pending means the caller must
     re-run with a larger bucket.
 
-    Unjitted so callers already inside a trace can pass a lazy row-view
-    `fd` (any object supporting fd[ids] -> [len(ids), n], e.g.
-    incremental._FdRows) instead of a dense [E, n] array."""
+    Unjitted: the fused kernel (ops/incremental.py) calls it inside its
+    trace with a lazy row view `fd` (incremental._FdRows: fd[ids] ->
+    [len(ids), n]) instead of a dense [E, n] array."""
     k_cap = chain_la.shape[1]
     step = make_round_step(chain_la, chain_rbase, chain_len, la, fd, rbase,
                            chain, n=n, sm=sm)
@@ -213,67 +165,3 @@ def frontier_sweep_impl(chain_la, chain_rbase, chain_len, la, fd, rbase,
     t_end, _, _, _, wt_tab, fr_tab = lax.while_loop(
         cond, body, (t0, jnp.bool_(True), wt_prev, fr_prev, wt_tab, fr_tab))
     return wt_tab, fr_tab, t_end
-
-
-frontier_sweep = functools.partial(jax.jit, static_argnames=(
-    "n", "sm", "rcap"))(frontier_sweep_impl)
-
-
-@functools.partial(jax.jit, static_argnames=("n",))
-def rounds_from_frontier(frontier, creator, index, self_parent, rho_min, *, n):
-    """Per-event rounds + witness flags from the frontier table.
-
-    round(chain[c, k]) = rho_min - 1 + #{rows with frontier[., c] <= k};
-    witness(x) = sits-on-root or round > round(self-parent)
-    (reference hashgraph.go:265-282). creator/index/self_parent: [E]."""
-    e = creator.shape[0]
-    rows = (frontier[:, creator] <= index[None, :]).sum(0)  # [E]
-    rounds = rho_min - 1 + rows.astype(jnp.int32)
-    sp_safe = jnp.where(self_parent >= 0, self_parent, 0)
-    wit = (self_parent < 0) | (rounds > rounds[sp_safe])
-    return rounds, wit
-
-
-def compute_frontier(la, rbase, fd, chain, chain_len, root_round,
-                     *, n: int, sm: int, rc: int = 64,
-                     view_chain_len: Optional[np.ndarray] = None,
-                     ) -> Tuple[np.ndarray, np.ndarray, int]:
-    """Host driver: sweep rounds in chunks of rc until the frontier
-    passes every chain's end. `view_chain_len` restricts to an
-    ancestry-closed prefix view (per-peer simulation): coordinates from
-    the full DAG stay exact for any closed view, so only the chain
-    lengths change. Returns (wt[R, n] absolute-round-indexed,
-    frontier[R', n], rho_min)."""
-    chain_len_eff = chain_len if view_chain_len is None else view_chain_len
-    chain_la, chain_rbase = build_chain_tables(la, rbase, chain, n=n)
-    rho_min = int(root_round.min()) + 1
-
-    wt_prev = jnp.full((n,), -1, dtype=jnp.int32)
-    fr_prev = jnp.zeros((n,), dtype=jnp.int32)
-    wt_rows, fr_rows = [], []
-    rho0 = rho_min
-    while True:
-        wt_o, fr_o, act, wt_prev, fr_prev = frontier_chunk(
-            chain_la, chain_rbase, chain_len_eff, la, fd, rbase, chain,
-            wt_prev, fr_prev, jnp.int32(rho0), n=n, sm=sm, rc=rc)
-        act_np = np.asarray(act)
-        wt_rows.append(np.asarray(wt_o))
-        fr_rows.append(np.asarray(fr_o))
-        if not bool(act_np[-1]):
-            break
-        rho0 += rc
-    wt_rel = np.concatenate(wt_rows, axis=0)
-    fr_rel = np.concatenate(fr_rows, axis=0)
-    active = (fr_rel < np.asarray(chain_len_eff)[None, :]).any(axis=1)
-    # highest round with any event = last active row
-    n_rounds = int(np.nonzero(active)[0][-1]) + 1 if active.any() else 0
-    wt_rel = wt_rel[:n_rounds]
-    fr_rel = fr_rel[:n_rounds]
-
-    # Absolute-round-indexed witness table (rows 0..rho_min-1 empty),
-    # matching the old kernels' contract for fame / round-received.
-    r_abs = rho_min + n_rounds
-    wt = np.full((max(r_abs, 1), n), -1, dtype=np.int32)
-    if n_rounds:
-        wt[rho_min:r_abs] = wt_rel
-    return wt, fr_rel, rho_min

@@ -17,8 +17,8 @@ per-sync work instead of full-DAG recompute:
                 strongly-see of an existing event is stable under new
                 descendants (a new first-descendant index always
                 exceeds the old event's last-ancestor index).
-  fame          kernels.decide_fame over a round window starting at the
-                first undecided round, voting through its last row
+  fame          kernels.decide_fame_impl over a round window starting at
+                the first undecided round, voting through its last row
                 that holds a witness. Window-relative round numbers
                 preserve the vote/coin semantics exactly: diff = j - rx
                 is shift-invariant, so first-round votes and coin
@@ -273,11 +273,13 @@ def _tables_update(ranks, chain_la, chain_rb, la, rb, newtab, newpos,
     chain_la[c, k, i] / chain_rb[c, k]  new rows written from the
         batch events' (frozen) coordinates;
     ranks[c, i, t] += #{new events on chain c : la[., i] < t}  — the
-        incremental form of kernels.first_descendant_cube's
-        compare-and-count: old events' la rows never change, so the
-        count over a chain only grows by the new suffix contributions
-        (reference semantics hashgraph.go:490-530). The count is a
-        histogram over la values + a cumulative sum along the
+        first-descendant rank cube (ranks[c, i, t] = first position on
+        chain c whose event descends from chain i's position t; la
+        columns are monotone along a chain, so the position is a
+        count), kept incrementally: old events' la rows never change,
+        so the count over a chain only grows by the new suffix
+        contributions (reference semantics hashgraph.go:490-530). The
+        count is a histogram over la values + a cumulative sum along the
         threshold axis — O(n^2·K + batch·n) work, replacing the dense
         [batch, K] compare cube's O(batch·n^2·K).
     """
@@ -334,12 +336,13 @@ _tables_update_nd = jax.jit(
 
 
 class _FdRows:
-    """Lazy row view of the first-descendant matrix: fd[ids] -> the
-    same [len(ids), n] rows _fd_from_ranks would give, gathered straight
-    from the resident rank cube. Every consumer of fd (frontier sweep,
-    fame, consensus timestamps) reads row gathers only, so the dense
-    [cap, n] materialization (512 MB/pass at the n=1024 north star) is
-    never built."""
+    """Lazy row view of the first-descendant matrix, gathered straight
+    from the resident rank cube: event a = chain[creator_a, index_a], so
+    fd[a, c] = ranks[c, creator_a, index_a], INT32_MAX when the position
+    is past chain c's end or a is a pad row. Every consumer of fd
+    (frontier sweep, fame, consensus timestamps) reads row gathers only,
+    so the dense [cap, n] materialization (512 MB/pass at the n=1024
+    north star) is never built."""
 
     def __init__(self, ranks, chain_len, creator, index):
         self.ranks = ranks
@@ -355,22 +358,6 @@ class _FdRows:
         raw = jnp.moveaxis(self.ranks[:, ca, ia], 0, -1)  # [*S, n]
         fd = jnp.where(raw < self.chain_len, raw, INT32_MAX)
         return jnp.where((ix >= 0)[..., None], fd, INT32_MAX)
-
-
-@functools.partial(jax.jit, static_argnames=("n",))
-def _fd_from_ranks(ranks, chain_len, creator, index, *, n):
-    """fd[a, c] from the resident rank cube: event a = chain[creator_a,
-    index_a], so fd[a, c] = ranks[c, creator_a, index_a], INT32_MAX when
-    the position is past chain c's end (same contract as
-    kernels.fd_from_cube, with the chain_len clamp fused into the
-    gather instead of materializing the clamped cube)."""
-    k = ranks.shape[2]
-    e1 = creator.shape[0] - 1
-    ca = creator[:e1]
-    ia = jnp.clip(index[:e1], 0, k - 1)
-    raw = ranks[:, ca, ia].T  # [cap, n]
-    fd = jnp.where(raw < chain_len[None, :], raw, INT32_MAX)
-    return jnp.where((index[:e1] >= 0)[:, None], fd, INT32_MAX)
 
 
 def _rr_steps(elig):
@@ -727,6 +714,15 @@ class PendingPass:
     )
 
 
+def _axis_size(mesh, axis) -> int:
+    """Devices along a mesh axis name or a tuple of them."""
+    names = (axis,) if isinstance(axis, str) else tuple(axis)
+    d = 1
+    for a in names:
+        d *= mesh.shape[a]
+    return d
+
+
 class IncrementalEngine:
     """Growable device-resident DAG + amortized consensus pipeline.
 
@@ -797,8 +793,8 @@ class IncrementalEngine:
         # same jitted kernels across the mesh (semantics-preserving;
         # the compiler inserts the collectives), so a node's DAG
         # capacity scales with its chips instead of one chip's HBM.
-        # O(E) 1-D int vectors stay replicated, the same tradeoff the
-        # one-shot sharded pipeline makes (ops/sharded.py).
+        # O(E) 1-D int vectors stay replicated: they are small beside
+        # the [cap, n] table, and every kernel gathers from them.
         self._mesh = mesh
         self._mesh_axis = mesh_axis
         # Devices the participant axis is split over; the round-received
@@ -806,8 +802,6 @@ class IncrementalEngine:
         self.devices = 1
         if mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec as P
-
-            from .sharded import _axis_size
 
             self.devices = daxes = _axis_size(mesh, mesh_axis)
             if n % daxes:
@@ -849,9 +843,9 @@ class IncrementalEngine:
         self._rounds_d = jnp.full((self.cap,), -1, jnp.int32)
         self._rr_d = jnp.full((self.cap,), -1, jnp.int32)
         self._ranks = jnp.zeros((n, n, self.kcap), jnp.int32, device=ch)
-        # chain_la/chain_rb could be re-gathered per run from la/chain
-        # (build_chain_tables), but the gather materializes this same
-        # [n, K, n] cube transiently anyway (the frontier consumes it),
+        # chain_la/chain_rb could be re-gathered per run from la/chain,
+        # but the gather materializes this same [n, K, n] cube
+        # transiently anyway (the frontier consumes it),
         # and at n=1024 it would re-read ~2 GB of HBM per sync; keeping
         # it resident costs the same peak memory and only writes the
         # new chain suffix rows.

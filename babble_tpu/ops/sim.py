@@ -1,39 +1,32 @@
-"""Batched gossip simulation: per-peer views as one vmap.
+"""Gossip simulation: the DAG a peer-selection schedule makes, and
+each peer's view of it.
 
 The reference runs N OS processes exchanging syncs (reference
-node/node.go:315-487); the batched simulator replays that protocol as
-tensors: a peer-selection schedule generates the DAG, knowledge masks
-track which events each peer has seen (gossip transfers the full
-ancestry closure, so every view is ancestry-closed), and consensus for
-ALL views is one `vmap` of the masked pipeline over the mask axis —
-the checkGossip oracle (node/node_test.go:548-599) computed on device.
-
-Ancestry-closure is what makes this sound: coordinates (last_anc /
-first_desc) computed once on the full DAG are exact for every closed
-subgraph (see kernels.compute_rounds), so views differ only in their
-witness tables.
+node/node.go:315-487); the simulator replays that protocol on the host:
+a peer-selection schedule generates the DAG, and knowledge masks track
+which events each peer has seen (gossip transfers the full ancestry
+closure, so every view is ancestry-closed). Each view's consensus order
+comes from an engine fed that view; check_view_consistency is the
+checkGossip oracle (node/node_test.go:548-599) over those orders.
 """
 
 from __future__ import annotations
 
 import random
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import List, Optional, Sequence, Tuple
 
-import jax
 import numpy as np
 
 from ..gojson import Timestamp
 from .. import crypto
 from ..hashgraph.event import Event
-from . import kernels
-from .dag import DagTensors, _assemble, build_dag
-from .pipeline import consensus_pipeline
+from .dag import DagTensors, build_dag
 
 
 class GossipSim:
     """Host-side gossip simulator over real signed events, tracking
-    per-peer knowledge (used for view-parity tests; the all-array
-    `synthetic_dag` is the benchmark path)."""
+    per-peer knowledge (used for view-parity tests; simulate_views is
+    its all-array counterpart, with no crypto)."""
 
     def __init__(self, n: int, seed: int = 0, seed_base: int = 9000):
         self.n = n
@@ -166,10 +159,10 @@ def simulate_views(
     rng = np.random.default_rng(seed + 1)
 
     e = n + steps
-    self_parent = np.full(e + 1, -1, np.int32)
-    other_parent = np.full(e + 1, -1, np.int32)
-    creator = np.zeros(e + 1, np.int32)
-    index = np.zeros(e + 1, np.int32)
+    self_parent = np.full(e, -1, np.int32)
+    other_parent = np.full(e, -1, np.int32)
+    creator = np.zeros(e, np.int32)
+    index = np.zeros(e, np.int32)
     heads = np.full(n, -1, np.int64)
     seqs = np.full(n, -1, np.int64)
     know = np.zeros((n, e), dtype=bool)
@@ -204,102 +197,11 @@ def simulate_views(
         next_snap = next(snap_iter, None)
     masks = np.concatenate(snap_masks, axis=0)
 
-    coin = np.zeros(e + 1, np.int8)
-    coin[:e] = rng.integers(0, 2, size=e, dtype=np.int8)
-    ts_rank = np.zeros(e + 1, np.int32)
-    ts_rank[:e] = np.arange(e, dtype=np.int32)
-    ts_values = np.arange(e, dtype=np.int64)
-    root_round = np.full(n, -1, np.int32)
+    coin = rng.integers(0, 2, size=e, dtype=np.int8)
     s_rank = rng.integers(0, 2**62, size=e, dtype=np.int64)
-
-    dag = _assemble(
-        n, e, self_parent, other_parent, creator, index, coin, ts_rank,
-        ts_values, root_round, hexes=[], hex_to_id={}, events=[])
+    dag = DagTensors(n, e, self_parent, other_parent, creator, index, coin,
+                     np.arange(e, dtype=np.int64), hexes=[], events=[])
     return dag, masks, s_rank
-
-
-def consensus_views_factored(dag: DagTensors, masks: np.ndarray):
-    """Per-view consensus with shared coordinates: last-ancestors and
-    first-descendants are exact for every ancestry-closed view (see
-    kernels.compute_rounds), so they are computed ONCE on the full DAG
-    and only the witness-table-dependent stages (rounds, fame, round
-    received) are vmapped over the view masks. This is what makes
-    V=n-peer simulation affordable: the O(E) coordinate sweeps do not
-    multiply by V.
-
-    masks: [V, E] bool. Returns per-view (rounds, witness, wt, famous,
-    rr, cts) with a leading V axis."""
-    v, e = masks.shape
-    assert e == dag.e
-    n, sm, r = dag.n, dag.super_majority, dag.max_rounds
-    padded = np.zeros((v, e + 1), dtype=bool)
-    padded[:, :e] = masks
-
-    la = kernels.compute_last_ancestors(
-        dag.self_parent, dag.other_parent, dag.creator, dag.index,
-        dag.levels, n=n)
-    fd = kernels.compute_first_descendants(
-        la, dag.creator, dag.index, dag.chain, dag.chain_len, n=n)
-
-    def rounds_one(mask):
-        return kernels.compute_rounds(
-            dag.self_parent, dag.other_parent, dag.creator, dag.index,
-            la, fd, dag.levels, dag.root_round, mask, n=n, sm=sm, r=r)
-
-    rounds_v, wit_v, wt_v = jax.vmap(rounds_one)(padded)
-
-    # Fame/round-received at a tight round bucket — same trick as
-    # pipeline.run_pipeline.
-    from .pipeline import pad_famous, tight_round_bucket
-
-    r_small = tight_round_bucket(rounds_v if e else np.zeros(0), r)
-    wt_small = jax.numpy.asarray(np.asarray(wt_v)[:, :r_small])
-
-    def fame_rr_one(wt_s, rounds, mask):
-        famous = kernels.decide_fame(
-            wt_s, la, fd, dag.index, dag.coin, n=n, sm=sm, r=r_small)
-        rr, cts = kernels.decide_round_received(
-            rounds, wt_s, famous, la, fd, dag.creator, dag.index,
-            dag.chain_rank, mask, n=n, r=r_small)
-        return famous, rr, cts
-
-    famous_s, rr_v, cts_v = jax.vmap(fame_rr_one)(
-        wt_small, rounds_v, padded)
-    famous_v = np.stack(
-        [pad_famous(f, r, n) for f in np.asarray(famous_s)])
-    return rounds_v, wit_v, wt_v, famous_v, rr_v, cts_v
-
-
-def consensus_views(dag: DagTensors, masks: np.ndarray):
-    """Run the masked consensus pipeline for V views in one vmap.
-
-    masks: [V, E] bool. Returns per-view (rounds, witness, wt, famous,
-    rr, cts) with a leading V axis.
-    """
-    v, e = masks.shape
-    assert e == dag.e
-    padded = np.zeros((v, e + 1), dtype=bool)
-    padded[:, :e] = masks
-
-    def run_one(mask):
-        return consensus_pipeline(
-            dag.self_parent,
-            dag.other_parent,
-            dag.creator,
-            dag.index,
-            dag.coin,
-            dag.levels,
-            dag.root_round,
-            dag.chain,
-            dag.chain_len,
-            dag.chain_rank,
-            mask,
-            n=dag.n,
-            sm=dag.super_majority,
-            r=dag.max_rounds,
-        )
-
-    return jax.vmap(run_one)(padded)
 
 
 def view_order(dag: DagTensors, rr: np.ndarray, cts: np.ndarray,

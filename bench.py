@@ -1,42 +1,30 @@
 #!/usr/bin/env python
-"""Benchmark: consensus throughput of the batched TPU engine, on the chip.
+"""Host-path benchmarks and CI gates: `python bench.py <mode>`.
 
-Measures in this one process, which is the only one to touch JAX, and
-refuses to run anywhere but a TPU: when JAX finds no TPU, or a stage
-fails, it exits non-zero. After every completed stage it prints the
-payload so far as one JSON line on stdout:
-  {"metric": ..., "value": N, "unit": "events/s", "vs_baseline": N, ...}
-The last line carries the device it ran on (`backend`, `device_kind`).
+The consensus engine's chip benchmark is benchmark/run.py (cells in
+BENCHMARK.json). These modes measure host paths, run anywhere, and
+print JSON lines on stdout, the last one the result:
 
-Metric: events/sec to full consensus order (device pipeline + host
-final sort) at N=64 peers over a 50k-event synthetic random-gossip DAG
-— the event pattern the gossip runtime produces (reference
-node/node.go:315-487). `vs_baseline` is the honest like-for-like
-multiple: this repo's own reference-semantics host engine on the same
-topology (real signed events, ECDSA verify on insert, same gossip
-pattern). The reference's published 4-node docker steady state
-(265.53-268.27 ev/s, reference docs/usage.rst:31-34) is reported
-separately as `ref_docker_events_per_s` — an indicative, not
-like-for-like, anchor.
+  --node-smoke          3-node host testnet: events/s, commit latency
+                        p50/p99, and a /metrics audit
+  --trace-overhead      tracing on vs off, interleaved pairs, 5% bar
+  --health-overhead     consensus health plane on vs off, 5% bar
+  --gossip-overhead     gossip observatory on vs off, 5% bar
+  --profile-overhead    flame profiler on vs off, 5% bar
+  --capacity-overhead   capacity observatory on vs off, 5% bar
+  --ingress-overhead    admission plane on vs off through HTTP, 5% bar
+  --verify-bench        signature verify us/event per backend and batch
+  --loadgen             open-loop client firehose through HTTP
+  --soak                cluster-scaling gossip soak (--cpus, --runtime)
+  --retention           state-growth soak over FileStores
 
-Stages (each emits a partial JSON line; later stages refine):
-  smoke     n=8    e=256     proves the pipeline end-to-end
-  headline  n=64   e=50_000  the reported metric
-  northstar n=1024 e=100_000 BASELINE.md driver target size
-  host      n=64   same topology subset -> vs_baseline denominator
-
-The other modes (--node-smoke, --soak, ...) measure host paths and run
-anywhere.
+With no mode it prints this and exits 2.
 """
 
 import json
 import os
-import subprocess
 import sys
 import time
-
-BUDGET_S = float(os.environ.get("BENCH_BUDGET_S", "1500"))
-REPO = os.path.dirname(os.path.abspath(__file__))
 
 _T0 = time.monotonic()
 
@@ -46,80 +34,8 @@ def log(msg):
           flush=True)
 
 
-def main() -> int:
-    """The chip measurement, in this process, or exit 1."""
-    import jax
-
-    dev = jax.devices()[0]
-    if dev.platform != "tpu":
-        log(f"JAX found no TPU (platform {dev.platform!r}); this bench "
-            "measures the chip only")
-        return 1
-    measure()
-    return 0
-
-
-# --------------------------------------------------------------------------
-# The measurement. Emits a (partial) JSON line after every completed stage
-# so a run cut by its budget still leaves the best result so far.
-# --------------------------------------------------------------------------
-
-
 def _emit(payload):
     print(json.dumps(payload), flush=True)
-
-
-def _budget_left():
-    return BUDGET_S - (time.monotonic() - _T0) - 30.0
-
-
-def time_pipeline(dag, s_rank, warm=1, reps=3, engine="auto"):
-    """Times `reps` full runs; returns (best, median, times, n_consensus,
-    max_round). The median with its spread is the number; best is
-    reported alongside, never alone (run-to-run spread on the chip is
-    not measured yet)."""
-    import numpy as np
-
-    from babble_tpu.ops.pipeline import run_pipeline
-
-    t0 = time.monotonic()
-    for _ in range(warm):
-        out = run_pipeline(dag, engine=engine)
-        np.asarray(out[0])
-    log(f"  [{engine}] compile+warmup {time.monotonic() - t0:.1f}s")
-    times = []
-    n_consensus = 0
-    max_round = 0
-    for _ in range(reps):
-        t0 = time.perf_counter()
-        out = run_pipeline(dag, engine=engine)
-        rounds, wit, wt, famous, rr, cts = [np.asarray(x) for x in out]
-        # host finish: the consensus total order (rr, ts, S-tiebreak)
-        mask = rr >= 0
-        np.lexsort((s_rank[mask], cts[mask], rr[mask]))
-        times.append(time.perf_counter() - t0)
-        n_consensus = int(mask.sum())
-        max_round = int(rounds.max())
-    return (min(times), float(np.median(times)), times, n_consensus,
-            max_round)
-
-
-def tune_engine(dag, s_rank):
-    """Time both pipeline engines once and return the faster — the
-    closure/frontier path is built for the MXU, the wavefront for
-    dispatch-cheap backends; measuring beats guessing on an unknown
-    chip."""
-    results = {}
-    for engine in ("closure", "wavefront"):
-        if _budget_left() < 60:
-            break
-        best, _, _, _, _ = time_pipeline(dag, s_rank, warm=1, reps=1,
-                                         engine=engine)
-        results[engine] = best
-        log(f"  tune: {engine} {best * 1e3:.1f} ms")
-    if not results:
-        return "auto"
-    return min(results, key=results.get)
 
 
 def host_engine_events_per_sec(n_peers, n_events, seed=7):
@@ -2228,430 +2144,6 @@ def retention():
     return 1 if failures else 0
 
 
-def measure():
-    import jax
-
-    from babble_tpu.devices import ensure_compile_cache
-    from babble_tpu.ops.dag import synthetic_dag
-
-    ensure_compile_cache()
-    devs = jax.devices()
-    log(f"backend={devs[0].platform} devices={len(devs)}x "
-        f"{devs[0].device_kind}")
-
-    ref_docker = 266.9  # reference docs/usage.rst:31-34 midpoint
-    payload = {
-        "metric": "consensus_events_per_s_n64",
-        "value": 0.0,
-        "unit": "events/s",
-        "vs_baseline": 0.0,
-        "baseline": "repo host engine, same topology (see host_* fields)",
-        "ref_docker_events_per_s": ref_docker,
-        "backend": devs[0].platform,
-        "device_kind": devs[0].device_kind,
-    }
-
-    profile_dir = os.environ.get("BENCH_PROFILE_DIR")
-
-    # -- stage 0: smoke ----------------------------------------------------
-    log("stage smoke: n=8 e=256")
-    dag, s_rank = synthetic_dag(8, 256, seed=0)
-    best, _, _, n_cons, _ = time_pipeline(dag, s_rank, warm=1, reps=2)
-    log(f"  smoke ok: {best * 1e3:.1f} ms, {n_cons} consensus events")
-    payload["smoke_events_per_s"] = round(n_cons / best, 1)
-    _emit(payload)
-
-    # -- stage 1: headline n=64 e=50k -------------------------------------
-    engine = "auto"
-    if _budget_left() > 60:
-        n, e = 64, 50_000
-        log(f"stage headline: n={n} e={e}")
-        t0 = time.monotonic()
-        dag, s_rank = synthetic_dag(n, e, seed=1)
-        log(f"  DAG gen {time.monotonic() - t0:.1f}s, "
-            f"levels={dag.levels.shape}")
-        engine = tune_engine(dag, s_rank)
-        log(f"  tuned engine: {engine}")
-        if profile_dir:
-            jax.profiler.start_trace(profile_dir)
-        best, med, times, n_cons, max_round = time_pipeline(
-            dag, s_rank, reps=5, engine=engine)
-        if profile_dir:
-            jax.profiler.stop_trace()
-        eps = n_cons / med
-        log(f"  headline: median {med * 1e3:.1f} ms (best {best * 1e3:.1f}, "
-            f"spread {min(times) * 1e3:.0f}-{max(times) * 1e3:.0f} ms) -> "
-            f"{n_cons} consensus events ({eps:,.0f} ev/s median), "
-            f"last round {max_round}")
-        # The headline metric is the MEDIAN of 5 runs; best and the full
-        # spread ride along.
-        payload["value"] = round(eps, 1)
-        payload["engine"] = engine
-        payload["headline_ms"] = round(med * 1e3, 2)
-        payload["headline_best_ms"] = round(best * 1e3, 2)
-        payload["headline_best_events_per_s"] = round(n_cons / best, 1)
-        payload["headline_spread_ms"] = [round(t * 1e3, 1) for t in times]
-        payload["headline_consensus_events"] = n_cons
-        _emit(payload)
-
-    # -- stage 2: host-engine baseline, same topology ---------------------
-    if _budget_left() > 60:
-        # Size SWEEP: the device headline runs at e=50k but the host
-        # engine would take minutes there, so the sweep measures how the
-        # host's per-event cost moves with size — evidence for (not an
-        # assumption of) the cross-size vs_baseline ratio. e must be
-        # large enough that fame decides at n=64 (a round is ~700
-        # events at this fan-out).
-        sweep = {}
-        for host_n_events in (2500, 5000, 10000):
-            if sweep and _budget_left() < 2.5 * host_n_events / max(
-                    min(sweep.values()), 1):
-                break
-            log(f"stage host baseline: n=64 e={host_n_events} "
-                "(same topology family)")
-            host_eps, host_done, _ = host_engine_events_per_sec(
-                64, host_n_events)
-            log(f"  host engine: {host_eps:,.0f} ev/s "
-                f"({host_done} consensus)")
-            sweep[host_n_events] = round(host_eps, 1)
-        # vs_baseline stays pinned to the fixed e=5000 run so the ratio
-        # is comparable across rounds; the sweep rides along as
-        # evidence of how host cost moves with size.
-        host_n_events = 5000 if 5000 in sweep else max(sweep)
-        host_eps = sweep[host_n_events]
-        payload["host_events_per_s"] = host_eps
-        payload["host_events"] = host_n_events
-        payload["host_sweep_events_per_s"] = sweep
-        if payload["value"] and host_eps > 0:
-            payload["vs_baseline"] = round(payload["value"] / host_eps, 1)
-        _emit(payload)
-
-    # -- stage 2b: sustained incremental ingest ---------------------------
-    # The live-node metric: events arrive in sync-sized batches and each
-    # batch re-runs consensus over the undecided tip (ops/incremental.py)
-    # — the counterpart of the reference's per-sync RunConsensus
-    # (node/core.go:277-296) rather than a one-shot full-DAG recompute.
-    if _budget_left() > 120:
-        from babble_tpu.ops.incremental import IncrementalEngine
-
-        n, e_sus, bs = 64, 50_000, 4096
-        log(f"stage sustained: n={n} e={e_sus} batch={bs} (pipelined)")
-        dag_s, _ = synthetic_dag(n, e_sus, seed=3)
-        eng = IncrementalEngine(
-            n, capacity=65536, block=512, k_capacity=1024)
-        import numpy as _np
-
-        # PIPELINED driving — the same dispatch/collect overlap the
-        # live node's consensus worker uses: append batch k+1 while
-        # pass k computes on device, then collect k's commit delta and
-        # dispatch k+1. Per-batch time is the HOST-BLOCKING wall
-        # (append + collect wait + dispatch staging); the device
-        # compute that overlapped the append no longer counts, which
-        # is exactly the production hot path.
-        phase_tot: dict = {}
-        overlap_ns = 0
-        prof_from = 3  # skip compile-warmup batches in the phase split
-
-        def _harvest(b_i):
-            nonlocal overlap_ns
-            if b_i >= prof_from:
-                for ph, ns in eng.phase_ns.items():
-                    phase_tot[ph] = phase_tot.get(ph, 0) + ns
-                overlap_ns += eng.last_overlap_ns
-
-        t0 = time.perf_counter()
-        per_batch = []
-        pending = None
-        b_i = 0
-        k = 0
-        while k < e_sus:
-            hi = min(k + bs, e_sus)
-            tb = time.perf_counter()
-            eng.append_batch(
-                dag_s.self_parent[k:hi], dag_s.other_parent[k:hi],
-                dag_s.creator[k:hi], dag_s.index[k:hi], dag_s.coin[k:hi],
-                _np.arange(k, hi))
-            if pending is not None:
-                eng.collect(pending)
-                _harvest(b_i)
-            pending = eng.dispatch()
-            per_batch.append(time.perf_counter() - tb)
-            b_i += 1
-            k = hi
-        if pending is not None:
-            eng.collect(pending)
-            _harvest(b_i)
-        # Drain appends staged during the final in-flight pass.
-        pending = eng.dispatch()
-        if pending is not None:
-            eng.collect(pending)
-        total = time.perf_counter() - t0
-        if e_sus % bs:  # final partial batch would skew the per-batch rate
-            per_batch = per_batch[:-1]
-        half = per_batch[len(per_batch) // 2:]
-        steady = float(_np.median(half))
-        log(f"  sustained: {total:.1f}s total ({e_sus / total:,.0f} ev/s), "
-            f"steady {bs / steady:,.0f} ev/s "
-            f"(per-batch spread {min(half):.2f}-{max(half):.2f}s), "
-            f"{int((eng.rr[:e_sus] >= 0).sum())} consensus")
-        payload["sustained_events_per_s"] = round(e_sus / total, 1)
-        payload["sustained_steady_events_per_s"] = round(bs / steady, 1)
-        payload["sustained_steady_spread_s"] = [
-            round(min(half), 3), round(max(half), 3)]
-        payload["sustained_batch"] = bs
-
-        # Phase split of the pipelined loop: host-blocking ns per
-        # phase (timers NOT synced — async dispatches only charge
-        # their enqueue). The device->host trio (c_pull + coords +
-        # fd_fold) is the share the tentpole targets: with the delta
-        # pull overlapped it should be a small minority of pass wall.
-        if phase_tot:
-            # c_pull_wait/xfer are a SPLIT of c_pull, not siblings —
-            # keep them out of the share denominator.
-            _sub = ("c_pull_wait", "c_pull_xfer")
-            top_t = {ph: ns for ph, ns in phase_tot.items()
-                     if ph not in _sub}
-            tot_ns = sum(top_t.values())
-            shares = {ph: round(ns / tot_ns, 3)
-                      for ph, ns in sorted(top_t.items())}
-            bounding = max(top_t, key=top_t.get)
-            pull_share = (shares.get("c_pull", 0) + shares.get("coords", 0)
-                          + shares.get("fd_fold", 0))
-            log(f"  phase split: " + ", ".join(
-                f"{ph} {sh:.0%}" for ph, sh in shares.items())
-                + f" -> bounded by {bounding}; "
-                f"pull share {pull_share:.0%}, "
-                f"overlap {overlap_ns / 1e9:.1f}s")
-            payload["sustained_phase_share"] = shares
-            payload["sustained_bounding_phase"] = bounding
-            payload["sustained_pull_share"] = round(pull_share, 3)
-            payload["sustained_overlap_s"] = round(overlap_ns / 1e9, 2)
-            if phase_tot.get("c_pull"):
-                # Wait (device still computing) vs xfer (D2H copy) —
-                # the attribution that says whether c_pull needs a
-                # faster kernel or a smaller pull.
-                payload["sustained_c_pull_split"] = {
-                    "wait": round(phase_tot.get("c_pull_wait", 0)
-                                  / phase_tot["c_pull"], 3),
-                    "xfer": round(phase_tot.get("c_pull_xfer", 0)
-                                  / phase_tot["c_pull"], 3)}
-
-        _emit(payload)
-
-    # -- stage 2c: the real gossiping node --------------------------------
-    # 4 live nodes (threads, inmem transport, per-event ECDSA, the full
-    # sync protocol) — the apples-to-apples number against the
-    # reference's 4-node docker steady state (265.53-268.27 ev/s,
-    # reference docs/usage.rst:31-34). Two rows: the host engine (the
-    # like-for-like configuration — 4 independent consensus engines on
-    # one machine, as the reference runs), and the TPU engine, where
-    # all 4 nodes time-share ONE chip — honest, but hardware-limited in
-    # a way a per-validator accelerator deployment is not.
-    if os.environ.get("BENCH_SKIP_NODE") != "1":
-        if _budget_left() > 180:
-            node_eps, node_ph = node_testnet_events_per_sec(
-                engine="host", warm_s=30.0, window_s=30.0)
-            log(f"  4-node --engine host testnet: {node_eps:,.1f} "
-                f"committed events/s (ref docker: {ref_docker})")
-            payload["node_events_per_s"] = round(node_eps, 1)
-            payload["node_vs_ref_docker"] = round(
-                node_eps / ref_docker, 2)
-            payload["node_phase_share"] = node_ph.get("phase_share")
-            payload["node_ingest_phase_share"] = node_ph.get(
-                "ingest_phase_share")
-            payload["commit_latency_p50_ms"] = node_ph.get(
-                "commit_latency_p50_ms")
-            payload["commit_latency_p99_ms"] = node_ph.get(
-                "commit_latency_p99_ms")
-            _emit(payload)
-        if _budget_left() > 150:
-            # Durable-path A/B: the same host testnet on WAL-backed
-            # FileStores. store_commit_share = fraction of node
-            # phase wall inside sqlite COMMITs; the events/s delta
-            # vs node_events_per_s is the full durable overhead.
-            file_eps, file_ph = node_testnet_events_per_sec(
-                engine="host", warm_s=30.0, window_s=30.0,
-                store="file")
-            log(f"  4-node --engine host --store file testnet: "
-                f"{file_eps:,.1f} committed events/s "
-                f"(store_commit_share "
-                f"{file_ph.get('store_commit_share')})")
-            payload["node_file_events_per_s"] = round(file_eps, 1)
-            payload["store_commit_share"] = file_ph.get(
-                "store_commit_share")
-            _emit(payload)
-        if _budget_left() > 520:
-            # The warm gate shrank 6000 -> 2500 committed events:
-            # engine prewarm compiles the kernel ladder at node
-            # construction and the persistent compile cache covers
-            # restarts, so the old multi-thousand-event drift of
-            # window-shape compiles is mostly gone.
-            node_eps, node_ph = node_testnet_events_per_sec(
-                engine="tpu", warm_s=180.0, window_s=40.0,
-                warm_gate_events=2500, windows=3)
-            log(f"  4-node --engine tpu testnet (one chip): "
-                f"{node_eps:,.1f} committed events/s; "
-                f"phases {node_ph}")
-            payload["node_tpu_events_per_s"] = round(node_eps, 1)
-            payload["node_tpu_phase_share"] = node_ph.get(
-                "phase_share")
-            payload["node_tpu_engine_phase_share"] = node_ph.get(
-                "engine_phase_share")
-            payload["node_tpu_engine_pull_share"] = node_ph.get(
-                "engine_pull_share")
-            payload["node_tpu_engine_overlap_s"] = node_ph.get(
-                "engine_overlap_s")
-            _emit(payload)
-        # 16 validators on one machine — 4x the reference's published
-        # deployment size, host engine (16 independent engines).
-        if _budget_left() > 150:
-            node_eps, _ = node_testnet_events_per_sec(
-                engine="host", n_nodes=16, warm_s=45.0, window_s=30.0,
-                interval=1.0)
-            log(f"  16-node --engine host testnet: {node_eps:,.1f} "
-                f"committed events/s")
-            payload["node16_events_per_s"] = round(node_eps, 1)
-            # Machine-tracked cluster-scaling trend (node{4,16}
-            # here, node{3,16} in the smoke payload): the ledger
-            # charts whether per-node throughput scales out or
-            # collapses with cluster size.
-            if "node_events_per_s" in payload:
-                payload["node_scaling_events_per_s"] = {
-                    "4": payload["node_events_per_s"],
-                    "16": round(node_eps, 1)}
-            _emit(payload)
-
-    # -- stage 3: north star n=1024 e=100k --------------------------------
-    if _budget_left() > 300:
-        n, e = 1024, 100_000
-        log(f"stage northstar: n={n} e={e}")
-        t0 = time.monotonic()
-        dag, s_rank = synthetic_dag(n, e, seed=2)
-        log(f"  DAG gen {time.monotonic() - t0:.1f}s, "
-            f"levels={dag.levels.shape}")
-        # Engine choice flips with n (the frontier sweep's trip
-        # count is the round count, which shrinks as n grows), so
-        # re-tune at this size instead of reusing the headline's.
-        engine_ns = tune_engine(dag, s_rank)
-        log(f"  tuned northstar engine: {engine_ns}")
-        best, med, times, n_cons, max_round = time_pipeline(
-            dag, s_rank, warm=1, reps=3, engine=engine_ns)
-        eps = n_cons / med
-        log(f"  northstar: median {med * 1e3:.1f} ms "
-            f"(spread {min(times) * 1e3:.0f}-{max(times) * 1e3:.0f}) -> "
-            f"{n_cons} consensus ({eps:,.0f} ev/s), "
-            f"last round {max_round}")
-        payload["northstar_events_per_s"] = round(eps, 1)
-        payload["northstar_best_events_per_s"] = round(n_cons / best, 1)
-        payload["northstar_spread_ms"] = [
-            round(t * 1e3, 1) for t in times]
-        payload["northstar_n"] = n
-        payload["northstar_events"] = e
-        _emit(payload)
-
-        # North-star INCREMENTAL: the engine a live `--engine tpu`
-        # node actually drives (ops/incremental.py), fed the same
-        # DAG in sync-sized batches — the validated at-scale number
-        # VERDICT r3 asked for (run on the real chip, value-pulling
-        # every sync).
-        if _budget_left() > 240:
-            from babble_tpu.node.core import engine_sizes
-            from babble_tpu.ops.incremental import IncrementalEngine
-            import numpy as _np
-
-            bs_ns = 4096
-            log(f"stage northstar incremental: n={n} e={e} "
-                f"batch={bs_ns}")
-            # Presized and fed as a node presizes and feeds it; the
-            # loop chip_smoke.py checks.
-            eng = IncrementalEngine(n, **engine_sizes(n))
-            t0 = time.perf_counter()
-            per_b = []
-            tb = t0
-            for _ in eng.feed(dag, bs_ns):
-                now = time.perf_counter()
-                per_b.append(now - tb)
-                tb = now
-            total_ns = time.perf_counter() - t0
-            half = per_b[len(per_b) // 2:]
-            steady_ns = float(_np.median(half))
-            n_cons_inc = int((eng.rr[:e] >= 0).sum())
-            log(f"  northstar incremental: {total_ns:.1f}s "
-                f"({e / total_ns:,.0f} ev/s), steady "
-                f"{bs_ns / steady_ns:,.0f} ev/s, "
-                f"{n_cons_inc} consensus")
-            payload["northstar_incremental_events_per_s"] = round(
-                e / total_ns, 1)
-            payload["northstar_incremental_steady_events_per_s"] = (
-                round(bs_ns / steady_ns, 1))
-            _emit(payload)
-
-        # Honest wall-clock multiple at this scale (BASELINE.md
-        # driver target: >=100x at n=1024/100k): the host engine
-        # reaches no consensus below ~3n events per round, so its
-        # per-event processing rate (insert + consensus pass) over
-        # a 2k-event prefix is measured and extrapolated to the
-        # full run — labeled as such.
-        if _budget_left() > 120:
-            host_e = 2000
-            log(f"stage northstar host extrapolation: n={n} e={host_e}")
-            # only the insert+consensus span counts (key generation
-            # and event signing setup are excluded on both sides)
-            _, _, host_dt = host_engine_events_per_sec(n, host_e)
-            host_rate = host_e / host_dt
-            extrapolated = e / host_rate
-            payload["northstar_host_rate_events_per_s"] = round(
-                host_rate, 1)
-            payload["northstar_host_wall_extrapolated_s"] = round(
-                extrapolated, 1)
-            payload["northstar_wall_speedup_vs_host"] = round(
-                extrapolated / best, 1)
-            log(f"  host rate {host_rate:.1f} ev/s -> extrapolated "
-                f"{extrapolated:,.0f}s vs device {best:.1f}s "
-                f"({extrapolated / best:,.0f}x)")
-            _emit(payload)
-
-        # vs-Go calibration (BASELINE.json's target names Go, not
-        # Python): build and run the C++ conservative stand-in for
-        # the reference engine's data path (cpp/ref_model_bench.cc
-        # — flat int-indexed storage, no GC, no signatures, fame
-        # and FindOrder omitted; every choice makes it FASTER than
-        # real Go), and report the resulting LOWER bound on the
-        # device-vs-Go wall-clock multiple. The Python-host
-        # extrapolation above brackets it from the other side.
-        if _budget_left() > 120:
-            src = os.path.join(REPO, "cpp", "ref_model_bench.cc")
-            binp = os.path.join(REPO, "cpp", "ref_model_bench")
-            stale = (not os.path.exists(binp)
-                     or os.path.getmtime(binp)
-                     < os.path.getmtime(src))
-            if stale:
-                subprocess.run(
-                    ["g++", "-O3", "-march=native", "-o", binp,
-                     src], check=True, timeout=120)
-            out = subprocess.run(
-                [binp, str(n), str(e)], capture_output=True,
-                timeout=1200, check=True)
-            model = json.loads(out.stdout)
-            model_wall = float(model["wall_s"])
-            vs_go_min = model_wall / best
-            payload["vs_go_model_wall_s"] = round(model_wall, 2)
-            payload["vs_go_estimated_min"] = round(vs_go_min, 1)
-            payload["vs_go_basis"] = (
-                "lower bound: wall of a C++ reimplementation "
-                "of the reference insert+DivideRounds data "
-                "path (cpp/ref_model_bench.cc), strictly "
-                "faster than Go (no GC/strings/signatures, "
-                "fame+order omitted), vs the device one-shot")
-            log(f"  vs-Go: C++ model {model_wall:,.1f}s vs "
-                f"device {best:.1f}s -> >= {vs_go_min:,.0f}x "
-                f"(conservative lower bound)")
-            _emit(payload)
-
-    payload["wall_s"] = round(time.monotonic() - _T0, 1)
-    _emit(payload)
-
-
 if __name__ == "__main__":
     if "--node-smoke" in sys.argv:
         sys.exit(node_smoke())
@@ -2676,4 +2168,5 @@ if __name__ == "__main__":
     elif "--retention" in sys.argv:
         sys.exit(retention())
     else:
-        sys.exit(main())
+        print(__doc__, file=sys.stderr)
+        sys.exit(2)

@@ -20,12 +20,13 @@ passed.
               events in a different valid order.
 3. n64        BASELINE.json config 2: 64 validators, uniform-random peer
               selection, 10k signed events through TpuHashgraph and the
-              host Hashgraph: identical consensus order and blocks; the
-              one-shot pipeline (the closure engine, on the chip)
-              agrees with the live engine.
+              host Hashgraph: identical consensus order and blocks.
 4. northstar  n=1024, 100k events: IncrementalEngine presized as a node
               presizes it, 4096-event syncs, packed pull every sync;
-              bit-identical to the one-shot pipeline on the same DAG.
+              rounds, witnesses, fame, round received and consensus
+              timestamps equal to the plain reference
+              (benchmark/reference.py, the benchmark's `correct` gate)
+              on the same DAG.
 
 --four-chips: at n=1024 (100k events) the mesh-resident engine over
 four chips is bit-identical to the same engine on device 0 alone; at
@@ -253,8 +254,6 @@ def n64(n: int = 64, e: int = 10_000, seed: int = 0, sync: int = 500):
     from babble_tpu.hashgraph import Hashgraph, InmemStore
     from babble_tpu.hashgraph.tpu_graph import TpuHashgraph
     from babble_tpu.node.core import engine_sizes
-    from babble_tpu.ops.dag import build_dag
-    from babble_tpu.ops.pipeline import run_pipeline
 
     participants, events = signed_gossip(n, e, seed)
     host_blocks, dev_blocks = [], []
@@ -274,16 +273,6 @@ def n64(n: int = 64, e: int = 10_000, seed: int = 0, sync: int = 500):
     check(len(cons) > 0, "n64: no consensus events")
     check(dev.consensus_events() == cons, "n64: consensus order != host")
     check(dev_blocks == host_blocks, "n64: blocks != host")
-    # The one-shot pipeline picks its engine by backend: on the chip, at
-    # this n, the block-closure/round-frontier engine.
-    import numpy as np
-
-    rounds, wit, _, _, rr, _ = map(
-        np.asarray, run_pipeline(build_dag(events, participants)))
-    eng = dev.engine
-    same({"rounds": eng.rounds[:e], "witness": eng.witness[:e],
-          "rr": eng.rr[:e]}, {"rounds": rounds, "witness": wit, "rr": rr},
-         "n64: one-shot pipeline vs engine")
     return {"events": e, "committed": len(cons), "blocks": len(host_blocks),
             "n": n}
 
@@ -312,39 +301,53 @@ def same(a: dict, b: dict, what: str) -> None:
               f"{what}: {k} differs")
 
 
-def northstar(n: int = 1024, e: int = 100_000, seed: int = 0):
-    """The live engine at north-star size against the one-shot
-    pipeline on the same DAG."""
+def against_reference(eng, dag, what: str) -> int:
+    """Check the engine fed `dag` (timestamps = event ids, as
+    IncrementalEngine.feed stamps them) against benchmark.reference over
+    the same DAG: rounds, witnesses, fame, round received, and consensus
+    timestamps where decided. Returns the decided event count."""
     import numpy as np
 
+    from benchmark import reference
+
+    e = dag.e
+    ref = reference.consensus(dag.n, dag.self_parent, dag.other_parent,
+                              dag.creator, dag.index, dag.coin,
+                              np.arange(e))
+    got = results(eng, e)
+    dec = ref.rr >= 0
+    r = min(len(got["famous"]), len(ref.famous))
+    same({"rounds": got["rounds"], "witness": got["witness"],
+          "rr": got["rr"], "cts": got["cts"][dec],
+          "famous": got["famous"][:r]},
+         {"rounds": ref.rounds, "witness": ref.witness, "rr": ref.rr,
+          "cts": ref.cts[dec], "famous": ref.famous[:r]},
+         f"{what}: engine vs reference")
+    check(not got["famous"][r:].any() and not ref.famous[r:].any(),
+          f"{what}: fame rows past the other's rounds")
+    return int(dec.sum())
+
+
+def northstar(n: int = 1024, e: int = 100_000, seed: int = 0):
+    """The live engine at north-star size against the plain reference
+    on the same DAG."""
     from babble_tpu.node.core import engine_sizes
     from babble_tpu.ops.dag import synthetic_dag
-    from babble_tpu.ops.incremental import CTS_SENTINEL, IncrementalEngine
-    from babble_tpu.ops.pipeline import run_pipeline
+    from babble_tpu.ops.incremental import IncrementalEngine
 
     t0 = time.monotonic()
     dag, _ = synthetic_dag(n, e, seed=seed)
     t1 = time.monotonic()
-    rounds, wit, wt, famous, rr, cts = map(np.asarray, run_pipeline(dag))
-    t2 = time.monotonic()
     eng = drive(IncrementalEngine(n, **engine_sizes(n)), dag)
-    t3 = time.monotonic()
+    t2 = time.monotonic()
     resident = eng.device_memory_stats()
-    rt = eng.famous.shape[0]
-    got = results(eng, e)
-    dec = rr >= 0
-    cts_ns = np.where(cts < 0, CTS_SENTINEL, cts.astype(np.int64))
-    same({"rounds": got["rounds"], "witness": got["witness"],
-          "rr": got["rr"], "famous": got["famous"],
-          "wt": eng.witness_table(), "cts": got["cts"][dec]},
-         {"rounds": rounds, "witness": wit, "rr": rr,
-          "famous": famous[:rt], "wt": wt[:rt], "cts": cts_ns[dec]},
-         "northstar: incremental vs one-shot")
-    check(bool((wt[rt:] == -1).all()), "northstar: witness rows past engine")
-    check(int(dec.sum()) > 0, "northstar: no consensus events")
-    return {"events": e, "committed": int(dec.sum()), "n": n,
-            "max_round": int(rounds.max()), "dag_s": round(t1 - t0, 3),
-            "oneshot_s": round(t2 - t1, 3), "engine_s": round(t3 - t2, 3),
+    committed = against_reference(eng, dag, "northstar")
+    t3 = time.monotonic()
+    check(committed > 0, "northstar: no consensus events")
+    return {"events": e, "committed": committed, "n": n,
+            "max_round": int(eng.rounds[:e].max()),
+            "dag_s": round(t1 - t0, 3), "engine_s": round(t2 - t1, 3),
+            "reference_s": round(t3 - t2, 3),
             "resident_device_bytes": resident["device_bytes"],
             "capacity": resident["capacity"],
             "chain_capacity": resident["chain_capacity"]}

@@ -12,6 +12,8 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
+import numpy as np
+
 from babble_tpu import crypto
 from babble_tpu.gojson import Timestamp
 from babble_tpu.hashgraph import Event, Hashgraph, InmemStore
@@ -307,3 +309,57 @@ def build_coin_graph(extra_rounds: int = 3) -> GraphBuilder:
             heads[c] = name
             k += 1
     return b
+
+
+# ------------------------------------------ the engine against the reference
+
+
+def feed_engine(eng, dag, bs: int):
+    """Append `dag` (ops/dag.py) to an IncrementalEngine in batches of
+    `bs` events with a pass after each; returns the engine, closed."""
+    for k in range(0, dag.e, bs):
+        hi = min(k + bs, dag.e)
+        eng.append_batch(dag.self_parent[k:hi], dag.other_parent[k:hi],
+                         dag.creator[k:hi], dag.index[k:hi], dag.coin[k:hi],
+                         dag.ts_ns[k:hi])
+        eng.run()
+    eng.close()
+    return eng
+
+
+def reference_of(dag):
+    """The plain reference's consensus (benchmark/reference.py) of a
+    whole DAG."""
+    from benchmark import reference
+
+    return reference.consensus(dag.n, dag.self_parent, dag.other_parent,
+                               dag.creator, dag.index, dag.coin, dag.ts_ns)
+
+
+def reference_witness_table(ref, creator, rows: int):
+    """The reference's witnesses as a [rows, n] table of event ids by
+    (round, creator), -1 empty."""
+    wt = np.full((rows, ref.famous.shape[1]), -1, np.int32)
+    w = np.flatnonzero(ref.witness)
+    wt[ref.rounds[w], creator[w]] = w
+    return wt
+
+
+def assert_engine_matches_reference(eng, dag):
+    """An engine fed all of `dag` holds the reference's rounds,
+    witnesses, witness table, fame, round received, and consensus
+    timestamps where decided. Returns the reference's result."""
+    ref = reference_of(dag)
+    e = dag.e
+    assert (eng.rounds[:e] == ref.rounds).all()
+    assert (eng.witness[:e] == ref.witness).all()
+    assert (eng.rr[:e] == ref.rr).all()
+    dec = ref.rr >= 0
+    assert (eng.cts_ns[:e][dec] == ref.cts[dec]).all()
+    ref_wt = reference_witness_table(ref, dag.creator, len(ref.famous))
+    for mine, theirs, empty in ((eng.witness_table(), ref_wt, -1),
+                                (eng.famous, ref.famous, 0)):
+        r = min(len(mine), len(theirs))
+        assert (mine[:r] == theirs[:r]).all()
+        assert (mine[r:] == empty).all() and (theirs[r:] == empty).all()
+    return ref

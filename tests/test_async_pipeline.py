@@ -6,9 +6,10 @@ while a pass is in flight (the second staging buffer), (b) capacity /
 chain-bucket regrowth crossing a dispatch boundary, and (c) the
 window-overflow redo path re-dispatching from a PendingPass snapshot.
 Each test drives those seams and asserts byte-identical consensus
-results against an oracle: the one-shot device pipeline for the raw
-engine, and the reference-semantics host engine (hashgraph/graph.py)
-for the full TpuHashgraph stack.
+results against an oracle: the plain reference's one-shot computation
+over the whole DAG (benchmark/reference.py) for the raw engine, and the
+reference-semantics host engine (hashgraph/graph.py) for the full
+TpuHashgraph stack.
 """
 
 from __future__ import annotations
@@ -22,13 +23,14 @@ from babble_tpu.hashgraph import Event, Hashgraph, InmemStore
 from babble_tpu.hashgraph.tpu_graph import TpuHashgraph
 from babble_tpu.ops.dag import synthetic_dag
 from babble_tpu.ops.incremental import IncrementalEngine
-from babble_tpu.ops.pipeline import run_pipeline
+
+from fixtures import assert_engine_matches_reference
 
 
 def test_pipelined_engine_matches_one_shot():
     """Interleaved appends (batch k+1 staged while pass k is in
-    flight) + forced capacity AND chain-bucket regrowth == the
-    one-shot full-DAG recompute, bit for bit."""
+    flight) + forced capacity AND chain-bucket regrowth == the plain
+    reference's one-shot computation over the whole DAG, bit for bit."""
     n, e, bs = 8, 420, 48
     dag, _ = synthetic_dag(n, e, seed=11)
     # Tiny engine: event capacity 64 and chain buckets 8 force several
@@ -59,11 +61,8 @@ def test_pipelined_engine_matches_one_shot():
             break
         eng.collect(pp)
 
-    rounds, wit, wt, famous, rr, cts = map(
-        np.asarray, run_pipeline(dag, engine="wavefront"))
-    assert (eng.rounds[:e] == rounds).all()
-    assert (eng.witness[:e] == wit).all()
-    assert (eng.rr[:e] == rr).all()
+    eng.close()
+    assert_engine_matches_reference(eng, dag)
 
 
 def test_dispatch_collect_contract():

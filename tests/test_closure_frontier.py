@@ -1,6 +1,8 @@
-"""Cross-validation of the block-closure / round-frontier kernels
-against the depth-sequential wavefront kernels (which are themselves
-parity-tested against the host engine on the reference fixtures).
+"""The block-closure coordinates (ops/closure.py) and the round frontier
+(ops/frontier.py), as the engine runs them, against the plain
+reference (benchmark/reference.py) on random gossip, and against the
+host engine (hashgraph/graph.py) where the DAG starts from non-base
+roots, which the reference does not take.
 
 Reference semantics anchors: hashgraph.go:448-499 (coordinates),
 211-339 + 616-646 (rounds/witnesses)."""
@@ -8,40 +10,15 @@ Reference semantics anchors: hashgraph.go:448-499 (coordinates),
 import numpy as np
 import pytest
 
-from babble_tpu.ops import closure, frontier, kernels
+from babble_tpu.hashgraph import Event, Hashgraph, InmemStore
+from babble_tpu.hashgraph.root import Root
+from babble_tpu.hashgraph.tpu_graph import TpuHashgraph
 from babble_tpu.ops.dag import synthetic_dag
-from babble_tpu.ops.pipeline import run_pipeline, run_pipeline_wavefront
+from babble_tpu.ops.incremental import IncrementalEngine
+from babble_tpu.ops.sim import GossipSim
 
-
-def _wavefront(dag):
-    n, sm, r = dag.n, dag.super_majority, dag.max_rounds
-    la = kernels.compute_last_ancestors(
-        dag.self_parent, dag.other_parent, dag.creator, dag.index,
-        dag.levels, n=n)
-    fd = kernels.compute_first_descendants(
-        np.asarray(la), dag.creator, dag.index, dag.chain, dag.chain_len,
-        n=n)
-    rounds, wit, wt = kernels.compute_rounds(
-        dag.self_parent, dag.other_parent, dag.creator, dag.index,
-        la, fd, dag.levels, dag.root_round, n=n, sm=sm, r=r)
-    return (np.asarray(la), np.asarray(fd), np.asarray(rounds),
-            np.asarray(wit), np.asarray(wt))
-
-
-def _frontier(dag, block=128, rc=16):
-    n, sm = dag.n, dag.super_majority
-    la, rbase = closure.coordinates(dag, block=block)
-    fd = kernels.compute_first_descendants(
-        la, dag.creator, dag.index, dag.chain, dag.chain_len, n=n)
-    wt, fr_rel, rho_min = frontier.compute_frontier(
-        la, rbase, fd, dag.chain, dag.chain_len, dag.root_round,
-        n=n, sm=sm, rc=rc)
-    e = dag.e
-    rounds, wit = frontier.rounds_from_frontier(
-        fr_rel, dag.creator[:e], dag.index[:e], dag.self_parent[:e],
-        rho_min, n=n)
-    return (np.asarray(la), np.asarray(rbase), np.asarray(rounds),
-            np.asarray(wit), wt)
+from fixtures import assert_engine_matches_reference, feed_engine
+from test_incremental import _assert_consensus_parity
 
 
 @pytest.mark.parametrize(
@@ -49,56 +26,56 @@ def _frontier(dag, block=128, rc=16):
 )
 def test_parity_random_gossip(n, e, seed):
     dag, _ = synthetic_dag(n, e, seed=seed)
-    la_o, fd_o, rounds_o, wit_o, wt_o = _wavefront(dag)
-    la_n, rbase, rounds_n, wit_n, wt_n = _frontier(dag)
-    assert (la_n == la_o).all()
-    assert (rounds_n == rounds_o).all()
-    assert (wit_n == wit_o).all()
-    rmax = int(rounds_o.max())
-    assert (wt_n[: rmax + 1] == wt_o[: rmax + 1]).all()
+    eng = IncrementalEngine(n, capacity=128, block=128, k_capacity=8)
+    feed_engine(eng, dag, max(e // 5, n))
+    assert_engine_matches_reference(eng, dag)
 
 
 def test_parity_nonbase_roots():
     """Non-base root rounds (the Reset / start-from-the-middle path,
-    reference hashgraph.go:879-898): rbase must seed frontiers above
-    round 0 and the skip-correction must hold candidates back until
-    their true round."""
-    n, e = 6, 150
-    dag, _ = synthetic_dag(n, e, seed=5)
-    # Pretend this DAG restarts from mixed per-participant root rounds.
-    dag.root_round = np.array([3, 4, 3, 5, 4, 3], dtype=np.int32)
-    la_o, fd_o, rounds_o, wit_o, wt_o = _wavefront(dag)
-    la_n, rbase, rounds_n, wit_n, wt_n = _frontier(dag)
-    assert (rounds_n == rounds_o).all()
-    assert (wit_n == wit_o).all()
-    rmax = int(rounds_o.max())
-    assert rmax >= 6  # actually started above base
-    assert (wt_n[: rmax + 1] == wt_o[: rmax + 1]).all()
-
-
-def test_pipeline_matches_wavefront_pipeline():
-    """Full-pipeline equivalence (fame, round-received, timestamps).
-    engine='closure' is forced — on CPU the 'auto' default resolves to
-    the wavefront, which would compare the oracle against itself."""
-    dag, _ = synthetic_dag(8, 400, seed=7)
-    out_n = run_pipeline(dag, engine="closure")
-    out_o = run_pipeline_wavefront(dag)
-    names = ["rounds", "wit", "wt", "famous", "rr", "cts"]
-    for name, a, b in zip(names, out_n, out_o):
-        a, b = np.asarray(a), np.asarray(b)
-        if name in ("wt", "famous"):
-            r = min(a.shape[0], b.shape[0])
-            rmax = int(np.asarray(out_o[0]).max()) + 1
-            r = min(r, rmax)
-            assert (a[:r] == b[:r]).all(), name
-        else:
-            assert (a == b).all(), name
+    reference hashgraph.go:879-898): the engine built with mixed
+    per-participant root rounds must seed frontiers above round 0 and
+    hold skip-correction candidates back until their true round, as
+    the host engine reset to the same roots does."""
+    sim = GossipSim(6, seed=5)
+    sim.run(144)
+    participants = sim.participants
+    root_round = [3, 4, 3, 5, 4, 3]
+    roots = {pk: Root(round=root_round[pid])
+             for pk, pid in participants.items()}
+    h = Hashgraph(participants, InmemStore(participants, 10000))
+    t = TpuHashgraph(participants, InmemStore(participants, 10000),
+                     capacity=64, block=64)
+    h.reset(roots)
+    t.reset(roots)
+    assert list(t.engine.root_round) == root_round
+    for ev in sim.events:
+        h.insert_event(Event(ev.body, r=ev.r, s=ev.s), True)
+        t.insert_event(Event(ev.body, r=ev.r, s=ev.s), True)
+    h.divide_rounds()
+    h.decide_fame()
+    h.find_order()
+    t.run_consensus()
+    hexes = [ev.hex() for ev in sim.events]
+    _assert_consensus_parity(h, t, hexes)
+    # Started above base: the first round is the lowest root round + 1.
+    assert min(h.round(x) for x in hexes) == min(root_round) + 1
+    assert h.store.last_round() >= 6
 
 
 def test_closure_block_sizes_agree():
-    """Block size must not affect results (pure scheduling knob)."""
+    """Block size must not affect results (pure scheduling knob): the
+    engine's coordinates and consensus at blocks of 64, 128 and 512."""
     dag, _ = synthetic_dag(8, 300, seed=9)
-    la64, rb64 = closure.coordinates(dag, block=64)
-    la256, rb256 = closure.coordinates(dag, block=256)
-    assert (np.asarray(la64) == np.asarray(la256)).all()
-    assert (np.asarray(rb64) == np.asarray(rb256)).all()
+    engines = [feed_engine(IncrementalEngine(8, capacity=64, block=b,
+                                             k_capacity=8), dag, 57)
+               for b in (64, 128, 512)]
+    e = dag.e
+    first = engines[0]
+    for eng in engines[1:]:
+        assert (np.asarray(eng._la)[:e] == np.asarray(first._la)[:e]).all()
+        assert (np.asarray(eng._rb)[:e] == np.asarray(first._rb)[:e]).all()
+        for name in ("rounds", "witness", "rr", "cts_ns"):
+            assert (getattr(eng, name)[:e] == getattr(first, name)[:e]).all()
+        assert (eng.famous == first.famous).all()
+    assert_engine_matches_reference(first, dag)

@@ -115,7 +115,7 @@ def test_coin_false_world_stalls(coin_builder):
 
 @pytest.mark.parametrize("const", [0, 1], ids=["coin0", "coin1"])
 def test_coin_world_device_parity(coin_builder, const):
-    """The device kernel's coin tensor path (kernels.decide_fame
+    """The device kernel's coin tensor path (kernels.decide_fame_impl
     coin_vote) must reproduce the host's coin-world outcome exactly —
     a sign flip in either engine breaks one of the two worlds."""
     b = coin_builder

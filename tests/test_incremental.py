@@ -4,9 +4,10 @@ Three layers of parity, mirroring the reference's oracle strategy
 (hashgraph_test.go fixtures -> core_test.go playbooks -> node_test.go
 checkGossip):
 
-1. IncrementalEngine fed in batches must equal the one-shot full
-   pipeline bit-for-bit (rounds, witnesses, fame, round-received,
-   consensus timestamps) across capacity/chain-bucket growth.
+1. IncrementalEngine fed in batches must equal the plain reference
+   (benchmark/reference.py) bit-for-bit (rounds, witnesses, fame,
+   round-received, consensus timestamps) across capacity/chain-bucket
+   growth.
 2. TpuHashgraph driven event-by-event must equal the incremental host
    engine on the reference fixture graphs: same rounds, witness sets,
    fame trileans, consensus order, and block hashes.
@@ -30,17 +31,21 @@ from babble_tpu.hashgraph.root import Root
 from babble_tpu.hashgraph.round_info import Trilean
 from babble_tpu.hashgraph.tpu_graph import TpuHashgraph
 from babble_tpu.ops import incremental as inc
-from babble_tpu.ops import kernels, pipeline
+from babble_tpu.ops import kernels
 from babble_tpu.ops.dag import synthetic_dag
-from babble_tpu.ops.incremental import CTS_SENTINEL, IncrementalEngine
-from babble_tpu.ops.pipeline import run_pipeline
+from babble_tpu.ops.incremental import IncrementalEngine
+from babble_tpu.ops.sim import simulate_views
 from babble_tpu.telemetry import SpanRing
 from benchmark import reference
 
 from fixtures import (
+    assert_engine_matches_reference,
     build_consensus_graph,
     build_funky_graph,
     build_round_graph,
+    feed_engine,
+    reference_of,
+    reference_witness_table,
 )
 from test_node import check_gossip, make_nodes, run_gossip
 
@@ -63,41 +68,45 @@ def make_tpu_twin(build):
     return h, b, t
 
 
-@pytest.mark.parametrize(
-    "n,e,bs,feed", [(8, 300, 37, False), (5, 97, 10, False),
-                    (8, 300, 37, True)], ids=["n8", "n5", "n8_feed"]
-)
-def test_engine_matches_full_pipeline(n, e, bs, feed):
-    """Batched ingest with run() between batches == one-shot recompute,
-    across capacity doubling and chain-bucket growth; `feed` drives the
-    pipelined dispatch/collect loop (IncrementalEngine.feed) instead."""
-    dag, _ = synthetic_dag(n, e, seed=3)
-    eng = IncrementalEngine(n, capacity=64, block=64, k_capacity=8)
-    if feed:
-        assert list(eng.feed(dag, bs))[-1] == e
-    else:
-        for k in range(0, e, bs):
-            hi = min(k + bs, e)
-            eng.append_batch(
-                dag.self_parent[k:hi], dag.other_parent[k:hi],
-                dag.creator[k:hi], dag.index[k:hi], dag.coin[k:hi],
-                np.arange(k, hi))
-            eng.run()
+def _silent_dag(n, steps, seed):
+    """A DAG in which the last n - supermajority validators are silent:
+    they never sync, so only their first events exist, and nobody
+    builds on them (the missing-node case, reference
+    node_test.go:409-420)."""
+    silent = np.arange(n) >= 2 * n // 3 + 1
+    dag, _, _ = simulate_views(n, steps, silent=silent, seed=seed)
+    return dag
 
-    rounds, wit, wt, famous, rr, cts = map(
-        np.asarray, run_pipeline(dag, engine="wavefront"))
-    assert (eng.rounds[:e] == rounds).all()
-    assert (eng.witness[:e] == wit).all()
-    assert (eng.rr[:e] == rr).all()
-    wt_abs = eng.witness_table()
-    rt = wt_abs.shape[0]
-    assert (wt_abs == wt[:rt]).all()
-    assert (wt[rt:] == -1).all()
-    assert (eng.famous == famous[:rt]).all()
-    dec = rr >= 0
-    # pipeline cts are ranks into dag.ts_values == arange(e); -1 = zero time
-    cts_ns = np.where(cts < 0, CTS_SENTINEL, cts.astype(np.int64))
-    assert (eng.cts_ns[:e][dec] == cts_ns[dec]).all()
+
+ENGINE_CASES = {
+    # (dag, sync size, fed through IncrementalEngine.feed)
+    "n8": (lambda: synthetic_dag(8, 300, seed=3)[0], 37, False),
+    "n5": (lambda: synthetic_dag(5, 97, seed=3)[0], 10, False),
+    "n8_feed": (lambda: synthetic_dag(8, 300, seed=3)[0], 37, True),
+    # Power-law peer selection (weights rank**-1.2): a skewed topology.
+    "powerlaw-16": (lambda: simulate_views(
+        16, 800, selector="powerlaw", alpha=1.2, seed=5)[0], 100, False),
+    "powerlaw-32": (lambda: simulate_views(
+        32, 3200, selector="powerlaw", alpha=1.2, seed=5)[0], 400, False),
+    "silent-16": (lambda: _silent_dag(16, 800, seed=6), 100, False),
+}
+
+
+@pytest.mark.parametrize("case", list(ENGINE_CASES))
+def test_engine_matches_reference(case):
+    """Batched ingest with run() between batches equals the plain
+    reference over the whole DAG, across capacity doubling and
+    chain-bucket growth; `_feed` cases drive the pipelined
+    dispatch/collect loop (IncrementalEngine.feed) instead."""
+    build, bs, feed = ENGINE_CASES[case]
+    dag = build()
+    eng = IncrementalEngine(dag.n, capacity=64, block=64, k_capacity=8)
+    if feed:
+        assert list(eng.feed(dag, bs))[-1] == dag.e
+    else:
+        feed_engine(eng, dag, bs)
+    ref = assert_engine_matches_reference(eng, dag)
+    assert (ref.rr >= 0).sum() > dag.e // 4, case
 
 
 TS_BASE = 1_700_000_000_000_000_000  # no real timestamp's hi half is 0
@@ -173,15 +182,33 @@ def test_timestamp_chunks_follow_the_pass(monkeypatch, n, e, sync, chunk,
     assert hit[case], [(p[0], p[1], p[2], p[5]) for p in pulls]
 
 
+def _reference_tables(dag, rows):
+    """The reference's view of `dag`: (la[e, n] last ancestors, fd[e, n]
+    first descendants, INT32_MAX for none), its result, and its witness
+    and fame tables padded with empty rows to `rows`."""
+    n, e, cr, idx = dag.n, dag.e, dag.creator, dag.index
+    la = reference.last_ancestors(n, dag.self_parent, dag.other_parent,
+                                  cr, idx)
+    chain = np.full((n, int(idx.max()) + 1), -1, np.int32)
+    chain[cr, idx] = np.arange(e)
+    chain_la = np.where((chain >= 0)[:, :, None], la[np.maximum(chain, 0)],
+                        inc.INT32_MAX)  # [n, K, n], monotone along K
+    fd = (chain_la[:, :, cr] < idx[None, None, :]).sum(1).T
+    fd = np.where(fd < np.bincount(cr, minlength=n), fd,
+                  inc.INT32_MAX).astype(np.int32)
+    ref = reference_of(dag)
+    wt = reference_witness_table(ref, cr, rows)
+    famous = np.zeros((rows, n), np.int32)
+    famous[:len(ref.famous)] = ref.famous
+    return la, fd, ref, wt, famous
+
+
 def _fame_case(case, monkeypatch):
     """decide_fame_impl over a window with empty rows equals the same
     kernel run with the full static trip count, r - 1 steps."""
     dag, _ = synthetic_dag(8, 600, seed=3)
-    n, sm, r = dag.n, dag.super_majority, 16
-    la, fd, _, _, wt = pipeline._coordinates_and_rounds(
-        dag.self_parent, dag.other_parent, dag.creator, dag.index,
-        dag.levels, dag.chain, dag.chain_len, dag.root_round, n=n, sm=sm,
-        r=dag.max_rounds)
+    n, sm, r = dag.n, 2 * dag.n // 3 + 1, 16
+    la, fd, _, wt, _ = _reference_tables(dag, 2 + r)
     win = np.asarray(wt)[2:2 + r].copy()  # rows from a later rx0
     top = int(np.nonzero((win >= 0).any(1))[0][-1])
     assert 8 <= top < r - 1  # trailing rows are empty
@@ -215,14 +242,9 @@ def _rr_case(case):
     """_rr_chunked run through the last eligible round equals the full
     sweep over all iw rounds."""
     dag, _ = synthetic_dag(8, 600, seed=3)
-    n, sm, iw, rl = dag.n, dag.super_majority, 16, 64
-    rounds, _, wt, famous, _, _ = map(
-        np.asarray, run_pipeline(dag, engine="wavefront"))
-    la, _, _, _, _ = pipeline._coordinates_and_rounds(
-        dag.self_parent, dag.other_parent, dag.creator, dag.index,
-        dag.levels, dag.chain, dag.chain_len, dag.root_round, n=n, sm=sm,
-        r=dag.max_rounds)
-    wt_w, fam_w = wt[:iw], famous[:iw]
+    n, iw, rl = dag.n, 16, 64
+    la, _, ref, wt_w, fam_w = _reference_tables(dag, iw)
+    rounds = ref.rounds
     wt_valid = wt_w >= 0
     fmask = (fam_w == kernels.FAME_TRUE) & wt_valid
     e = dag.e
@@ -310,16 +332,25 @@ def test_window_loops_stop_at_the_last_row_with_work(monkeypatch, case):
         _replay_case(monkeypatch)
 
 
-@pytest.mark.parametrize(
-    "build,every",
-    [(build_round_graph, 4), (build_consensus_graph, 7),
-     (build_funky_graph, 3)],
-    ids=["round", "consensus", "funky"],
-)
-def test_tpu_graph_matches_host(build, every):
-    """TpuHashgraph with interleaved run_consensus calls reproduces the
-    host engine's rounds, witness sets, fame, consensus order, and
-    blocks on the reference fixture graphs."""
+GRAPHS = {"round": (build_round_graph, 4),
+          "consensus": (build_consensus_graph, 7),
+          "funky": (build_funky_graph, 3)}
+
+
+@pytest.mark.parametrize("check", ["interleaved", "rounds", "fame", "order"])
+@pytest.mark.parametrize("graph", list(GRAPHS))
+def test_tpu_graph_matches_host(graph, check):
+    """TpuHashgraph reproduces the host engine on the reference fixture
+    graphs. `interleaved` runs consensus every few inserts and checks
+    rounds, witness sets, fame, consensus order and blocks; the other
+    checks run it once over the whole graph: `rounds` rounds and
+    witness sets, `fame` fame and the undecided rounds, `order` round
+    received, consensus timestamps, order and blocks."""
+    build, every = GRAPHS[graph]
+    if check != "interleaved":
+        h, b, t = make_tpu_twin(build)
+        _one_pass_parity(h, b, t, check)
+        return
     h, b = build()
     h.divide_rounds()
     h.decide_fame()
@@ -359,6 +390,43 @@ def test_tpu_graph_matches_host(build, every):
             continue
         tb = t.store.get_block(r)
         assert tb.hash() == hb.hash(), f"block {r}"
+
+
+def _one_pass_parity(h, b, t, check):
+    if check == "rounds":
+        for ev in b.ordered_events:
+            x = ev.hex()
+            assert t.round(x) == h.round(x), b.get_name(x)
+            assert t.witness(x) == h.witness(x), b.get_name(x)
+        for r in range(h.store.last_round() + 1):
+            assert set(t.store.round_witnesses(r)) == set(
+                h.store.round_witnesses(r)), f"round {r}"
+    elif check == "fame":
+        for r in range(h.store.last_round() + 1):
+            hri = h.store.get_round(r)
+            tri = t.store.get_round(r)
+            for w in hri.witnesses():
+                assert tri.events[w].famous == hri.events[w].famous, (
+                    f"fame mismatch {b.get_name(w)} round {r}")
+        assert sorted(set(t.undecided_rounds)) == sorted(
+            set(h.undecided_rounds))
+        assert t.last_consensus_round == h.last_consensus_round
+    else:
+        for ev in b.ordered_events:
+            x = ev.hex()
+            rr = h.round_received(x)
+            assert t.round_received(x) == rr, b.get_name(x)
+            if rr >= 0:
+                assert (t.store.get_event(x).consensus_timestamp.ns
+                        == h.store.get_event(x).consensus_timestamp.ns), (
+                    b.get_name(x))
+        assert t.consensus_events() == h.consensus_events()
+        rounds = sorted({h.store.get_event(x).round_received
+                         for x in h.consensus_events()})
+        for r in rounds:
+            hb, tb = h.store.get_block(r), t.store.get_block(r)
+            assert tb.transactions == hb.transactions, f"block {r}"
+            assert tb.hash() == hb.hash(), f"block {r}"
 
 
 def test_tpu_graph_consensus_timestamps():
@@ -680,3 +748,14 @@ def test_tpu_insert_wire_batch_matches_serial_inserts():
     assert serial.store.consensus_events() == \
         batched.store.consensus_events()
     assert serial.last_consensus_round == batched.last_consensus_round
+
+
+@pytest.mark.parametrize("phase", ["n64", "northstar"])
+def test_chip_smoke_checks_on_cpu(phase):
+    """chip_smoke's n64 (device engine vs host engine on signed gossip)
+    and northstar (engine vs the plain reference) phases, with their
+    checks, at a small size."""
+    import chip_smoke
+
+    out = getattr(chip_smoke, phase)(n=8, e=600)
+    assert out["events"] == 600 and out["committed"] > 0

@@ -1,153 +1,63 @@
-"""Parity oracle: the batched TPU engine must reproduce the incremental
-host engine (itself asserted against the reference fixtures in
-test_hashgraph.py) bit-for-bit on rounds, witness sets, fame trileans,
-round-received, consensus timestamps, consensus order, and blocks."""
+"""The device engine on the reference fixture graphs, through
+TpuHashgraph, against the incremental host engine (itself asserted
+against the reference fixtures in test_hashgraph.py): coordinates, and
+the reference's own expectations for the funky graph. Rounds, fame,
+order and blocks on the same graphs are test_incremental.py's
+test_tpu_graph_matches_host."""
 
 from __future__ import annotations
 
+import jax.numpy as jnp
 import numpy as np
-import pytest
 
-from babble_tpu.hashgraph.round_info import Trilean
-from babble_tpu.ops import run_consensus_batch
-from babble_tpu.ops.kernels import INT32_MAX, ZERO_TS_RANK
+from babble_tpu.hashgraph import InmemStore
+from babble_tpu.hashgraph.tpu_graph import TpuHashgraph
+from babble_tpu.ops import incremental as inc
 
-from fixtures import (
-    build_basic_graph,
-    build_consensus_graph,
-    build_funky_graph,
-    build_round_graph,
-)
+from fixtures import build_basic_graph, build_funky_graph
 
 
-def host_consensus(h):
-    h.divide_rounds()
-    h.decide_fame()
-    h.find_order()
-    return h
-
-
-def run_both(build):
-    h, b = build()
-    host_consensus(h)
-    res = run_consensus_batch(b.ordered_events, b.participants())
-    return h, b, res
-
-
-@pytest.mark.parametrize(
-    "build",
-    [build_round_graph, build_consensus_graph, build_funky_graph],
-    ids=["round", "consensus", "funky"],
-)
-def test_rounds_and_witnesses_parity(build):
-    h, b, res = run_both(build)
-    for eid, ev in enumerate(res.dag.events):
-        assert int(res.rounds[eid]) == h.round(ev.hex()), (
-            f"round mismatch for {b.get_name(ev.hex())}"
-        )
-        assert bool(res.witness[eid]) == h.witness(ev.hex()), (
-            f"witness mismatch for {b.get_name(ev.hex())}"
-        )
-    for r in range(h.store.last_round() + 1):
-        host_w = set(h.store.round_witnesses(r))
-        dev_w = set(res.witnesses_of_round(r))
-        assert dev_w == host_w, f"witness set mismatch in round {r}"
-
-
-@pytest.mark.parametrize(
-    "build",
-    [build_round_graph, build_consensus_graph, build_funky_graph],
-    ids=["round", "consensus", "funky"],
-)
-def test_fame_parity(build):
-    h, b, res = run_both(build)
-    for r in range(h.store.last_round() + 1):
-        info = h.store.get_round(r)
-        for whex in info.witnesses():
-            host_fame = info.events[whex].famous
-            dev_fame = res.fame_of(whex)
-            assert dev_fame == host_fame, (
-                f"fame mismatch for {b.get_name(whex)} in round {r}: "
-                f"host={host_fame} dev={dev_fame}"
-            )
-    host_undecided = sorted(set(h.undecided_rounds))
-    assert res.undecided_rounds == host_undecided
-    assert res.last_consensus_round == h.last_consensus_round
-
-
-@pytest.mark.parametrize(
-    "build",
-    [build_round_graph, build_consensus_graph, build_funky_graph],
-    ids=["round", "consensus", "funky"],
-)
-def test_order_and_blocks_parity(build):
-    h, b, res = run_both(build)
-    # round received + consensus timestamps per event
-    for eid, ev in enumerate(res.dag.events):
-        host_ev = h.store.get_event(ev.hex())
-        host_rr = host_ev.round_received if host_ev.round_received is not None else -1
-        assert int(res.round_received[eid]) == host_rr, (
-            f"round_received mismatch for {b.get_name(ev.hex())}"
-        )
-        if host_rr >= 0:
-            assert res.consensus_timestamp(eid).ns == host_ev.consensus_timestamp.ns, (
-                f"consensus ts mismatch for {b.get_name(ev.hex())}"
-            )
-    # total order
-    assert res.consensus_order == h.consensus_events(), "consensus order mismatch"
-    # blocks
-    host_blocks = []
-    rr_seen = []
-    for ehex in h.consensus_events():
-        ev = h.store.get_event(ehex)
-        if ev.round_received not in rr_seen:
-            rr_seen.append(ev.round_received)
-            host_blocks.append(h.store.get_block(ev.round_received))
-    assert len(res.blocks) == len(host_blocks)
-    for dev_b, host_b in zip(res.blocks, host_blocks):
-        assert dev_b.round_received == host_b.round_received
-        assert dev_b.transactions == host_b.transactions
-        assert dev_b.hash() == host_b.hash(), "block hash mismatch"
+def tpu_twin(b):
+    """A TpuHashgraph fed a fixture's events, consensus run once."""
+    participants = b.participants()
+    t = TpuHashgraph(participants, InmemStore(participants, 10000),
+                     capacity=64, block=64)
+    for ev in b.ordered_events:
+        t.insert_event(ev, True)
+    t.run_consensus()
+    return t
 
 
 def test_coordinates_parity_basic():
-    """The ancestry fixture exercises coordinates without the full
-    insert pipeline (reference hashgraph_test.go:66-133)."""
+    """The ancestry fixture exercises coordinates (reference
+    hashgraph_test.go:66-133): the engine's resident last ancestors and
+    its first descendants, read from the rank cube, equal the host's."""
     h, b = build_basic_graph()
-    from babble_tpu.ops import build_dag
-    from babble_tpu.ops import kernels
-
-    dag = build_dag(b.ordered_events, b.participants())
-    la = np.asarray(
-        kernels.compute_last_ancestors(
-            dag.self_parent, dag.other_parent, dag.creator, dag.index, dag.levels,
-            n=dag.n,
-        )
-    )
-    fd = np.asarray(
-        kernels.compute_first_descendants(
-            la, dag.creator, dag.index, dag.chain, dag.chain_len, n=dag.n
-        )
-    )
-    for eid, ev in enumerate(dag.events):
+    t = tpu_twin(b)
+    eng = t.engine
+    ids = np.array([t._eid_of[ev.hex()] for ev in b.ordered_events])
+    la = np.asarray(eng._la)[ids]
+    fd = np.asarray(inc._FdRows(eng._ranks, jnp.asarray(eng.chain_len),
+                                eng._cr_d, eng._idx_d)[jnp.asarray(ids)])
+    for k, ev in enumerate(b.ordered_events):
         host_ev = h.store.get_event(ev.hex())
-        assert la[eid].tolist() == [c.index for c in host_ev.last_ancestors], (
+        assert la[k].tolist() == [c.index for c in host_ev.last_ancestors], (
             f"last_anc mismatch for {b.get_name(ev.hex())}"
         )
-        assert fd[eid].tolist() == [c.index for c in host_ev.first_descendants], (
+        assert fd[k].tolist() == [c.index for c in host_ev.first_descendants], (
             f"first_desc mismatch for {b.get_name(ev.hex())}"
         )
 
 
 def test_funky_reference_asserts():
     """Re-assert the reference's funky-fixture expectations directly
-    against the batched engine (hashgraph_test.go:1539-1588)."""
+    against the device engine (hashgraph_test.go:1539-1588)."""
     h, b = build_funky_graph()
-    res = run_consensus_batch(b.ordered_events, b.participants())
-    assert int(res.rounds.max()) == 5
-    assert res.undecided_rounds == [4, 5]
+    t = tpu_twin(b)
+    assert max(t.round(ev.hex()) for ev in b.ordered_events) == 5
+    assert t.undecided_rounds == [4, 5]
     # exact per-block tx counts from the reference test
     expected_tx_counts = {1: 6, 2: 7, 3: 7}
-    by_rr = {blk.round_received: blk for blk in res.blocks}
     for rr, n_txs in expected_tx_counts.items():
-        assert len(by_rr[rr].transactions or []) == n_txs, f"block {rr}"
+        blk = t.store.get_block(rr)
+        assert len(blk.transactions or []) == n_txs, f"block {rr}"

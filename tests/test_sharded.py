@@ -1,8 +1,9 @@
-"""Multi-chip parity: the sharded pipeline (ops/sharded.py) must match
-the single-device wavefront pipeline bit-for-bit on the 8-device
-virtual mesh — rounds, witnesses, witness table, fame, round received,
-and consensus timestamps (SURVEY §5 comms plan; the driver re-checks
-this via dryrun_multichip)."""
+"""Multi-chip parity: IncrementalEngine with its carries sharded over
+meshes of the 8 virtual CPU devices (conftest.py) — as
+Core(engine_mesh=...) runs it — must match the plain reference
+(benchmark/reference.py) and the single-device engine bit-for-bit:
+rounds, witnesses, witness table, fame, round received, and consensus
+timestamps (__graft_entry__.dryrun_multichip checks the same)."""
 
 from __future__ import annotations
 
@@ -14,8 +15,8 @@ import pytest
 from jax.sharding import Mesh
 
 from babble_tpu.ops.dag import synthetic_dag
-from babble_tpu.ops.pipeline import run_pipeline
-from babble_tpu.ops.sharded import sharded_pipeline
+
+from fixtures import assert_engine_matches_reference, feed_engine
 
 
 def _mesh(shape):
@@ -23,6 +24,11 @@ def _mesh(shape):
     assert len(devices) >= 8, "conftest must provision the virtual mesh"
     if shape == "1d":
         return Mesh(np.array(devices[:8]), ("sp",)), "sp"
+    if shape == "sp4":
+        # The four-chip cell's mesh, built as a node builds it.
+        from babble_tpu.node.core import engine_mesh
+
+        return engine_mesh(devices, 16, 4), "sp"
     # Hosts x chips: shards span both axes — the multi-host layout
     # where XLA routes intra-host collective segments over ICI and
     # cross-host segments over DCN (the reference's TCP backend spans
@@ -34,21 +40,19 @@ def _mesh(shape):
 @pytest.mark.parametrize(
     "n,e,shape",
     [(8, 400, "1d"), (16, 1000, "1d"), (64, 5000, "1d"), (8, 480, "2d"),
-     (64, 5000, "2d")],
-    ids=["n8", "n16", "n64-e5000", "n8-dcn-ici", "n64-dcn-ici"],
+     (64, 5000, "2d"), (16, 1000, "sp4")],
+    ids=["n8", "n16", "n64-e5000", "n8-dcn-ici", "n64-dcn-ici", "sp4"],
 )
-def test_sharded_matches_single_device(n, e, shape):
+def test_mesh_engine_matches_reference(n, e, shape):
+    from babble_tpu.ops.incremental import IncrementalEngine
+
     mesh, axis = _mesh(shape)
     dag, _ = synthetic_dag(n, e, seed=11)
-    ref = [np.asarray(x) for x in run_pipeline(dag, engine="wavefront")]
-    got = [np.asarray(x) for x in sharded_pipeline(dag, mesh, axis=axis)]
-
-    names = ["rounds", "witness", "witness_table", "famous",
-             "round_received", "cts"]
-    for name, a, b in zip(names, ref, got):
-        assert a.shape == b.shape, name
-        assert (a == b).all(), (
-            f"{name} mismatch: {np.argwhere(a != b)[:5]}")
+    eng = IncrementalEngine(n, capacity=256, block=64, k_capacity=8,
+                            mesh=mesh, mesh_axis=axis)
+    assert eng.devices == mesh.devices.size
+    feed_engine(eng, dag, max(e // 5, 97))
+    assert_engine_matches_reference(eng, dag)
 
 
 @pytest.mark.parametrize("shape", ["1d", "2d"], ids=["1d", "dcn-ici"])

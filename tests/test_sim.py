@@ -1,28 +1,63 @@
-"""Batched per-peer view simulation: the device-side checkGossip.
+"""Per-peer view simulation: the checkGossip oracle on the device engine.
 
-- Each simulated peer's ancestry-closed view runs through the masked
-  pipeline in one vmap; all views must produce prefix-compatible
+- Each simulated peer's ancestry-closed view runs through its own
+  IncrementalEngine; all views must produce prefix-compatible
   consensus orders (reference node/node_test.go:548-599).
-- A single peer's masked view must match the incremental host engine
-  fed exactly that sub-DAG — masked-kernel parity."""
+- A single peer's view must match the incremental host engine fed
+  exactly that sub-DAG."""
 
 from __future__ import annotations
+
+import json
 
 import numpy as np
 
 from babble_tpu.hashgraph import Hashgraph, InmemStore
+from babble_tpu.hashgraph.event import event_from_json_obj
+from babble_tpu.ops.dag import DagTensors
+from babble_tpu.ops.incremental import IncrementalEngine
 from babble_tpu.ops.sim import (
     GossipSim,
     check_view_consistency,
-    consensus_views,
+    simulate_views,
     view_order,
 )
+
+from fixtures import feed_engine
 
 
 def build_sim(n=5, steps=150, seed=3):
     sim = GossipSim(n, seed=seed)
     sim.run(steps)
     return sim
+
+
+def view_consensus(dag, masks):
+    """Run one engine per view (masks [V, E] bool, each view
+    ancestry-closed) over the view's own sub-DAG. Returns [V, E] arrays
+    (rounds, rr, cts_ns) by global event id, -1 outside the view."""
+    v, e = masks.shape
+    out = np.full((3, v, e), -1, np.int64)
+    for k, mask in enumerate(masks):
+        ids = np.flatnonzero(mask)
+        local = np.full(e, -1, np.int32)
+        local[ids] = np.arange(len(ids))
+
+        def remap(parents):
+            p = parents[ids]
+            return np.where(p >= 0, local[np.maximum(p, 0)], -1)
+
+        sub = DagTensors(dag.n, len(ids), remap(dag.self_parent),
+                         remap(dag.other_parent), dag.creator[ids],
+                         dag.index[ids], dag.coin[ids], dag.ts_ns[ids],
+                         hexes=[], events=[])
+        assert (sub.self_parent < np.arange(len(ids))).all()
+        eng = feed_engine(IncrementalEngine(dag.n, capacity=64, block=64,
+                                            k_capacity=8), sub, 64)
+        out[0, k, ids] = eng.rounds[:len(ids)]
+        out[1, k, ids] = eng.rr[:len(ids)]
+        out[2, k, ids] = eng.cts_ns[:len(ids)]
+    return out
 
 
 def test_view_consistency_vmap():
@@ -32,9 +67,7 @@ def test_view_consistency_vmap():
     # add the full view as an extra row: every peer's order must be a
     # prefix-compatible subsequence of the global order too
     masks = np.vstack([masks, np.ones((1, dag.e), dtype=bool)])
-    out = consensus_views(dag, masks)
-    rr_v = np.asarray(out[4])
-    cts_v = np.asarray(out[5])
+    _, rr_v, cts_v = view_consensus(dag, masks)
     orders = check_view_consistency(dag, rr_v, cts_v)
     assert len(orders[-1]) > 0, "full view reached no consensus"
     # at least one partial view decided something
@@ -51,94 +84,35 @@ def test_masked_view_matches_host_engine():
 
     # host engine over exactly that sub-DAG, in insertion order
     sub_events = [ev for i, ev in enumerate(sim.events) if mask[i]]
-    import json
-    from babble_tpu.hashgraph.event import event_from_json_obj
-
     h = Hashgraph(sim.participants, InmemStore(sim.participants, 10000))
     for ev in sub_events:
         h.insert_event(event_from_json_obj(json.loads(ev.marshal())), True)
     h.run_consensus()
     host_order = h.consensus_events()
 
-    out = consensus_views(dag, mask[None, :])
-    rr = np.asarray(out[4])[0]
-    cts = np.asarray(out[5])[0]
+    rounds, rr, cts = (a[0] for a in view_consensus(dag, mask[None, :]))
     dev_order = [dag.hexes[i] for i in view_order(dag, rr, cts)]
-    assert dev_order == host_order, "masked view diverges from host engine"
+    assert dev_order == host_order, "view engine diverges from host engine"
 
     # per-event round parity within the view
-    rounds = np.asarray(out[0])[0]
     for i, ev in enumerate(sim.events):
         if mask[i]:
             assert int(rounds[i]) == h.round(ev.hex())
-
-
-# ---------------------------------------------------------------- at scale
-
-
-def test_views_at_scale_factored():
-    """64 ancestry-closed views (16 peers x 4 temporal snapshots)
-    through the factored vmap (shared coordinates, per-view witness
-    stages) with a power-law selector — the at-scale
-    check_view_consistency target. Temporal snapshots also assert
-    order monotonicity: a peer's earlier consensus order must be a
-    prefix of its later one."""
-    from babble_tpu.ops.sim import (
-        check_view_consistency,
-        consensus_views_factored,
-        simulate_views,
-    )
-
-    n = 16
-    dag, masks, s_rank = simulate_views(
-        n, steps=800, selector="powerlaw", alpha=1.2, seed=5,
-        snapshots=[200, 400, 600, 800])
-    assert masks.shape[0] == 64
-    out = consensus_views_factored(dag, masks)
-    rr_v = np.asarray(out[4])
-    cts_v = np.asarray(out[5])
-    orders = check_view_consistency(dag, rr_v, cts_v, s_ints=s_rank)
-    decided = [len(o) for o in orders]
-    assert max(decided) > 100, f"too little consensus at scale: {decided}"
 
 
 def test_views_with_silent_peers():
     """Up to n - supermajority peers can be silent (the missing-node
     scenario, reference node_test.go:409-420) and the remaining
     supermajority still reaches prefix-consistent consensus."""
-    from babble_tpu.ops.sim import (
-        check_view_consistency,
-        consensus_views_factored,
-        simulate_views,
-    )
-
     n = 16
     sm = 2 * n // 3 + 1
     silent = np.zeros(n, bool)
     silent[sm:] = True  # n - sm = 5 silent peers
     dag, masks, s_rank = simulate_views(
         n, steps=400, silent=silent, seed=6)
-    out = consensus_views_factored(dag, masks[~silent])
-    rr_v = np.asarray(out[4])
-    cts_v = np.asarray(out[5])
+    _, rr_v, cts_v = view_consensus(dag, masks[~silent])
     orders = check_view_consistency(dag, rr_v, cts_v, s_ints=s_rank)
     assert max(len(o) for o in orders) > 50, "silent-peer run decided too little"
     # silent peers' initial events are invisible to the active network
     for sid in np.nonzero(silent)[0]:
         assert not masks[~silent][:, sid].any()
-
-
-def test_factored_views_match_fused():
-    """The factored path (shared coordinates) must equal the fused
-    per-view pipeline bit-for-bit."""
-    from babble_tpu.ops.sim import consensus_views_factored
-
-    sim = build_sim(n=5, steps=100, seed=9)
-    dag = sim.dag()
-    masks = sim.view_masks()
-    a = consensus_views(dag, masks)
-    b = consensus_views_factored(dag, masks)
-    for name, x, y in zip(
-        ("rounds", "wit", "wt", "famous", "rr", "cts"), a, b
-    ):
-        assert (np.asarray(x) == np.asarray(y)).all(), name
