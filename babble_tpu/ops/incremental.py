@@ -593,28 +593,29 @@ def _consensus_fused(chain_la, chain_rb_tab, chain_len, la, ranks, rb_vec,
             lanes = lax.dynamic_slice(sel_l, (at,), (tc,))
             sel_ids = uid[lanes]
             t_sel = jnp.clip(rr_u[lanes] - i0, 0, iw - 1)
-            w_sel = wt_safe[t_sel]  # [tc, n]
-            fm_sel = fmask[t_sel]
+            fm_sel = fmask[t_sel]  # [tc, n]
             idxw_sel = idx_w[t_sel]
-            cr_sel = creator[sel_ids]
-            ix_sel = index[sel_ids]
             fd_sel = fd_ts[sel_ids]  # [tc, n]
-            see_sel = la[w_sel, cr_sel[:, None]] >= ix_sel[:, None]
-            s_mask = see_sel & fm_sel
+            # "Famous witness w sees x" is read from x's first-descendant
+            # row, not from la[w, creator_x] >= index_x: la[., i] never
+            # decreases along a chain (a self-parent's ancestors are its
+            # child's), and the rank cube is folded from this same la, so
+            # the positions of w's chain that see x are exactly those at
+            # or above fd[x, chain_w]. It rests on one event per (creator,
+            # index): a forked chain would break the monotonicity, and
+            # the engine accepts none. Do not restore the la gather.
+            s_mask = (fd_sel <= idxw_sel) & fm_sel
             s_cnt = s_mask.sum(1)
-            valid_t = fd_sel <= idxw_sel  # first descendant reaches witness
             fd_pos = jnp.clip(fd_sel, 0, k - 1)
             rows_n = jnp.arange(n)[None, :]
             ts_hi = chain_th[rows_n, fd_pos]
             ts_lo = chain_tl[rows_n, fd_pos]
-            # ZERO_TIME for unreached witnesses (sorts first); INT32_MAX
-            # pads the non-famous lanes to the end. Median by
-            # LEXICOGRAPHIC two-key sort — signed (hi, lo) order equals
-            # int64 ns order (_ts_split).
-            hi_v = jnp.where(valid_t, ts_hi, ZERO_TS_HI)
-            lo_v = jnp.where(valid_t, ts_lo, 0)
-            hi_m = jnp.where(s_mask, hi_v, INT32_MAX)
-            lo_m = jnp.where(s_mask, lo_v, INT32_MAX)
+            # INT32_MAX pads the lanes of witnesses that are not famous or
+            # do not see x to the end. Median by LEXICOGRAPHIC two-key
+            # sort — signed (hi, lo) order equals int64 ns order
+            # (_ts_split).
+            hi_m = jnp.where(s_mask, ts_hi, INT32_MAX)
+            lo_m = jnp.where(s_mask, ts_lo, INT32_MAX)
             s_hi, s_lo = lax.sort((hi_m, lo_m), dimension=1, num_keys=2)
             pick = (s_cnt // 2)[:, None]
             return tuple(

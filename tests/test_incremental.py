@@ -672,6 +672,64 @@ def test_tpu_reset_from_frame():
         h, t, [e.hex() for e in hf.events], b.get_name)
 
 
+def _reset_from_frame_engine():
+    _, _, t = make_tpu_twin(build_consensus_graph)
+    frame = t.get_frame()
+    t.reset(frame.roots)
+    for ev in frame.events:
+        t.insert_event(Event(ev.body, r=ev.r, s=ev.s), False)
+    t.run_consensus()
+    return t.engine
+
+
+def _fed_engine(build, bs, mesh=None):
+    dag = build()
+    return feed_engine(
+        IncrementalEngine(dag.n, capacity=256, block=64, k_capacity=8,
+                          mesh=mesh, mesh_axis="sp"), dag, bs)
+
+
+def _gossip16():
+    return synthetic_dag(16, 1000, seed=11)[0]
+
+
+def _sp4_engine():
+    from babble_tpu.node.core import engine_mesh
+
+    return _fed_engine(_gossip16, 200, mesh=engine_mesh(jax.devices(), 16, 4))
+
+
+SEES_CASES = {
+    "n8": lambda: _fed_engine(ENGINE_CASES["n8"][0], 37),
+    "n16": lambda: _fed_engine(_gossip16, 200),
+    "powerlaw-16": lambda: _fed_engine(*ENGINE_CASES["powerlaw-16"][:2]),
+    "silent-16": lambda: _fed_engine(*ENGINE_CASES["silent-16"][:2]),
+    "reset-from-frame": _reset_from_frame_engine,
+    "sp4": _sp4_engine,
+}
+
+
+@pytest.mark.parametrize("case", list(SEES_CASES))
+def test_first_descendant_row_answers_witness_sees(case):
+    """The identity the fused kernel's timestamp stage rests on: for
+    every event x and every witness w of the engine's witness table,
+    la[w, creator_x] >= index_x exactly when fd[x, creator_w] <=
+    index_w, both read from the engine's resident la and rank cube
+    (_FdRows)."""
+    eng = SEES_CASES[case]()
+    e = eng.e
+    la = np.asarray(eng._la)[:e]
+    fd = np.asarray(inc._FdRows(eng._ranks, jnp.asarray(eng.chain_len),
+                                eng._cr_d, eng._idx_d)[jnp.arange(e)])
+    wt = eng.witness_table()
+    w = wt[wt >= 0]
+    cr, ix = eng.creator[:e], eng.index[:e]
+    by_la = la[w][:, cr] >= ix[None, :]  # [witness, x]
+    by_fd = fd[:, eng.creator[w]].T <= eng.index[w][:, None]
+    assert by_la.any() and not by_la.all(), case
+    assert (by_la == by_fd).all(), case
+
+
 def test_append_batch_vectorized_matches_serial():
     """The vectorized append_batch (one slice assignment per staging
     column) must leave the engine bit-identical to per-event appends —
