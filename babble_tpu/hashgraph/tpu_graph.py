@@ -36,6 +36,7 @@ from .root import Root
 from .round_info import RoundInfo
 from .store import Store
 from ..common import StoreError, StoreErrType, is_store_err
+from ..telemetry.trace import SpanRing
 
 
 class TpuHashgraph(Hashgraph):
@@ -59,8 +60,12 @@ class TpuHashgraph(Hashgraph):
         self._k_capacity = k_capacity
         self._mesh = mesh
         self._mesh_axis = mesh_axis
-        # The owner's span ring (Core's), for the engine's pass spans.
-        self._trace = trace
+        # The owner's span ring (Core's), for the engine's pass spans
+        # and the `hg.apply` span; a disabled ring when built bare.
+        self._trace = trace if trace is not None else SpanRing(0)
+        # Wall ns of the last pass's Store mirror and block commit
+        # (the `hg.apply` span), for the owner's `mirror` phase.
+        self.apply_ns: Optional[int] = None
         self.engine = IncrementalEngine(
             len(participants), capacity=capacity, block=block,
             k_capacity=k_capacity, mesh=mesh, mesh_axis=mesh_axis,
@@ -209,11 +214,15 @@ class TpuHashgraph(Hashgraph):
         store writes), so it never spans the unlocked seam — gossip
         inserts landing during the device round trip commit in their
         own sync batches, not inside the consensus transaction."""
-        self.store.begin_batch()
-        try:
-            self._apply_delta(delta)
-        finally:
-            self.store.commit_batch()
+        got = delta.new_received
+        with self._trace.span("hg.apply", cat="consensus", ordered=len(got),
+                              blocks=len({rr for _, rr, _ in got})) as rec:
+            self.store.begin_batch()
+            try:
+                self._apply_delta(delta)
+            finally:
+                self.store.commit_batch()
+        self.apply_ns = rec.t1 - rec.t0
 
     def divide_rounds(self) -> None:  # test-surface compatibility
         self.run_consensus()

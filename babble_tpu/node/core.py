@@ -277,7 +277,9 @@ class Core:
         return int(self._m_forks.value)
 
     def _timed(self, phase: str, t0: int) -> None:
-        dt = time.perf_counter_ns() - t0
+        self._add_phase(phase, time.perf_counter_ns() - t0)
+
+    def _add_phase(self, phase: str, dt: int) -> None:
         ent = self.phase_ns.setdefault(phase, [0, 0, 0])
         ent[0] = dt
         ent[1] += dt
@@ -503,11 +505,12 @@ class Core:
         # decode happened in the transport) so /debug/phases splits the
         # sync wall into marshal vs graph work (docs/ingest.md).
         columnar = not isinstance(unknown, list)
-        t0 = time.perf_counter_ns()
-        events = self.hg.read_wire_batch(unknown)
+        with self.trace.span("core.from_wire", cat="sync",
+                             events=len(unknown)) as fw:
+            events = self.hg.read_wire_batch(unknown)
         if columnar:
-            self._timed("wire_unpack", t0)
-        self._timed("from_wire", t0)
+            self._add_phase("wire_unpack", fw.t1 - fw.t0)
+        self._add_phase("from_wire", fw.t1 - fw.t0)
 
         # Dedup-before-verify (ROADMAP crypto-plane lever 1): an event
         # already in the store OR currently in another batch's unlocked
@@ -524,23 +527,26 @@ class Core:
         verifying = {ev.hex() for ev in to_verify}
         inflight.update(verifying)
         try:
-            if to_verify:
-                self._m_verified.inc(len(to_verify))
-                if unlocked is not None:
-                    with unlocked():
+            backend = active_backend(self.device_verify)
+            with self.trace.span("core.verify", cat="sync",
+                                 events=len(to_verify), backend=backend):
+                if to_verify:
+                    self._m_verified.inc(len(to_verify))
+                    if unlocked is not None:
+                        with unlocked():
+                            verify_events(to_verify, self.verify_workers,
+                                          self.device_verify,
+                                          runtime=self.runtime)
+                    else:
                         verify_events(to_verify, self.verify_workers,
                                       self.device_verify,
                                       runtime=self.runtime)
-                else:
-                    verify_events(to_verify, self.verify_workers,
-                                  self.device_verify,
-                                  runtime=self.runtime)
+            if to_verify:
                 # Per-backend sub-split of the verify wall
                 # (docs/observability.md "Crypto plane"): same interval
                 # stamped under `verify_<backend>` so /debug/phases
                 # attributes the cost to the backend that paid it.
-                self._timed(
-                    "verify_" + active_backend(self.device_verify), t0)
+                self._timed("verify_" + backend, t0)
             self._timed("verify", t0)
             return self._insert_batch(unknown, events, has_event,
                                       wrap_fresh_only)
@@ -571,60 +577,62 @@ class Core:
         fresh_events: List[Event] = []  # first-seen inserts, topo order
         my_hex = self.hex_id()
 
-        t0 = time.perf_counter_ns()
         other_head = ""
         traced: List[int] = []
         store = self.hg.store
         store.begin_batch()
         try:
-            batch_insert = getattr(self.hg, "insert_wire_batch", None)
-            if batch_insert is not None and columnar:
-                # Device-direct seam: hand the whole fresh batch to the
-                # engine's vectorized append staging in one call. Head
-                # selection below matches the serial loop: the peer's
-                # head is the LAST event of its diff even when that
-                # event was skipped as a duplicate.
-                fresh = [ev for ev in events if not has_event(ev.hex())]
-                batch_insert(fresh)
-                fresh_events.extend(fresh)
-                for ev in fresh:
-                    if (tips is not None and ev.index()
-                            <= tips.get(ev.body.creator_id, -1)):
-                        n_stale += 1
-                    else:
-                        n_new += 1
-                    if ev.trace_id:
-                        traced.append(ev.trace_id)
-                    if ev.creator() == my_hex:
-                        self.head = ev.hex()
-                        self.seq = ev.index()
-                    elif ev.create_ns:
-                        prop.append(ev)
-                if events:
-                    other_head = events[-1].hex()
-            else:
-                for k, ev in enumerate(events):
-                    if not has_event(ev.hex()):
+            with self.trace.span("core.insert", cat="sync",
+                                 events=len(events)) as ins:
+                batch_insert = getattr(self.hg, "insert_wire_batch", None)
+                if batch_insert is not None and columnar:
+                    # Device-direct seam: hand the whole fresh batch to the
+                    # engine's vectorized append staging in one call. Head
+                    # selection below matches the serial loop: the peer's
+                    # head is the LAST event of its diff even when that
+                    # event was skipped as a duplicate.
+                    fresh = [ev for ev in events if not has_event(ev.hex())]
+                    batch_insert(fresh)
+                    fresh_events.extend(fresh)
+                    for ev in fresh:
                         if (tips is not None and ev.index()
                                 <= tips.get(ev.body.creator_id, -1)):
                             n_stale += 1
                         else:
                             n_new += 1
-                        self.insert_event(ev, False)
-                        fresh_events.append(ev)
                         if ev.trace_id:
                             traced.append(ev.trace_id)
-                        if ev.create_ns and ev.creator() != my_hex:
+                        if ev.creator() == my_hex:
+                            self.head = ev.hex()
+                            self.seq = ev.index()
+                        elif ev.create_ns:
                             prop.append(ev)
-                    if k == len(events) - 1:
-                        # Head selection: the peer's head is the LAST
-                        # event of its diff even when that event was
-                        # skipped as a duplicate (its stored copy may
-                        # differ in wire indexes, but the hash covers
-                        # only {Body, R, S}, so the hex names the
-                        # stored copy identically).
-                        other_head = ev.hex()
-            self._timed("insert", t0)
+                    if events:
+                        other_head = events[-1].hex()
+                else:
+                    for k, ev in enumerate(events):
+                        if not has_event(ev.hex()):
+                            if (tips is not None and ev.index()
+                                    <= tips.get(ev.body.creator_id, -1)):
+                                n_stale += 1
+                            else:
+                                n_new += 1
+                            self.insert_event(ev, False)
+                            fresh_events.append(ev)
+                            if ev.trace_id:
+                                traced.append(ev.trace_id)
+                            if ev.create_ns and ev.creator() != my_hex:
+                                prop.append(ev)
+                        if k == len(events) - 1:
+                            # Head selection: the peer's head is the LAST
+                            # event of its diff even when that event was
+                            # skipped as a duplicate (its stored copy may
+                            # differ in wire indexes, but the hash covers
+                            # only {Body, R, S}, so the hex names the
+                            # stored copy identically).
+                            other_head = ev.hex()
+                ins["new"] = n_new
+            self._add_phase("insert", ins.t1 - ins.t0)
 
             # Epidemic broadcast (docs/gossip.md): hand the fresh
             # inserts to the owner BEFORE wrapping them, so the relay
@@ -899,6 +907,11 @@ class Core:
         engine = getattr(self.hg, "engine", None)
         if engine is None:
             return
+        # The pass's Store mirror and block commit (the `hg.apply` span).
+        mirror = getattr(self.hg, "apply_ns", None)
+        if mirror is not None:
+            self._add_phase("mirror", mirror)
+            self.hg.apply_ns = None
         if getattr(engine, "phase_ns", None):
             for ph, ns in engine.phase_ns.items():
                 ent = self.phase_ns.setdefault(f"engine_{ph}", [0, 0, 0])

@@ -73,6 +73,28 @@ from .plumtree import Plumtree
 from .state import NodeState, StateMachine
 
 
+def build_core(conf: Config, id: int, key, store: Store,
+               commit_callback, *, trace: SpanRing, registry: Registry,
+               clock: ClusterClock) -> Core:
+    """The Core a Node runs: its settings from `conf`, its participants
+    from `store`."""
+    return Core(
+        id, key, store.participants(), store,
+        commit_callback=commit_callback,
+        engine=getattr(conf, "engine", "host"),
+        engine_mesh=getattr(conf, "engine_mesh", 0),
+        engine_prewarm=getattr(conf, "engine_prewarm", False),
+        engine_opts=getattr(conf, "engine_opts", None),
+        verify_workers=getattr(conf, "verify_workers", -1),
+        device_verify=getattr(conf, "device_verify", False),
+        runtime=getattr(conf, "runtime", "threads"),
+        trace=trace,
+        registry=registry,
+        clock=clock,
+        gossip_observatory=bool(getattr(conf, "gossip_observatory", True)),
+    )
+
+
 class Node:
     def __init__(
         self,
@@ -219,21 +241,9 @@ class Node:
         self._tx_stamp_cap = 8192
         self._tx_stamp_lock = threading.Lock()
         pmap = store.participants()
-        self.core = Core(
-            id, key, pmap, store,
-            commit_callback=self._on_block_decided,
-            engine=getattr(conf, "engine", "host"),
-            engine_mesh=getattr(conf, "engine_mesh", 0),
-            engine_prewarm=getattr(conf, "engine_prewarm", False),
-            engine_opts=getattr(conf, "engine_opts", None),
-            verify_workers=getattr(conf, "verify_workers", -1),
-            device_verify=getattr(conf, "device_verify", False),
-            runtime=getattr(conf, "runtime", "threads"),
-            trace=self.trace,
-            registry=self.registry,
-            clock=self.clock,
-            gossip_observatory=self._observatory,
-        )
+        self.core = build_core(conf, id, key, store, self._on_block_decided,
+                               trace=self.trace, registry=self.registry,
+                               clock=self.clock)
         # Preferred sync payload encoding (docs/ingest.md): what this
         # node SENDS and SERVES; both wire forms are always accepted.
         self._wire_format = getattr(conf, "wire_format", "columnar")
